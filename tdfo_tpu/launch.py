@@ -3,8 +3,10 @@
 Replaces the reference's per-backend script zoo (``python train.py`` /
 ``train_dp.py`` / ``train_ps.py``; ``torchx run ... dist.ddp -j 1x2``,
 ``torchrec/README.md:56``).  On a TPU pod every host runs this same command;
-``jax.distributed.initialize()`` discovers peers from the TPU environment —
-no TF_CONFIG / cluster.json / torchx env plumbing (SURVEY.md §5.6).
+where the environment describes several processes
+``jax.distributed.initialize()`` discovers the peers from it — no TF_CONFIG /
+cluster.json / torchx env plumbing (SURVEY.md §5.6).  A single process (one
+host, 1 or 4 chips) initialises nothing.
 
 Subcommands:
   * ``train`` (default)      — build the Trainer from config and fit.
@@ -56,16 +58,26 @@ import sys
 
 
 def _init_distributed(flag: str) -> None:
-    import jax
-
+    """``--distributed``: ``never`` initialises nothing; ``auto`` initialises
+    only where a multi-process environment is described
+    (``core/mesh.multiprocess_env``) — a single process never calls
+    ``jax.distributed.initialize()``, whose auto-detection may wait on a
+    metadata service a sealed one-host machine does not have; ``always``
+    demands such an environment.  An initialisation that was asked for and
+    fails is fatal."""
     if flag == "never":
         return
-    try:
-        jax.distributed.initialize()
-    except Exception as e:  # single-process runs have no coordinator
-        if flag == "always":
-            raise
-        print(f"single-process run (jax.distributed not initialised: {e})")
+    from tdfo_tpu.core.mesh import initialize_distributed
+
+    if initialize_distributed():
+        return
+    if flag == "always":
+        raise SystemExit(
+            "--distributed always, but no multi-process environment is "
+            "described: set WORLD_SIZE / RANK / COORDINATOR_ADDRESS (or run "
+            "where jax's TPU pod variables are set)")
+    print("single-process run (no multi-process environment described; "
+          "jax.distributed not initialised)")
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -196,6 +208,9 @@ def main(argv: list[str] | None = None) -> int:
         print(f"seq preprocessing: {stats}")
         return 0
 
+    from tdfo_tpu.core.mesh import configure_compile_cache
+
+    configure_compile_cache()
     _init_distributed(args.distributed)
 
     if cfg.model == "bert4rec":

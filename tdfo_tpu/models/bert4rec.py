@@ -212,12 +212,15 @@ def make_sharded_bert4rec(
     elif attn == "flash":
         # single-device long-context path: Pallas blockwise online-softmax
         # kernel, O(T) memory (tdfo_tpu/ops/pallas_kernels.py)
+        from tdfo_tpu.core.mesh import mesh_platform, pallas_impl
         from tdfo_tpu.ops.pallas_kernels import flash_attention
 
         def attn_fn(q, k, v, mask=None):
             key_valid = None if mask is None else mask[:, 0, 0, :]
-            interp = jax.default_backend() != "tpu"
-            return flash_attention(q, k, v, key_valid, interpret=interp)
+            how = pallas_impl("flash_attention", mesh_platform(mesh),
+                              off_chip="interpret")
+            return flash_attention(q, k, v, key_valid,
+                                   interpret=how == "interpret")
     elif attn == "full":
         attn_fn = dot_product_attention
     else:

@@ -30,8 +30,11 @@ those well.
     direction covering up to R rows.
 
 Both take ``interpret=`` for CPU-exact testing (the suite runs them in
-interpreter mode on the spoofed CPU mesh; the benchmark exercises the
-compiled path on the real chip).
+interpreter mode on the spoofed CPU mesh).  The compiled path is held by
+``tests/test_chip_compile.py`` (compiled for a described v5e, nothing runs)
+and run on the chip by ``chip_smoke.py``'s ``kernels`` phase.  Callers pick
+kernel / interpret / XLA through ``core/mesh.pallas_impl``, from the platform
+of the devices the arrays live on.
 """
 
 from __future__ import annotations
@@ -47,9 +50,6 @@ from jax.experimental.pallas import tpu as pltpu
 from tdfo_tpu.ops.quant import (
     bytes_to_f32, dequantize_rows, f32_to_bytes, quantize_rows)
 
-# jax < 0.5 ships the same dataclass under the TPU-prefixed name
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = [
     "flash_attention",
     "LineLayout",
@@ -63,6 +63,7 @@ __all__ = [
 ]
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
+_LANE = 128  # Mosaic lane tile
 
 
 # --------------------------------------------------------------------------
@@ -135,10 +136,16 @@ def flash_attention(
                            with_lse=False)[0]
 
 
-def _clip_blocks(block_q, block_k, t):
-    # blocks must stay multiples of 8 (Mosaic sublane tile) even when clipped
-    # to a short T
-    return max(8, min(block_q, t) // 8 * 8), max(8, min(block_k, t) // 8 * 8)
+def _clip_blocks(block_q, block_k, t, interpret):
+    # Blocks clip to a short T but stay whole tiles.  The compiled kernels
+    # slice the [8, T] mask / lse / delta rows along LANES at block starts, so
+    # Mosaic needs every block a multiple of 128 (T=20 pads to one 128-block;
+    # 16-wide blocks are refused: "cannot statically prove that index ... is
+    # a multiple of 128").  Interpret mode (CPU tests) only needs the 8-row
+    # sublane tile, which keeps small-T multi-block cases cheap to test.
+    tile = 8 if interpret else _LANE
+    return (max(tile, min(block_q, t) // tile * tile),
+            max(tile, min(block_k, t) // tile * tile))
 
 
 def _pad_t(t, block_q, block_k):
@@ -153,7 +160,7 @@ def _flash_fwd_impl(q, k, v, key_valid, block_q, block_k, interpret,
     b, h, t, dh = q.shape
     if key_valid is None:
         key_valid = jnp.ones((b, t), bool)
-    block_q, block_k = _clip_blocks(block_q, block_k, t)
+    block_q, block_k = _clip_blocks(block_q, block_k, t, interpret)
     if t % block_q or t % block_k:
         # pad T up to a multiple of BOTH blocks (lcm, so the recursive call
         # terminates): padded keys are masked out, padded query rows sliced
@@ -303,7 +310,7 @@ def _flash_bwd_dkv_kernel(valid_ref, lse_ref, delta_ref, q_ref, k_ref, v_ref,
 
 def _flash_bwd_impl(q, k, v, key_valid, out, lse, g, block_q, block_k, interpret):
     b, h, t, dh = q.shape
-    block_q, block_k = _clip_blocks(block_q, block_k, t)
+    block_q, block_k = _clip_blocks(block_q, block_k, t, interpret)
     if t % block_q or t % block_k:
         pad = _pad_t(t, block_q, block_k) - t
         padt = lambda x: jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
@@ -423,7 +430,6 @@ flash_attention.defvjp(
 # 128 B/row — a one-row-per-line [V, 1, 128] layout would cost 512 B/row
 # (17 GB for the 33.7M-row Criteo stack, an OOM on v5e).
 
-_LANE = 128  # Mosaic lane tile
 _SLOT_WIDTHS = (8, 16, 32, 64, 128)
 
 # optimizer-state lanes per vocab row, after the d table lanes
@@ -1102,7 +1108,7 @@ def fat_line_update(
         out_shape=jax.ShapeDtypeStruct(fat.shape, fat.dtype),
         # fat (operands: ids, corr, [seed,] gp, [tl,] fat)
         input_output_aliases={(3 if row_form else 4) + len(seed_ops): 0},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -1343,7 +1349,7 @@ def fat_line_update_routed(
         out_shape=jax.ShapeDtypeStruct(fat.shape, fat.dtype),
         # operands: ulines, sdiv, corr, [seed,] tsi, lines, g_u, fat
         input_output_aliases={6 + len(seed_ops): 0},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

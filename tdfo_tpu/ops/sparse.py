@@ -28,6 +28,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from tdfo_tpu.core.mesh import mesh_platform, pallas_impl
 from tdfo_tpu.obs import counters
 from tdfo_tpu.ops.quant import (
     component_key,
@@ -804,9 +805,27 @@ def _kernel_seed(sr_key, dtype):
                               dtype=jnp.int32)
 
 
+def _fat_impl(op: str, layout, platform: str | None, interpret: bool) -> str:
+    """``"kernel"`` | ``"interpret"`` | ``"xla"`` for one fat-line update,
+    decided by :func:`tdfo_tpu.core.mesh.pallas_impl` from the platform of
+    the devices the table lives on (``None`` = jax's default device).  On
+    TPU devices it is the kernel or an error, never another formulation."""
+    platform = platform or mesh_platform()
+    wide = layout.d > 128  # lines spanning 4+ tiles: the kernels do not cover them
+    if wide and platform == "tpu":
+        raise NotImplementedError(
+            f"{op}: fused fat-line storage has no TPU kernel for embed_dim "
+            f"{layout.d} > 128, and the XLA formulation re-tiles the whole "
+            "table every step there — set fused_table_threshold = -1 for "
+            "this table width")
+    return pallas_impl(
+        op, platform, off_chip="interpret" if interpret and not wide else "xla")
+
+
 def fat_apply_routed(fat, slots, ulines, g_u, row_lidx, row_slot, lines, *,
                      embedding_dim, kind, lr, b1=0.9, b2=0.999, eps=1e-8,
-                     weight_decay=0.0, interpret: bool = False, sr_key=None):
+                     weight_decay=0.0, interpret: bool = False, sr_key=None,
+                     platform: str | None = None):
     """Fused fat-line step on ROW-level summed grads + routing info from
     :func:`dedupe_rows_and_lines` — the fastest update path: the expensive
     C x R slot-space segment-sum never exists; the kernel routes window
@@ -845,7 +864,8 @@ def fat_apply_routed(fat, slots, ulines, g_u, row_lidx, row_slot, lines, *,
         new_count = None
         corr = jnp.zeros((2,), jnp.float32)
         new_slots = slots
-    if layout.d <= 128 and (jax.default_backend() == "tpu" or interpret):
+    how = _fat_impl("fat_line_update_routed", layout, platform, interpret)
+    if how != "xla":
         from tdfo_tpu.ops.pallas_kernels import routed_lines_per_step
 
         oob = jnp.iinfo(jnp.int32).max
@@ -878,10 +898,11 @@ def fat_apply_routed(fat, slots, ulines, g_u, row_lidx, row_slot, lines, *,
         fat = fat_line_update_routed(
             fat, lines_p, ulines_p, sdiv, tsi, g_pad, corr, layout=layout,
             lr=lr, b1=b1, b2=b2, eps=eps, weight_decay=weight_decay,
-            interpret=interpret, sr_seed=_kernel_seed(sr_key, fat.dtype),
+            interpret=how == "interpret",
+            sr_seed=_kernel_seed(sr_key, fat.dtype),
         )
         return fat, new_slots
-    # XLA fallback: construct the line-slot operands by (cheap on CPU)
+    # XLA formulation: construct the line-slot operands by (cheap on CPU)
     # scatter, then share the verified line-level formulation
     slotidx = jnp.minimum(row_lidx, cl).astype(jnp.int32) * r + row_slot
     slotidx = jnp.where(row_lidx < cl, slotidx, cl * r)  # padding -> dropped
@@ -899,9 +920,11 @@ def fat_apply_routed(fat, slots, ulines, g_u, row_lidx, row_slot, lines, *,
 
 def _fat_apply_lines(fat, slots, ulines, g_slots, touched, *, layout, lr,
                      b1=0.9, b2=0.999, eps=1e-8, weight_decay=0.0,
-                     interpret: bool = False, sr_key=None):
-    """Shared line-level dispatch: kernel on TPU (or interpret), XLA
-    formulation elsewhere.  ``g_slots``: [C*R, d] summed grads in line-slot
+                     interpret: bool = False, sr_key=None,
+                     platform: str | None = None):
+    """Shared line-level dispatch (:func:`_fat_impl`): the kernel on TPU
+    devices, off the chip the interpreted kernel (``interpret``) or the XLA
+    formulation.  ``g_slots``: [C*R, d] summed grads in line-slot
     order; ``touched``: [C*R] occupancy (any dtype, > 0 = touched).
     Returns ``(fat, slots)``."""
     from tdfo_tpu.ops.pallas_kernels import fat_line_update
@@ -926,9 +949,9 @@ def _fat_apply_lines(fat, slots, ulines, g_slots, touched, *, layout, lr,
         touched_f = (ulines < fat.shape[0]).astype(jnp.float32)[:, None]
     else:
         touched_f = (touched.reshape(c, layout.r) > 0).astype(jnp.float32)
-    # d > 128 lines span 4+ tiles — rare configs with no on-chip coverage;
-    # keep them on the proven XLA formulation (the pre-existing guard)
-    if layout.d <= 128 and (jax.default_backend() == "tpu" or interpret):
+    how = _fat_impl("fat_line_update", layout, platform, interpret)
+    if how != "xla":
+        interpret = how == "interpret"
         sr_seed = _kernel_seed(sr_key, fat.dtype)
         if layout.r == 1:
             # row-form operands: stream d lanes per line, no touched mask
@@ -957,7 +980,8 @@ def _fat_apply_lines(fat, slots, ulines, g_slots, touched, *, layout, lr,
 
 def fat_apply_unique(fat, slots, uids, g, valid=None, *, embedding_dim, kind,
                      lr, b1=0.9, b2=0.999, eps=1e-8, weight_decay=0.0,
-                     interpret: bool = False, sr_key=None):
+                     interpret: bool = False, sr_key=None,
+                     platform: str | None = None):
     """Fused fat-line optimizer step on PRE-deduplicated row-level
     ``(uids, g)``.  ``uids`` must be sorted ascending with int32-max
     sentinels at the top (the :func:`dedupe_grads` layout) — the line
@@ -981,13 +1005,15 @@ def fat_apply_unique(fat, slots, uids, g, valid=None, *, embedding_dim, kind,
         fat, slots, ulines, g_slots.reshape(-1, g_slots.shape[-1]),
         touched.reshape(-1), layout=layout, lr=lr, b1=b1, b2=b2, eps=eps,
         weight_decay=weight_decay, interpret=interpret, sr_key=sr_key,
+        platform=platform,
     )
 
 
 def fat_update(fat, slots, ids, grads, *, embedding_dim, kind, lr, b1=0.9,
                b2=0.999, eps=1e-8, weight_decay=0.0,
                capacity: int | None = None, max_distinct: int | None = None,
-               interpret: bool = False, sr_key=None):
+               interpret: bool = False, sr_key=None,
+               platform: str | None = None):
     """Big-table tier: fused in-backward optimizer over packed fat lines
     (``pallas_kernels.line_layout``) — fbgemm TBE parity for every
     ``EmbOptimType`` kind the framework exposes (adam / sgd / adagrad /
@@ -1026,7 +1052,7 @@ def fat_update(fat, slots, ids, grads, *, embedding_dim, kind, lr, b1=0.9,
     return _fat_apply_lines(
         fat, slots, ulines, g_slots, touched, layout=layout, lr=lr, b1=b1,
         b2=b2, eps=eps, weight_decay=weight_decay, interpret=interpret,
-        sr_key=sr_key,
+        sr_key=sr_key, platform=platform,
     )
 
 
@@ -1076,8 +1102,12 @@ class SparseOptimizer:
             return (jnp.zeros_like(table, dtype=sd),)
         if self.kind == "rowwise_adagrad":
             # ONE f32 cell per row: the state layout that scales to 1e9 rows
-            # (always f32 — slot_dtype does not apply to this kind)
-            return (jnp.zeros((table.shape[0],), jnp.float32),)
+            # (always f32 — slot_dtype does not apply to this kind).  Built
+            # from a column of the table so it is laid out like the table's
+            # rows: a fresh zeros((V,)) is uncommitted, the trainer pins it
+            # replicated, the first step hands it back row-sharded — and the
+            # second step compiles a second program for the new layout
+            return (jnp.zeros_like(table[:, 0], dtype=jnp.float32),)
         if self.kind == "adam":
             return (
                 jnp.zeros_like(table, dtype=sd),
@@ -1087,7 +1117,8 @@ class SparseOptimizer:
         raise ValueError(f"unknown sparse optimizer kind: {self.kind!r}")
 
     def update_routed(self, table, slots, ulines, g_u, row_lidx, row_slot,
-                      lines, *, embedding_dim: int, sr_key=None):
+                      lines, *, embedding_dim: int, sr_key=None,
+                      platform: str | None = None):
         """Fat-line fastest path: row-level summed grads + routing arrays
         from :func:`dedupe_rows_and_lines` (the dedup-lookup step shares
         ONE sort between the forward's line gather — whose result ``lines``
@@ -1099,12 +1130,12 @@ class SparseOptimizer:
             table, slots, ulines, g_u, row_lidx, row_slot, lines,
             embedding_dim=embedding_dim, kind=self.kind, lr=self.lr,
             b1=self.b1, b2=self.b2, eps=self.eps,
-            weight_decay=self.weight_decay, sr_key=sr_key,
+            weight_decay=self.weight_decay, sr_key=sr_key, platform=platform,
         )
 
     def update_unique(self, table, slots, uids, g, valid, *,
                       embedding_dim: int | None = None, sr_key=None,
-                      qscale=None):
+                      qscale=None, platform: str | None = None):
         """Tier dispatch on PRE-deduplicated ``(uids, g, valid)`` — the
         dedup-lookup step path (one shared sort per array per step).  The
         small-vocab one-hot tier needs raw ids and is bypassed here;
@@ -1124,6 +1155,7 @@ class SparseOptimizer:
                 table, slots, uids, g, valid, embedding_dim=embedding_dim,
                 kind=self.kind, lr=self.lr, b1=self.b1, b2=self.b2,
                 eps=self.eps, weight_decay=self.weight_decay, sr_key=sr_key,
+                platform=platform,
             )
         if self.kind == "sgd":
             out = sparse_sgd(table, uids, g, valid, lr=self.lr,
@@ -1435,7 +1467,7 @@ class SparseOptimizer:
 
     def update(self, table, slots, ids, grads, *, embedding_dim: int | None = None,
                capacity: int | None = None, max_distinct: int | None = None,
-               sr_key=None, qscale=None):
+               sr_key=None, qscale=None, platform: str | None = None):
         if table.ndim == 3:
             if qscale is not None:
                 raise ValueError(
@@ -1449,6 +1481,7 @@ class SparseOptimizer:
                 kind=self.kind, lr=self.lr, b1=self.b1, b2=self.b2,
                 eps=self.eps, weight_decay=self.weight_decay,
                 capacity=capacity, max_distinct=max_distinct, sr_key=sr_key,
+                platform=platform,
             )
         if (self.kind == "adam" and qscale is None
                 and table.shape[0] <= self.small_vocab_threshold):
@@ -1468,7 +1501,7 @@ class SparseOptimizer:
                                       max_distinct=max_distinct)
         return self.update_unique(table, slots, uids, g, valid,
                                   embedding_dim=embedding_dim, sr_key=sr_key,
-                                  qscale=qscale)
+                                  qscale=qscale, platform=platform)
 
 
 def sparse_optimizer(kind: str, lr: float, weight_decay: float = 0.0, **kw) -> SparseOptimizer:

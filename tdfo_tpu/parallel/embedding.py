@@ -58,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tdfo_tpu.core.mesh import MODEL_AXIS, shard_map
+from tdfo_tpu.core.mesh import MODEL_AXIS, mesh_platform, shard_map
 from tdfo_tpu.ops.quant import dequantize_rows, quantize_rows
 
 __all__ = ["EmbeddingSpec", "ShardedEmbeddingCollection", "make_embedding_specs"]
@@ -257,8 +257,10 @@ class ShardedEmbeddingCollection:
         of a 2-collective program per table; the train step then routes
         those tables' updates through :meth:`grouped_update` (one id + one
         grad ``all_to_all``).  Lookup values are identical to the per-table
-        program; update numerics are bit-identical when each table serves a
-        single feature (every shipped schema) — tables shared by several
+        program; update numerics are the same operations in the same
+        order when each table serves a single feature (every shipped
+        schema: a few f32 ULP between the two compiled programs, see
+        :meth:`grouped_update`) — tables shared by several
         features receive the same per-row grad addends in a different
         (shard-major instead of feature-major) summation order.
 
@@ -280,6 +282,9 @@ class ShardedEmbeddingCollection:
             raise ValueError("duplicate table names")
         self.mesh = mesh
         self.axis = axis
+        # platform of the devices the tables live on: what every fat-line
+        # update dispatches kernel / interpret / xla from
+        self.platform = mesh_platform(mesh)
         # <= 0 means "exact" everywhere (the config knob documents 0 that
         # way) — never let 0.0 slip through as a 1-element bucket capacity
         if a2a_capacity_factor is not None and a2a_capacity_factor <= 0:
@@ -818,7 +823,8 @@ class ShardedEmbeddingCollection:
         if not self.needs_shard_map_update(array_name):
             return opt.update(table, slots, ids, grads, embedding_dim=d,
                               capacity=max_distinct, max_distinct=max_distinct,
-                              sr_key=sr_key, qscale=qscale)
+                              sr_key=sr_key, qscale=qscale,
+                              platform=self.platform)
         if qscale is not None:
             raise ValueError(
                 f"array {array_name!r}: fat-line int8 tables carry their "
@@ -853,7 +859,7 @@ class ShardedEmbeddingCollection:
                 kind=kind, lr=opt.lr, b1=opt.b1, b2=opt.b2, eps=opt.eps,
                 weight_decay=opt.weight_decay,
                 capacity=max_distinct, max_distinct=max_distinct,
-                sr_key=sk,
+                sr_key=sk, platform=self.platform,
             )
 
         mesh = self.mesh
@@ -1428,11 +1434,15 @@ class ShardedEmbeddingCollection:
         each local shard — replacing one ``opt.update`` (and its implied
         GSPMD collectives) per table array.
 
-        Bit-exactness vs the per-table path: the stable owner sort delivers
+        Exactness vs the per-table path: the stable owner sort delivers
         each shard its owned contributions in global stream order — the
         same order ``dedupe_grads``' segment-sum adds them in ``opt.update``
-        — so per-row grad sums and optimizer outputs are identical (single-
-        feature tables; see ``__init__``).  Small-vocab adam tables take
+        — so per-row grad sums and optimizer outputs are the same
+        operations in the same order (single-feature tables; see
+        ``__init__``).  Compiled as a different program than an eager
+        per-table ``opt.update``, touched rows agree to a few f32 ULP, not
+        to the bit; untouched rows are bit-identical
+        (``tests/test_grouped_a2a.py``).  Small-vocab adam tables take
         the dedupe tier here rather than ``opt.update``'s one-hot tier
         (a different summation ORDER, same semantics).  Under a finite
         capacity factor, overflowed ids' grads are dropped — the exact ids
@@ -1532,7 +1542,8 @@ class ShardedEmbeddingCollection:
                             kind=self.fused_kind, lr=opt.lr, b1=opt.b1,
                             b2=opt.b2, eps=opt.eps,
                             weight_decay=opt.weight_decay,
-                            capacity=md, max_distinct=md, sr_key=sk)
+                            capacity=md, max_distinct=md, sr_key=sk,
+                            platform=self.platform)
                     else:
                         uids, gu, valid = dedupe_grads(
                             mids, mg, capacity=md, vocab=rps,
@@ -1542,12 +1553,13 @@ class ShardedEmbeddingCollection:
                             nt, ns, nq = opt.update_unique(
                                 shard, sl, uids, gu, valid,
                                 embedding_dim=_g.dim, sr_key=sk,
-                                qscale=qs_tl[qi])
+                                qscale=qs_tl[qi], platform=self.platform)
                             out_q.append(nq)
                         else:
                             nt, ns = opt.update_unique(
                                 shard, sl, uids, gu, valid,
-                                embedding_dim=_g.dim, sr_key=sk)
+                                embedding_dim=_g.dim, sr_key=sk,
+                                platform=self.platform)
                     out_t.append(nt)
                     out_s.append(ns)
                 return tuple(out_t), tuple(out_s), tuple(out_q)

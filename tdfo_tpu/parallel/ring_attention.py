@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tdfo_tpu.core.mesh import SEQ_AXIS, axis_size, shard_map
+from tdfo_tpu.core.mesh import (SEQ_AXIS, mesh_platform, pallas_impl,
+                                shard_map)
 
 __all__ = ["ring_attention", "ring_flash_attention", "ring_self_attention", "make_ring_attn_fn"]
 
@@ -85,7 +86,7 @@ def ring_attention(
     all-XLA counterpart of the Pallas flash kernel, composed with the ring.
     Must divide the local Tk; identical numerics either way.
     """
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     b, h, tq, dh = q.shape
     tk = k.shape[2]
     scale = 1.0 / jnp.sqrt(dh).astype(jnp.float32)
@@ -151,7 +152,7 @@ def _ring_flash_fwd_impl(q, k, v, key_valid, axis_name, block_q, block_k,
                          interpret):
     from tdfo_tpu.ops.pallas_kernels import _flash_fwd_impl
 
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % p) for i in range(p)]
     b, h, tq, dh = q.shape
 
@@ -224,7 +225,7 @@ def _ring_flash_bwd(axis_name, block_q, block_k, interpret, res, g):
     from tdfo_tpu.ops.pallas_kernels import _flash_bwd_impl
 
     q, k, v, key_valid, out, lse = res
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % p) for i in range(p)]
     b, h, tq, _ = q.shape
     lse8 = jnp.broadcast_to(lse[:, :, None, :], (b, h, 8, tq))
@@ -302,8 +303,10 @@ def ring_self_attention(
     qkv_spec = P(b_ax, h_ax, axis, None)
     valid_spec = P(b_ax, axis)
     if impl == "flash":
-        interp = jax.default_backend() != "tpu"
-        fn = partial(ring_flash_attention, axis_name=axis, interpret=interp)
+        how = pallas_impl("ring_flash_attention", mesh_platform(mesh),
+                          off_chip="interpret")
+        fn = partial(ring_flash_attention, axis_name=axis,
+                     interpret=how == "interpret")
     elif impl == "xla":
         fn = partial(ring_attention, axis_name=axis, block_k=block_k)
     else:

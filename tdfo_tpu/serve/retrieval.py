@@ -16,12 +16,15 @@ replicated):
   3. global merge: the ``k x n_shards`` candidates concatenate shard-major
      and one final ``lax.top_k`` picks the answer.
 
-Bitwise-equal to :func:`retrieval_reference` (single-device stable argsort)
-including tie-breaks: ``lax.top_k`` prefers lower indices, which within a
-shard means lower corpus position, and the shard-major merge order means
-lower shard — i.e. lower corpus position globally — exactly the stable
+Returns the ids of :func:`retrieval_reference` (single-device stable
+argsort) including tie-breaks: ``lax.top_k`` prefers lower indices, which
+within a shard means lower corpus position, and the shard-major merge order
+means lower shard — i.e. lower corpus position globally — exactly the stable
 argsort's preference.  Scores pass through selection untouched, so they are
-the per-shard matmul's f32 bits.
+the per-shard matmul's f32 values: within a few ULP of the reference's, not
+its bits — a per-shard and a whole-corpus matmul are two XLA programs, and
+which order each sums a row's D products in is the compiler's choice
+(``tests/closeness.py``).
 
 Two-stage program (``coarse_k`` > 0, the ScaNN split for int8 corpora that
 would not fit HBM at f32):
@@ -36,13 +39,13 @@ would not fit HBM at f32):
   2. RERANK: candidate corpus positions sort ascending (restoring the
      lower-position tie-break the coarse selection scrambled), full rows
      gather (CLAUDE.md: FULL-row gathers only) and dequantize, and
-     ``lax.top_k`` over EXACT per-query :func:`mips_scores` bits picks the
-     final k.  The per-query ``lax.map`` formulation is bit-identical to
-     the full-corpus matmul; the batched ``dot_general`` is NOT (measured).
+     ``lax.top_k`` over EXACT per-query :func:`mips_scores` values picks
+     the final k: no approximation beyond storage quantization, and within
+     a few ULP of the full-corpus matmul's score for the same pair.
 
 ``coarse_k >= n_items`` routes STATICALLY to the exact program (the coarse
-stage could drop nothing), so the degenerate case is bitwise-equal to the
-exact scan by construction.
+stage could drop nothing), so the degenerate case IS the exact scan — the
+same program, bitwise-equal by construction.
 """
 
 from __future__ import annotations
@@ -63,8 +66,8 @@ __all__ = ["make_retrieval", "mips_scores", "retrieval_reference"]
 def mips_scores(queries: jax.Array, vectors: jax.Array) -> jax.Array:
     """THE serving score formula: ``[B, D] x [N, D] -> [B, N]`` f32 inner
     products from bf16 operands.  One definition shared by the sharded
-    program and the reference so the bitwise-equality contract compares
-    identical arithmetic."""
+    program and the reference so the comparison is of identical arithmetic
+    (the summation order inside each compiled matmul still differs)."""
     return jax.lax.dot_general(
         queries.astype(jnp.bfloat16),
         vectors.astype(jnp.bfloat16),
@@ -104,10 +107,10 @@ def _gather_dequant(vectors, qscale, flat_pos):
 
 
 def _rerank_scores(queries, cand):
-    """Exact re-rank: ``[B, D] x [B, m, D] -> [B, m]``, bit-identical to
-    :func:`mips_scores` of the full corpus at the candidate columns.  Uses
-    a per-query ``lax.map`` of the SAME dot_general — the batched
-    formulation produces different f32 bits (measured on CPU)."""
+    """Exact re-rank: ``[B, D] x [B, m, D] -> [B, m]``, the
+    :func:`mips_scores` of the full corpus at the candidate columns to
+    within a few ULP.  Uses a per-query ``lax.map`` of the SAME dot_general,
+    which stays closest to the full-corpus matmul's summation."""
     return jax.lax.map(
         lambda qc: mips_scores(qc[0][None, :], qc[1])[0], (queries, cand))
 
@@ -315,7 +318,8 @@ def retrieval_reference(
 ) -> tuple[jax.Array, jax.Array]:
     """Single-device exact reference: full matmul + STABLE argsort (ties ->
     lowest corpus position, the same preference ``lax.top_k`` encodes).
-    The bitwise yardstick for :func:`make_retrieval` — ids AND f32 scores.
+    The yardstick for :func:`make_retrieval`: identical ids, f32 scores
+    within a few ULP (two XLA programs do not share their last bit).
     int8 corpora dequantize first: the reference scores the corpus as
     served, not the pre-quantization vectors."""
     vectors = jnp.asarray(jax.device_get(corpus.vectors))[:corpus.n_items]

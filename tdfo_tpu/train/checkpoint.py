@@ -41,6 +41,18 @@ __all__ = ["CheckpointManager", "LAYOUT_VERSION"]
 # a clear error instead of silently corrupting the resumed run.
 LAYOUT_VERSION = 3
 
+# No target size for OCDBT data files: orbax otherwise caps a chunk at
+# tensorstore's 2 GiB default, and an array shard above that is split into
+# chunks whose shape must DIVIDE the shard's.  The stacked Criteo-Kaggle
+# table is [33,762,577, 16] f32 = 2.16 GB and 33,762,577 is prime, so the
+# only "chunk" dividing its rows is one row: 33.7M chunks of 64 bytes, a save
+# that had not finished after 15 minutes (full-size rehearsal, PR 23; a
+# 16M-row table saved in 10 s).  With no target the shard stays ONE chunk, as
+# every smaller array already is: 25 s to save, 8 s to restore.  This is a
+# property of the files written, not of the state's layout — checkpoints
+# written either way restore alike (no LAYOUT_VERSION bump).
+_OCDBT_TARGET_DATA_FILE_SIZE = 0
+
 
 class CheckpointManager:
     """Step-indexed save/restore of an arbitrary train-state pytree.
@@ -63,6 +75,7 @@ class CheckpointManager:
             options=ocp.CheckpointManagerOptions(
                 max_to_keep=max_to_keep, create=True
             ),
+            item_handlers=ocp.PyTreeCheckpointHandler(),
         )
 
     def save(
@@ -95,7 +108,9 @@ class CheckpointManager:
         retry_call(
             self._mgr.save,
             step_id,
-            args=ocp.args.StandardSave(payload),
+            args=ocp.args.PyTreeSave(
+                payload,
+                ocdbt_target_data_file_size=_OCDBT_TARGET_DATA_FILE_SIZE),
             force=force,
             description=f"ckpt_save:{step_id}",
         )
@@ -207,7 +222,10 @@ class CheckpointManager:
             restored = retry_call(
                 self._mgr.restore,
                 step_id,
-                args=ocp.args.StandardRestore(abstract),
+                args=ocp.args.PyTreeRestore(
+                    item=abstract,
+                    restore_args=ocp.checkpoint_utils.construct_restore_args(
+                        abstract)),
                 description=f"ckpt_restore:{step_id}",
             )
         except (ValueError, KeyError, TypeError) as e:

@@ -385,7 +385,8 @@ class OnlineLoop:
         if self.model_kind == "seq":
             batches = [self._seq_train_batch(b) for b in batches]
         trainer, loss = self.trainer, 0.0
-        auc = AUC.empty() if trainer._train_auc_enabled else None
+        auc = (trainer._fresh_accumulator(AUC.empty())
+               if trainer._train_auc_enabled else None)
         for batch in prefetch_to_mesh(iter(batches), trainer.mesh, P("data")):
             if self.model_kind == "seq":
                 # the bert4rec step signature (trainer.py fit loop): a fixed

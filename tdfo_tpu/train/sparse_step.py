@@ -514,6 +514,7 @@ def make_sparse_train_step(
                             state.tables[tname], state.slots[tname], ulines,
                             g_u, row_lidx, row_slot, lines,
                             embedding_dim=d_t, sr_key=_sr_key(tname),
+                            platform=coll.platform,
                         ))
                     continue
                 _, uids, seg, valid = ctx
@@ -552,14 +553,14 @@ def make_sparse_train_step(
                      new_tables[qn]) = state.sparse_opt.update_unique(
                         state.tables[tname], state.slots[tname], uids, g_u,
                         valid, embedding_dim=d_t, sr_key=_sr_key(tname),
-                        qscale=state.tables[qn],
+                        qscale=state.tables[qn], platform=coll.platform,
                     )
                 else:
                     new_tables[tname], new_slots[tname] = (
                         state.sparse_opt.update_unique(
                             state.tables[tname], state.slots[tname], uids,
                             g_u, valid, embedding_dim=d_t,
-                            sr_key=_sr_key(tname),
+                            sr_key=_sr_key(tname), platform=coll.platform,
                         ))
                 continue
             all_ids, _, bound = _concat_ids(feats, cold_ids)

@@ -36,7 +36,7 @@ import math
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Any, Callable, Iterator
+from typing import Any, Callable, Iterator, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -45,7 +45,7 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from tdfo_tpu.core.config import Config
-from tdfo_tpu.core.mesh import make_mesh
+from tdfo_tpu.core.mesh import make_mesh, mesh_platform
 from tdfo_tpu.obs import counters as obs_counters
 from tdfo_tpu.obs import events as obs_events
 from tdfo_tpu.obs import trace as obs_trace
@@ -424,15 +424,19 @@ def _check_cache_overflow(overflow: dict) -> None:
 class Trainer:
     """Config-driven trainer for both workload families."""
 
-    def __init__(self, config: Config, *, log_dir: str | Path | None = None):
+    def __init__(self, config: Config, *, log_dir: str | Path | None = None,
+                 devices: Sequence[jax.Device] | None = None):
+        """``devices``: build the mesh over these instead of all of
+        ``jax.devices()`` (a one-device reference run on a multi-chip
+        host)."""
         self.config = config
-        if config.use_tpu and jax.default_backend() != "tpu":
+        self.mesh = make_mesh(config.mesh, devices=devices)
+        if config.use_tpu and mesh_platform(self.mesh) != "tpu":
             raise RuntimeError(
-                f"use_tpu = true but the jax backend is "
-                f"{jax.default_backend()!r} (TPUStrategy-resolution parity: "
-                "refuse to silently train a TPU config elsewhere)"
+                f"use_tpu = true but the mesh's devices are "
+                f"{mesh_platform(self.mesh)!r} (TPUStrategy-resolution "
+                "parity: refuse to silently train a TPU config elsewhere)"
             )
-        self.mesh = make_mesh(config.mesh)
         self.logger = MetricLogger(log_dir or config.checkpoint_dir,
                                    tensorboard=config.tensorboard,
                                    rotate_bytes=config.telemetry.log_rotate_bytes)
@@ -544,7 +548,7 @@ class Trainer:
         from tdfo_tpu.models.twotower import init_twotower
 
         cfg = self.config
-        dtype = compute_dtype(cfg.mixed_precision)
+        dtype = compute_dtype(cfg.mixed_precision, mesh_platform(self.mesh))
         model, params = init_twotower(
             jax.random.key(cfg.seed), cfg.size_map, cfg.embed_dim, dtype=dtype
         )
@@ -617,7 +621,7 @@ class Trainer:
             raise ValueError(
                 f"{cfg.model} needs vocab sizes {missing} in size_map (run preprocessing)"
             )
-        dtype = compute_dtype(cfg.mixed_precision)
+        dtype = compute_dtype(cfg.mixed_precision, mesh_platform(self.mesh))
         sharding = cfg.embedding_sharding if cfg.model_parallel else "replicated"
         if custom:
             from tdfo_tpu.models.dlrm import generic_embedding_specs
@@ -1198,7 +1202,8 @@ class Trainer:
         # (jax-flax/train_dp.py:190,219-220 parity).  Not persisted in the
         # cursor (device histograms): after a mid-epoch resume the epoch AUC
         # covers post-resume steps only.  State evolution is unaffected.
-        train_auc = AUC.empty() if self._train_auc_enabled else None
+        train_auc = (self._fresh_accumulator(AUC.empty())
+                     if self._train_auc_enabled else None)
         # pipeline_overlap carry: (transformed batch, input-dist ctx) one
         # batch ahead of training.  Not persisted in cursors: n_steps counts
         # TRAINED batches, so a resume fast-forwards past exactly those and
@@ -1404,6 +1409,13 @@ class Trainer:
         )
         return avg
 
+    def _fresh_accumulator(self, tree):
+        """A zeroed on-device accumulator (train AUC, eval sums), placed as
+        the step hands it back: replicated on the mesh.  Left uncommitted,
+        the first call compiles one program for it and the second call —
+        now fed the committed output — compiles the same step again."""
+        return jax.device_put(tree, NamedSharding(self.mesh, P()))
+
     def _run_cache_flush(self) -> dict:
         """One cache write-back dispatch.  With telemetry counters on, the
         flush program returns a third element (the flush-scoped counter
@@ -1501,11 +1513,11 @@ class Trainer:
         whole mesh (multi-host included), so this is the ``all_gather_object``
         capability (``torchrec/train.py:108-111``) with zero host collectives
         — and no per-batch ``float()`` sync stalling the eval pipeline."""
-        acc = {
+        acc = self._fresh_accumulator({
             "loss_sum": jnp.zeros(()),
             "w_sum": jnp.zeros(()),
             "auc": AUC.empty(),
-        }
+        })
         for batch in self._eval_batches():
             acc = self.eval_accum(self.state, batch, acc)
             if self._watchdog is not None:  # eval batches count as liveness
@@ -1526,6 +1538,7 @@ class Trainer:
         for k in self._METRIC_KS:
             acc[f"Recall@{k}"] = jnp.zeros(())
             acc[f"NDCG@{k}"] = jnp.zeros(())
+        acc = self._fresh_accumulator(acc)
         rename = lambda raw: {"seqs": raw["eval_seqs"], "cands": raw["candidate_items"]}
         for batch in self._eval_batches(rename, pattern=pattern):
             acc = self.eval_accum(self.state, batch, acc)

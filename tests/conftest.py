@@ -13,6 +13,10 @@ spoof_cpu_devices(8)
 import jax  # noqa: E402
 
 jax.config.update("jax_default_matmul_precision", "highest")
+# hermetic suite: launch.main / chip_smoke place a persistent compile cache
+# in the checkout (core/mesh.configure_compile_cache); tests must neither
+# fill it nor pass because of a stale entry in it
+jax.config.update("jax_enable_compilation_cache", False)
 
 import pytest  # noqa: E402
 

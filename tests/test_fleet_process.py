@@ -314,6 +314,65 @@ def test_spawn_prebinds_listener_and_detaches_child_stdio(
         adopted.close()
 
 
+def _replica_spec(tmp_path):
+    import dataclasses
+
+    from tdfo_tpu.core.config import ServingSpec
+
+    sock = tmp_path / "replica-0.sock"
+    spec = tmp_path / "replica-0.json"
+    spec.write_text(json.dumps({
+        "replica_id": 0, "socket": str(sock),
+        "store_dir": str(tmp_path / "store"),
+        "serving": dataclasses.asdict(ServingSpec()),
+        "jax_platforms": "cpu"}))
+    return sock, spec
+
+
+def test_spawned_replica_is_pinned_to_cpu_without_parent_env(
+        tmp_path, monkeypatch):
+    """One process per chip: on the chip machine ``JAX_PLATFORMS`` is unset
+    and the parent holds the chip, so the REAL spawn path must put the
+    spec's platform into the child's environment before the interpreter
+    starts (``python -m`` imports jax before ``replica_main.main`` runs — a
+    later assignment is not read).  The child reports what jax read."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    sock, spec = _replica_spec(tmp_path)
+    proc = ProcessSupervisor._spawn_child(spec)
+    try:
+        # the pre-bound listener queues this until the child has imported
+        # jax, passed its platform check and called accept
+        client = wire.connect(sock, attempts=1)
+        try:
+            wire.send_msg(client, {"type": "shutdown"})
+            assert wire.recv_msg(client) == {"type": "bye", "replica": 0}
+        finally:
+            client.close()
+        assert proc.wait(timeout=120) == 0
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+    log = (tmp_path / "replica-0.log").read_text()
+    assert "[replica 0] jax_platforms=cpu" in log, log
+
+
+def test_replica_refuses_a_platform_its_spec_does_not_name(tmp_path):
+    """Started by hand without the variable, jax has read no platform and
+    would reach for whatever accelerator is there: the child must die at
+    its check, before any backend initialises."""
+    import subprocess as sp
+    import sys
+
+    _, spec = _replica_spec(tmp_path)
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    out = sp.run([sys.executable, "-m", "tdfo_tpu.serve.replica_main",
+                  str(spec)], env=env, capture_output=True, text=True,
+                 timeout=120, cwd=Path(__file__).resolve().parents[1])
+    assert out.returncode != 0
+    assert "jax_platforms is None but the spec says 'cpu'" in out.stderr
+    assert "jax_platforms=" not in out.stdout
+
+
 # ---------------------------------------------------- loadgen disciplines
 
 

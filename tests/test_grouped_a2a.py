@@ -21,6 +21,8 @@ import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from closeness import assert_within_ulp
+
 from tdfo_tpu.ops.sparse import sparse_optimizer
 from tdfo_tpu.parallel.embedding import EmbeddingSpec, ShardedEmbeddingCollection
 from tdfo_tpu.train.sparse_step import (
@@ -118,8 +120,12 @@ def test_grouped_forward_matches_per_table_exactly(mesh8, stack):
 
 @pytest.mark.parametrize("stack", [False, True])
 def test_grouped_update_matches_sequential_reference(mesh8, stack):
-    """Bit-identical tables AND optimizer slots vs the sequential per-table
-    reference (opt.update per table on REPLICATED arrays, feature order)."""
+    """Tables AND optimizer slots match the sequential per-table reference
+    (opt.update per table on REPLICATED arrays, feature order): rows the
+    step did not touch stay bit-identical to their initial values, touched
+    rows agree within 4 ULP of the array's scale — the reference runs
+    op-by-op eagerly, the grouped update as one jitted shard_map program,
+    and two XLA programs do not share their last bit."""
     coll = _coll(mesh8, grouped=True, stack=stack)
     tables = coll.init(jax.random.PRNGKey(0))
     opt = sparse_optimizer("rowwise_adagrad", lr=0.05)
@@ -146,11 +152,18 @@ def test_grouped_update_matches_sequential_reference(mesh8, stack):
     got_t, got_s = jax.jit(
         lambda t, s, i, g: coll.grouped_update(opt, t, s, i, g)
     )(tables, slots, feats, grads)
+    touched_any = False
     for a in got_t:
-        np.testing.assert_array_equal(
-            np.asarray(ref_t[a]), np.asarray(got_t[a]), err_msg=a)
+        init, ref, got = (np.asarray(x) for x in
+                          (tables[a], ref_t[a], got_t[a]))
+        untouched = np.all(ref == init, axis=1)
+        touched_any |= bool((~untouched).any())
+        np.testing.assert_array_equal(got[untouched], init[untouched],
+                                      err_msg=a)
+        assert_within_ulp(got, ref, err_msg=a)
         for x, y in zip(ref_s[a], got_s[a]):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+            assert_within_ulp(y, x, err_msg=a)
+    assert touched_any
 
 
 def _toy_forward(dense, embs, batch):

@@ -28,6 +28,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from closeness import assert_same_topk, assert_within_ulp
+
 from tdfo_tpu.data.replay import ReplayConsumer, RequestLog
 from tdfo_tpu.models.bert4rec import (
     PAD_ID,
@@ -280,8 +282,11 @@ class TestHistoryWindow:
 def test_microbatcher_seq_panels_and_compile_pin(mesh8, tmp_path):
     """Ragged seq traffic through the frontend's bucket batcher: 2-D panel
     columns pad/unpad row-wise like CTR columns, per-request scores match
-    the direct scorer bitwise, and the jit cache stays <= len(buckets) —
-    the bounded-compile contract that makes live serving viable."""
+    the direct scorer — bitwise where the request fills its bucket (the
+    same program on the same rows), within 16 ULP of the score scale where
+    it was padded (a [bucket, T] and an [n, T] transformer forward are two
+    XLA programs) — and the jit cache stays <= len(buckets): the
+    bounded-compile contract that makes live serving viable."""
     coll, _, state = _bert4rec_sparse(mesh8)
     bundle = load_bundle(_export_seq(tmp_path / "b", coll, state))
     scorer = make_seq_scorer(bundle, mesh=mesh8)
@@ -304,7 +309,10 @@ def test_microbatcher_seq_panels_and_compile_pin(mesh8, tmp_path):
     for rid, batch in requests.items():
         ref = np.asarray(ref_scorer.score(dict(batch)))
         assert mb.results[rid].shape == ref.shape  # unpadded [n, C] panels
-        np.testing.assert_array_equal(mb.results[rid], ref)
+        if len(ref) in buckets:  # shipped alone, unpadded: same program
+            np.testing.assert_array_equal(mb.results[rid], ref)
+        else:
+            assert_within_ulp(mb.results[rid], ref, max_ulp=16, err_msg=rid)
 
 
 # ------------------------------------------------------ item-table corpus
@@ -343,8 +351,9 @@ def test_item_corpus_layout(mesh8, tmp_path):
 
 def test_item_retrieval_exact_matches_reference(mesh8, tmp_path):
     """Sharded exact MIPS over the item corpus, queried with the scorer's
-    own last-position hidden states, is bitwise-equal (ids AND f32 scores)
-    to the single-device stable-argsort reference."""
+    own last-position hidden states, returns exactly the ids of the
+    single-device stable-argsort reference and f32 scores within 4 ULP of
+    it (two XLA programs)."""
     coll, _, state = _bert4rec_sparse(mesh8)
     bundle = load_bundle(_export_seq(tmp_path / "b", coll, state))
     scorer = make_seq_scorer(bundle, mesh=mesh8)
@@ -353,10 +362,7 @@ def test_item_retrieval_exact_matches_reference(mesh8, tmp_path):
     for k in (1, 10):
         scores, ids = make_retrieval(corpus, mesh=mesh8, top_k=k)(q)
         ref_s, ref_i = retrieval_reference(q, corpus, top_k=k)
-        np.testing.assert_array_equal(np.asarray(ids), np.asarray(ref_i))
-        np.testing.assert_array_equal(
-            np.asarray(scores).view(np.uint32),
-            np.asarray(ref_s).view(np.uint32))
+        assert_same_topk(ids, scores, ref_i, ref_s)
 
 
 def test_item_retrieval_ranks_like_the_served_scores(mesh8, tmp_path):
