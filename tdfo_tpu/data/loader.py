@@ -642,6 +642,8 @@ def prefetch_to_mesh(it, mesh, pspec=None, *, size: int = 2):
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from tdfo_tpu.obs.trace import phase
+
     sharding = NamedSharding(mesh, pspec if pspec is not None else P("data"))
 
     def put(batch):
@@ -652,17 +654,24 @@ def prefetch_to_mesh(it, mesh, pspec=None, *, size: int = 2):
             }
         return jax.device_put(batch, sharding)
 
+    def put_next() -> bool:
+        """One host batch out of the stream (``loader_next``: decode, shuffle
+        pool, stacking) and onto the mesh (``h2d_put``).  The two phases
+        close before the caller yields."""
+        with phase("loader_next"):
+            batch = next(it, None)
+        if batch is None:
+            return False
+        with phase("h2d_put"):
+            q.append(put(batch))
+        return True
+
     q = collections.deque()
     it = iter(it)
-    try:
-        for _ in range(size):
-            q.append(put(next(it)))
-    except StopIteration:
-        pass
+    for _ in range(size):
+        if not put_next():
+            break
     while q:
         b = q.popleft()
-        try:
-            q.append(put(next(it)))
-        except StopIteration:
-            pass
+        put_next()
         yield b

@@ -36,16 +36,26 @@ via ``clock()``/``elapsed_ms()``/``elapsed_s()`` below, the single
 sanctioned home for monotonic differencing so host-loop timing all flows
 through one auditable site (the ``time.time()`` twin of this rule is
 ``tests/test_quality.py::test_no_wall_clock_differencing_around_device_work``).
+
+Train-loop phases (``phase``/``epoch_phases``/``epoch_history``, the second
+half of this module) are a different shape for a different rate: a JSONL
+append per record is right for an online cycle and wrong for a 6 ms step, so
+a phase is a ``jax.profiler.TraceAnnotation`` (``tdfo:<name>``: free while no
+profiler session runs, on the device trace's clock while one does) plus an
+in-memory per-epoch accumulator that is always on and touches no file.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import threading
 import time
 from pathlib import Path
 from typing import Iterator
+
+from jax.profiler import TraceAnnotation
 
 from tdfo_tpu.utils.logrotate import maybe_rotate_path
 
@@ -143,3 +153,144 @@ def span(component: str, kind: str, **fields) -> Iterator[dict]:
     finally:
         emit(component, kind, dur_ms=round(elapsed_ms(t0), 3),
              **{**fields, **extra})
+
+
+# ------------------------------------------------------- train-loop phases
+
+HISTORY_EPOCHS = 64
+_HISTORY: collections.deque = collections.deque(maxlen=HISTORY_EPOCHS)
+_now = time.monotonic  # the phases' clock (tests substitute a fake)
+_session_on = TraceAnnotation.is_enabled  # the profiler's own atomic load
+
+
+class _Thread(threading.local):
+    epoch = None  # the accumulator open on this thread (a class default:
+    #               a missing attribute costs a raised AttributeError a read)
+
+
+_THREAD = _Thread()
+
+
+def phase(name: str):
+    """``with phase("dispatch"): ...`` — one named stretch of the train loop.
+
+    A ``jax.profiler.TraceAnnotation("tdfo:<name>")`` wherever it runs: an
+    atomic load while no profiler session runs, a host span on the device
+    trace's clock while one does.  If an ``epoch_phases`` accumulator is
+    open ON THIS THREAD the duration is also added to it under ``name``
+    (seconds, count, longest single occurrence, and self time: the part no
+    nested phase covers); anywhere else (eval, serving, another thread, a benchmark's
+    probe of the stream) it only annotates.  Class-based, no generator: it
+    runs several times a step.  A phase closes on the thread and inside the
+    ``next()`` it opened in (never hold one across a ``yield``), and does
+    not nest inside a phase of its own name."""
+    acc = _THREAD.epoch
+    if acc is None:
+        return TraceAnnotation("tdfo:" + name)
+    ph = acc._phases.get(name)
+    if ph is None:
+        ph = acc._phases[name] = _Phase(name, acc)
+    return ph
+
+
+class _Phase:
+    """One name's span and sums inside one epoch accumulator."""
+
+    __slots__ = ("_label", "_ann", "_acc", "_outer", "_inner_s", "_t0",
+                 "seconds", "count", "max_seconds", "self_seconds")
+
+    def __init__(self, name: str, acc: "epoch_phases"):
+        self._label = "tdfo:" + name
+        self._ann = None
+        self._acc = acc
+        self.seconds = self.max_seconds = self.self_seconds = 0.0
+        self.count = 0
+
+    def __enter__(self):
+        if _session_on():  # an annotation object serves one span only
+            self._ann = TraceAnnotation(self._label)
+            self._ann.__enter__()
+        acc = self._acc
+        self._outer, acc._open = acc._open, self
+        self._inner_s = 0.0
+        self._t0 = _now()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dt = _now() - self._t0
+        self._acc._open = outer = self._outer
+        if outer is None:
+            self._acc._top_s += dt
+        else:
+            outer._inner_s += dt
+        self.seconds += dt
+        self.count += 1
+        if dt > self.max_seconds:
+            self.max_seconds = dt
+        self.self_seconds += dt - self._inner_s
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        return False
+
+
+class epoch_phases:
+    """The accumulator round ONE ``_train_epoch`` call: ``with
+    epoch_phases(epoch) as ep: ...; rec = ep.close(steps)``.
+
+    ``close`` stops the epoch's clock and appends the record ::
+
+        {"epoch", "steps", "loop_s", "loop_self_s",
+         "phases": {name: [seconds, count, max_seconds]},
+         "self_s": {name: seconds}}
+
+    to the bounded in-memory history (``epoch_history()``).  ``loop_s`` is
+    the whole call on the phases' clock; ``loop_self_s`` is ``loop_s`` minus
+    the outermost phases: the Python loop's own time.  Leaving the ``with``
+    without ``close`` (an epoch that raised) drops the accumulator and
+    records nothing."""
+
+    def __init__(self, epoch: int):
+        self.epoch = int(epoch)
+        self._phases: dict[str, _Phase] = {}
+        self._open: _Phase | None = None
+        self._top_s = 0.0
+
+    def __enter__(self):
+        if _THREAD.epoch is not None:
+            raise RuntimeError("epoch_phases: an epoch is already open on "
+                               "this thread")
+        _THREAD.epoch = self
+        self._t0 = _now()
+        return self
+
+    def close(self, steps: int) -> dict:
+        loop_s = _now() - self._t0
+        _THREAD.epoch = None
+        record = {
+            "epoch": self.epoch, "steps": int(steps), "loop_s": loop_s,
+            "loop_self_s": loop_s - self._top_s,
+            "phases": {k: [p.seconds, p.count, p.max_seconds]
+                       for k, p in self._phases.items()},
+            "self_s": {k: p.self_seconds for k, p in self._phases.items()},
+        }
+        with _LOCK:
+            _HISTORY.append(record)
+        return record
+
+    def __exit__(self, exc_type, exc, tb):
+        if _THREAD.epoch is self:
+            _THREAD.epoch = None
+        return False
+
+
+def epoch_history() -> list[dict]:
+    """The records of the last ``HISTORY_EPOCHS`` closed epochs of this
+    process, oldest first.  ``configure`` leaves them alone."""
+    with _LOCK:
+        return list(_HISTORY)
+
+
+def reset_epoch_history() -> None:
+    with _LOCK:
+        _HISTORY.clear()

@@ -201,6 +201,7 @@ def _flash_fwd_impl(q, k, v, key_valid, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((b, h, t, dh), q.dtype),
         ] + ([jax.ShapeDtypeStruct((b, h, 8, t), jnp.float32)] if with_lse else []),
         interpret=interpret,
+        name="flash_fwd",
     )(
         jnp.broadcast_to(key_valid.astype(jnp.float32)[:, None, :], (b, 8, t)),
         q, k, v,
@@ -350,6 +351,7 @@ def _flash_bwd_impl(q, k, v, key_valid, out, lse, g, block_q, block_k, interpret
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(mask8, lse, delta8, q, k, v, g)
 
     dk, dv = pl.pallas_call(
@@ -374,6 +376,7 @@ def _flash_bwd_impl(q, k, v, key_valid, out, lse, g, block_q, block_k, interpret
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(mask8, lse, delta8, q, k, v, g)
     return dq, dk, dv
 
@@ -1112,6 +1115,7 @@ def fat_line_update(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        name="fat_line_update",
     )(ulines_p, corr, *seed_ops, gp_p, *tl_ops, fat)
 
 
@@ -1353,5 +1357,6 @@ def fat_line_update_routed(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        name="fat_line_update_routed",
     )(ulines, sdiv, corr, *seed_ops, tsi, lines,
       g_u.astype(jnp.float32), fat)

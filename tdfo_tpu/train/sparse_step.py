@@ -330,115 +330,117 @@ def make_sparse_train_step(
             return forward(dense_params, embs, batch)
 
         dedup_ctx: dict[str, tuple] = {}
-        if dedup_lookup:
-            embs = {}
-            for tname, feats in by_table_static.items():
-                # column-sharded tables shard the EMBEDDING dim: the compact
-                # gather would drop the activation sharding the default
-                # lookup constrains — keep them on the default path (their
-                # update falls back too, since no ctx entry exists)
-                if (tname in coll.specs
-                        and coll.specs[tname].sharding == "column"):
-                    embs.update(_overlay_lookup(coll.lookup(
-                        state.tables, {f: ids[f] for f in feats}, mode=mode),
-                        feats))
-                    continue
-                table = state.tables[tname]
-                d = coll.array_embedding_dim(tname)
-                fat = table.ndim == 3
-                all_ids, sizes, bound = _concat_ids(feats, cold_ids)
-                obs_counters.emit(f"emb/{tname}/touched_ids",
-                                  lambda a=all_ids: (a >= 0).sum())
-                total = all_ids.shape[0]
-                # +1 slack: negative (padding) ids dedupe to ONE sentinel
-                # slot beyond the real-id bound; without it the expand would
-                # clamp the sentinel seg onto a real row's slot
-                cap = (-(-(bound + 1) // 8) * 8) if bound + 1 < total else None
-                if fat:
-                    # routed fat-line flow: ONE sort yields the row-level
-                    # expand key AND the line grouping.  Forward: gather
-                    # whole packed LINES straight off the 3D array (the
-                    # fast TPU gather — reshaping the table to a row view
-                    # materialises a multi-GB copy), expand per distinct
-                    # row from the SMALL gathered block, slot-select, then
-                    # expand per batch position.  Sentinel rows resolve to
-                    # line 0 slot 0 = row 0, the default lookup's clip.
-                    from tdfo_tpu.ops.sparse import dedupe_rows_and_lines
+        with jax.named_scope("emb_lookup"):
+            if dedup_lookup:
+                embs = {}
+                for tname, feats in by_table_static.items():
+                    # column-sharded tables shard the EMBEDDING dim: the compact
+                    # gather would drop the activation sharding the default
+                    # lookup constrains — keep them on the default path (their
+                    # update falls back too, since no ctx entry exists)
+                    if (tname in coll.specs
+                            and coll.specs[tname].sharding == "column"):
+                        embs.update(_overlay_lookup(coll.lookup(
+                            state.tables, {f: ids[f] for f in feats}, mode=mode),
+                            feats))
+                        continue
+                    table = state.tables[tname]
+                    d = coll.array_embedding_dim(tname)
+                    fat = table.ndim == 3
+                    all_ids, sizes, bound = _concat_ids(feats, cold_ids)
+                    obs_counters.emit(f"emb/{tname}/touched_ids",
+                                      lambda a=all_ids: (a >= 0).sum())
+                    total = all_ids.shape[0]
+                    # +1 slack: negative (padding) ids dedupe to ONE sentinel
+                    # slot beyond the real-id bound; without it the expand would
+                    # clamp the sentinel seg onto a real row's slot
+                    cap = (-(-(bound + 1) // 8) * 8) if bound + 1 < total else None
+                    if fat:
+                        # routed fat-line flow: ONE sort yields the row-level
+                        # expand key AND the line grouping.  Forward: gather
+                        # whole packed LINES straight off the 3D array (the
+                        # fast TPU gather — reshaping the table to a row view
+                        # materialises a multi-GB copy), expand per distinct
+                        # row from the SMALL gathered block, slot-select, then
+                        # expand per batch position.  Sentinel rows resolve to
+                        # line 0 slot 0 = row 0, the default lookup's clip.
+                        from tdfo_tpu.ops.sparse import dedupe_rows_and_lines
 
-                    lay = coll.fat_layout_for(tname)
-                    _, _, bound_l = _concat_ids(feats, cold_ids,
-                                                rows_per_line=lay.r)
-                    cap_r = cap if cap is not None else total
-                    cap_l = min(cap_r, -(-(bound_l + 1) // 8) * 8)
-                    seg, ulines, row_lidx, row_slot = dedupe_rows_and_lines(
-                        all_ids.astype(jnp.int32), capacity_rows=cap_r,
-                        capacity_lines=cap_l, rows_per_line=lay.r,
-                    )
-                    oob = jnp.iinfo(jnp.int32).max
-                    lines = jnp.take(
-                        table, jnp.where(ulines < oob, ulines, 0), axis=0)
-                    flat = lines.reshape(cap_l, lay.tiles * 128)
-                    rowlines = jnp.take(
-                        flat, jnp.minimum(row_lidx, cap_l - 1), axis=0)
-                    # int8 byte lines slot-select codes AND the adjacent 8
-                    # sidecar bytes, then decode the small selected block
-                    span = d + 8 if lay.dtype == "int8" else d
-                    rows = rowlines[:, :span]
-                    for s in range(1, lay.r):
-                        rows = jnp.where(
-                            (row_slot == s)[:, None],
-                            rowlines[:, s * lay.w: s * lay.w + span], rows)
-                    if lay.dtype == "int8":
-                        rows = dequantize_rows(
-                            rows[:, :d], bytes_to_f32(rows[:, d:span]))
-                    dedup_ctx[tname] = ("routed", ulines, seg, row_lidx,
-                                        row_slot, lines)
-                    obs_counters.emit(f"emb/{tname}/unique_lines",
-                                      lambda u=ulines: (u < oob).sum())
-                else:
-                    uids, seg, valid = dedupe_ids(
-                        all_ids.astype(jnp.int32), capacity=cap,
-                        max_distinct=cap,
-                    )
-                    rows = jnp.take(table, jnp.where(valid, uids, 0), axis=0)
-                    if coll.array_is_int8(tname):
-                        # sidecar rides the same compact gather; dequantize
-                        # the small block so downstream expand stays f32
-                        rows = dequantize_rows(rows, jnp.take(
-                            state.tables[qscale_name(tname)],
-                            jnp.where(valid, uids, 0), axis=0))
-                    if tname in cached:
-                        # serve cached (authoritative) rows into the compact
-                        # gather — sentinel slots clamp to row 0 exactly like
-                        # the eager gather, so they overlay to row 0's
-                        # authoritative value too
-                        rows = cache_overlay_rows(
-                            _pin_replicated(
-                                coll.mesh,
-                                state.slots[CACHE_PREFIX + tname]),
-                            jnp.where(valid, uids, 0),
-                            rows, mesh=coll.mesh)
-                    dedup_ctx[tname] = ("rows", uids, seg, valid)
-                    obs_counters.emit(f"emb/{tname}/unique_rows",
-                                      lambda v=valid: v.sum())
-                off = 0
-                # dequantize after the compact gather (identity for f32):
-                # the model interface is f32 whatever the storage dtype
-                rows = rows.astype(jnp.float32)
-                for f, n_f in zip(feats, sizes):
-                    e = jnp.take(rows, seg[off:off + n_f], axis=0)
-                    e = e.reshape(*ids[f].shape, e.shape[-1])
-                    embs[f] = _merge_hot(f, e)
-                    off += n_f
-            for f in full_hot_feats:  # no cold side: hot gather only
-                embs[f] = _merge_hot(f, None)
-        else:
-            # coll.lookup routes hot/cold internally (eval shares that path)
-            embs = _overlay_lookup(
-                coll.lookup(state.tables, ids, mode=mode), features)
-        loss, (g_dense, g_embs) = jax.value_and_grad(
-            loss_from_embs, argnums=(0, 1), has_aux=with_aux
-        )(state.dense_params, embs)
+                        lay = coll.fat_layout_for(tname)
+                        _, _, bound_l = _concat_ids(feats, cold_ids,
+                                                    rows_per_line=lay.r)
+                        cap_r = cap if cap is not None else total
+                        cap_l = min(cap_r, -(-(bound_l + 1) // 8) * 8)
+                        seg, ulines, row_lidx, row_slot = dedupe_rows_and_lines(
+                            all_ids.astype(jnp.int32), capacity_rows=cap_r,
+                            capacity_lines=cap_l, rows_per_line=lay.r,
+                        )
+                        oob = jnp.iinfo(jnp.int32).max
+                        lines = jnp.take(
+                            table, jnp.where(ulines < oob, ulines, 0), axis=0)
+                        flat = lines.reshape(cap_l, lay.tiles * 128)
+                        rowlines = jnp.take(
+                            flat, jnp.minimum(row_lidx, cap_l - 1), axis=0)
+                        # int8 byte lines slot-select codes AND the adjacent 8
+                        # sidecar bytes, then decode the small selected block
+                        span = d + 8 if lay.dtype == "int8" else d
+                        rows = rowlines[:, :span]
+                        for s in range(1, lay.r):
+                            rows = jnp.where(
+                                (row_slot == s)[:, None],
+                                rowlines[:, s * lay.w: s * lay.w + span], rows)
+                        if lay.dtype == "int8":
+                            rows = dequantize_rows(
+                                rows[:, :d], bytes_to_f32(rows[:, d:span]))
+                        dedup_ctx[tname] = ("routed", ulines, seg, row_lidx,
+                                            row_slot, lines)
+                        obs_counters.emit(f"emb/{tname}/unique_lines",
+                                          lambda u=ulines: (u < oob).sum())
+                    else:
+                        uids, seg, valid = dedupe_ids(
+                            all_ids.astype(jnp.int32), capacity=cap,
+                            max_distinct=cap,
+                        )
+                        rows = jnp.take(table, jnp.where(valid, uids, 0), axis=0)
+                        if coll.array_is_int8(tname):
+                            # sidecar rides the same compact gather; dequantize
+                            # the small block so downstream expand stays f32
+                            rows = dequantize_rows(rows, jnp.take(
+                                state.tables[qscale_name(tname)],
+                                jnp.where(valid, uids, 0), axis=0))
+                        if tname in cached:
+                            # serve cached (authoritative) rows into the compact
+                            # gather — sentinel slots clamp to row 0 exactly like
+                            # the eager gather, so they overlay to row 0's
+                            # authoritative value too
+                            rows = cache_overlay_rows(
+                                _pin_replicated(
+                                    coll.mesh,
+                                    state.slots[CACHE_PREFIX + tname]),
+                                jnp.where(valid, uids, 0),
+                                rows, mesh=coll.mesh)
+                        dedup_ctx[tname] = ("rows", uids, seg, valid)
+                        obs_counters.emit(f"emb/{tname}/unique_rows",
+                                          lambda v=valid: v.sum())
+                    off = 0
+                    # dequantize after the compact gather (identity for f32):
+                    # the model interface is f32 whatever the storage dtype
+                    rows = rows.astype(jnp.float32)
+                    for f, n_f in zip(feats, sizes):
+                        e = jnp.take(rows, seg[off:off + n_f], axis=0)
+                        e = e.reshape(*ids[f].shape, e.shape[-1])
+                        embs[f] = _merge_hot(f, e)
+                        off += n_f
+                for f in full_hot_feats:  # no cold side: hot gather only
+                    embs[f] = _merge_hot(f, None)
+            else:
+                # coll.lookup routes hot/cold internally (eval shares that path)
+                embs = _overlay_lookup(
+                    coll.lookup(state.tables, ids, mode=mode), features)
+        with jax.named_scope("dense_fwd_bwd"):
+            loss, (g_dense, g_embs) = jax.value_and_grad(
+                loss_from_embs, argnums=(0, 1), has_aux=with_aux
+            )(state.dense_params, embs)
         aux = None
         if with_aux:
             loss, aux = loss
@@ -453,8 +455,9 @@ def make_sparse_train_step(
                 (state.dense_params, state.tables)))
 
         # dense half: optax
-        updates, new_opt_state = state.tx.update(g_dense, state.opt_state, state.dense_params)
-        new_dense = optax.apply_updates(state.dense_params, updates)
+        with jax.named_scope("dense_update"):
+            updates, new_opt_state = state.tx.update(g_dense, state.opt_state, state.dense_params)
+            new_dense = optax.apply_updates(state.dense_params, updates)
 
         # sparse half: group features by table, one row-sparse update each.
         # _sr_key: stochastic-rounding key per narrow-storage array, derived
@@ -464,175 +467,179 @@ def make_sparse_train_step(
             return (_make_sr_key(state.step, aname)
                     if _array_is_narrow(state, aname) else None)
 
-        new_tables = dict(state.tables)
-        new_slots = dict(state.slots)
-        if grouped_feats:
-            # one grouped backward exchange for every row/table-sharded
-            # feature: 2 collectives total (ids + grads) vs 2 per array.
-            # One base key serves the whole exchange (grouped_update folds
-            # per-array table ids itself)
-            g_narrow = any(_array_is_narrow(state, a) for a in grouped_arrays)
-            gt, gs = coll.grouped_update(
-                state.sparse_opt, state.tables, state.slots,
-                {f: ids[f] for f in grouped_feats},
-                {f: g_embs[f] for f in grouped_feats},
-                sr_key=(_make_sr_key(state.step, "__grouped_update__")
-                        if g_narrow else None))
-            new_tables.update(gt)
-            new_slots.update(gs)
-        for tname, feats in by_table_static.items():
-            grad_list = [
-                g_embs[f].reshape(-1, g_embs[f].shape[-1]) for f in feats
-            ]
-            all_grads = jnp.concatenate(grad_list)
-            # small-vocab adam tables keep the one-hot MXU tier (raw ids,
-            # no scatter — ~10x the per-row scatter formulation update_unique
-            # would fall back to)
-            small_adam = (
-                state.sparse_opt.kind == "adam"
-                and state.tables[tname].ndim == 2
-                and state.tables[tname].shape[0]
-                <= state.sparse_opt.small_vocab_threshold
-            )
-            if (tname in dedup_ctx and not small_adam
-                    and not coll.needs_shard_map_update(tname)):
-                # shared-dedupe fast path: segment-sum by the forward's seg
-                # and feed the optimizer tiers directly (no second sort)
-                ctx = dedup_ctx[tname]
-                d_t = coll.array_embedding_dim(tname)
-                if ctx[0] == "routed":
-                    # row-level segment-sum (the cheap space) + in-kernel
-                    # routing: the whole table update has no XLA scatter,
-                    # and the kernel reuses the forward's line gather
-                    _, ulines, seg, row_lidx, row_slot, lines = ctx
-                    g_u = jax.ops.segment_sum(
-                        all_grads.astype(jnp.float32), seg,
-                        num_segments=row_lidx.shape[0],
-                    )
-                    new_tables[tname], new_slots[tname] = (
-                        state.sparse_opt.update_routed(
-                            state.tables[tname], state.slots[tname], ulines,
-                            g_u, row_lidx, row_slot, lines,
-                            embedding_dim=d_t, sr_key=_sr_key(tname),
-                            platform=coll.platform,
-                        ))
-                    continue
-                _, uids, seg, valid = ctx
-                g_u = jax.ops.segment_sum(
-                    all_grads, seg, num_segments=uids.shape[0]
+        with jax.named_scope("emb_update"):
+            new_tables = dict(state.tables)
+            new_slots = dict(state.slots)
+            if grouped_feats:
+                # one grouped backward exchange for every row/table-sharded
+                # feature: 2 collectives total (ids + grads) vs 2 per array.
+                # One base key serves the whole exchange (grouped_update folds
+                # per-array table ids itself)
+                g_narrow = any(_array_is_narrow(state, a) for a in grouped_arrays)
+                gt, gs = coll.grouped_update(
+                    state.sparse_opt, state.tables, state.slots,
+                    {f: ids[f] for f in grouped_feats},
+                    {f: g_embs[f] for f in grouped_feats},
+                    sr_key=(_make_sr_key(state.step, "__grouped_update__")
+                            if g_narrow else None))
+                new_tables.update(gt)
+                new_slots.update(gs)
+            for tname, feats in by_table_static.items():
+                grad_list = [
+                    g_embs[f].reshape(-1, g_embs[f].shape[-1]) for f in feats
+                ]
+                all_grads = jnp.concatenate(grad_list)
+                # small-vocab adam tables keep the one-hot MXU tier (raw ids,
+                # no scatter — ~10x the per-row scatter formulation update_unique
+                # would fall back to)
+                small_adam = (
+                    state.sparse_opt.kind == "adam"
+                    and state.tables[tname].ndim == 2
+                    and state.tables[tname].shape[0]
+                    <= state.sparse_opt.small_vocab_threshold
                 )
-                g_u = jnp.where(valid[:, None], g_u, 0.0)
-                if tname in cached:
-                    # cached tier: admit misses (gather-only), update in
-                    # the cache — the big table and slot rows stay
-                    # untouched until the coalesced flush.  All cache-math
-                    # operands pin replicated (see _pin_replicated).
+                if (tname in dedup_ctx and not small_adam
+                        and not coll.needs_shard_map_update(tname)):
+                    # shared-dedupe fast path: segment-sum by the forward's seg
+                    # and feed the optimizer tiers directly (no second sort)
+                    ctx = dedup_ctx[tname]
+                    d_t = coll.array_embedding_dim(tname)
+                    if ctx[0] == "routed":
+                        # row-level segment-sum (the cheap space) + in-kernel
+                        # routing: the whole table update has no XLA scatter,
+                        # and the kernel reuses the forward's line gather
+                        _, ulines, seg, row_lidx, row_slot, lines = ctx
+                        with jax.named_scope("segment_sum"):
+                            g_u = jax.ops.segment_sum(
+                                all_grads.astype(jnp.float32), seg,
+                                num_segments=row_lidx.shape[0],
+                            )
+                        new_tables[tname], new_slots[tname] = (
+                            state.sparse_opt.update_routed(
+                                state.tables[tname], state.slots[tname], ulines,
+                                g_u, row_lidx, row_slot, lines,
+                                embedding_dim=d_t, sr_key=_sr_key(tname),
+                                platform=coll.platform,
+                            ))
+                        continue
+                    _, uids, seg, valid = ctx
+                    with jax.named_scope("segment_sum"):
+                        g_u = jax.ops.segment_sum(
+                            all_grads, seg, num_segments=uids.shape[0]
+                        )
+                    g_u = jnp.where(valid[:, None], g_u, 0.0)
+                    if tname in cached:
+                        # cached tier: admit misses (gather-only), update in
+                        # the cache — the big table and slot rows stay
+                        # untouched until the coalesced flush.  All cache-math
+                        # operands pin replicated (see _pin_replicated).
+                        ck = CACHE_PREFIX + tname
+                        u_r, g_r, v_r = _pin_replicated(
+                            coll.mesh, (uids, g_u, valid))
+                        qsc = (state.tables[qscale_name(tname)]
+                               if coll.array_is_int8(tname) else None)
+                        with obs_counters.scope(f"emb/{tname}/"):
+                            new_cache, new_slots[tname] = (
+                                state.sparse_opt.cache_update_unique(
+                                    _pin_replicated(coll.mesh, state.slots[ck]),
+                                    state.tables[tname],
+                                    state.slots[tname], u_r, g_r, v_r,
+                                    step=state.step, sr_key=_sr_key(tname),
+                                    mesh=coll.mesh, qscale=qsc,
+                                ))
+                        new_slots[ck] = _pin_replicated(coll.mesh, new_cache)
+                        continue
+                    if (coll.array_is_int8(tname)
+                            and state.tables[tname].ndim == 2):
+                        # plain 2D int8: the (scale, offset) sidecar is a
+                        # separate array; fat int8 carries it in-line and
+                        # never threads qscale
+                        qn = qscale_name(tname)
+                        (new_tables[tname], new_slots[tname],
+                         new_tables[qn]) = state.sparse_opt.update_unique(
+                            state.tables[tname], state.slots[tname], uids, g_u,
+                            valid, embedding_dim=d_t, sr_key=_sr_key(tname),
+                            qscale=state.tables[qn], platform=coll.platform,
+                        )
+                    else:
+                        new_tables[tname], new_slots[tname] = (
+                            state.sparse_opt.update_unique(
+                                state.tables[tname], state.slots[tname], uids,
+                                g_u, valid, embedding_dim=d_t,
+                                sr_key=_sr_key(tname), platform=coll.platform,
+                            ))
+                    continue
+                all_ids, _, bound = _concat_ids(feats, cold_ids)
+                obs_counters.emit(f"emb/{tname}/touched_ids",
+                                  lambda a=all_ids: (a >= 0).sum())
+                # dedupe capacity = the proven bound when it is tighter than the
+                # id count: scatter cost scales with SLOTS, so stacked many-table
+                # arrays (e.g. DLRM-Criteo, where small tables are fully covered
+                # every step) save ~half the update cost
+                total = all_ids.shape[0]
+                md = -(-bound // 8) * 8 if bound < total else None
+                if tname in cached and not small_adam:
+                    # cached tier: the SAME dedupe (bit-identical summed grads)
+                    # feeds the cache update; no big array is written.  All
+                    # cache-math operands pin replicated (see _pin_replicated).
                     ck = CACHE_PREFIX + tname
-                    u_r, g_r, v_r = _pin_replicated(
-                        coll.mesh, (uids, g_u, valid))
+                    i_r, g_r = _pin_replicated(
+                        coll.mesh, (all_ids, all_grads))
                     qsc = (state.tables[qscale_name(tname)]
                            if coll.array_is_int8(tname) else None)
                     with obs_counters.scope(f"emb/{tname}/"):
                         new_cache, new_slots[tname] = (
-                            state.sparse_opt.cache_update_unique(
+                            state.sparse_opt.cache_update(
                                 _pin_replicated(coll.mesh, state.slots[ck]),
                                 state.tables[tname],
-                                state.slots[tname], u_r, g_r, v_r,
-                                step=state.step, sr_key=_sr_key(tname),
-                                mesh=coll.mesh, qscale=qsc,
+                                state.slots[tname], i_r, g_r,
+                                step=state.step, capacity=md, max_distinct=md,
+                                sr_key=_sr_key(tname), mesh=coll.mesh,
+                                qscale=qsc,
                             ))
                     new_slots[ck] = _pin_replicated(coll.mesh, new_cache)
                     continue
+                # sharding-aware routing: fused row-sharded tables update inside
+                # an explicit shard_map (Pallas has no GSPMD partition rule)
                 if (coll.array_is_int8(tname)
                         and state.tables[tname].ndim == 2):
-                    # plain 2D int8: the (scale, offset) sidecar is a
-                    # separate array; fat int8 carries it in-line and
-                    # never threads qscale
+                    # plain 2D int8 threads the separate qscale sidecar; fat
+                    # int8 byte containers carry it in-line
                     qn = qscale_name(tname)
                     (new_tables[tname], new_slots[tname],
-                     new_tables[qn]) = state.sparse_opt.update_unique(
-                        state.tables[tname], state.slots[tname], uids, g_u,
-                        valid, embedding_dim=d_t, sr_key=_sr_key(tname),
-                        qscale=state.tables[qn], platform=coll.platform,
+                     new_tables[qn]) = coll.sparse_update(
+                        state.sparse_opt, tname,
+                        state.tables[tname], state.slots[tname], all_ids,
+                        all_grads, max_distinct=md, sr_key=_sr_key(tname),
+                        qscale=state.tables[qn],
                     )
                 else:
-                    new_tables[tname], new_slots[tname] = (
-                        state.sparse_opt.update_unique(
-                            state.tables[tname], state.slots[tname], uids,
-                            g_u, valid, embedding_dim=d_t,
-                            sr_key=_sr_key(tname), platform=coll.platform,
-                        ))
-                continue
-            all_ids, _, bound = _concat_ids(feats, cold_ids)
-            obs_counters.emit(f"emb/{tname}/touched_ids",
-                              lambda a=all_ids: (a >= 0).sum())
-            # dedupe capacity = the proven bound when it is tighter than the
-            # id count: scatter cost scales with SLOTS, so stacked many-table
-            # arrays (e.g. DLRM-Criteo, where small tables are fully covered
-            # every step) save ~half the update cost
-            total = all_ids.shape[0]
-            md = -(-bound // 8) * 8 if bound < total else None
-            if tname in cached and not small_adam:
-                # cached tier: the SAME dedupe (bit-identical summed grads)
-                # feeds the cache update; no big array is written.  All
-                # cache-math operands pin replicated (see _pin_replicated).
-                ck = CACHE_PREFIX + tname
-                i_r, g_r = _pin_replicated(
-                    coll.mesh, (all_ids, all_grads))
-                qsc = (state.tables[qscale_name(tname)]
-                       if coll.array_is_int8(tname) else None)
-                with obs_counters.scope(f"emb/{tname}/"):
-                    new_cache, new_slots[tname] = (
-                        state.sparse_opt.cache_update(
-                            _pin_replicated(coll.mesh, state.slots[ck]),
-                            state.tables[tname],
-                            state.slots[tname], i_r, g_r,
-                            step=state.step, capacity=md, max_distinct=md,
-                            sr_key=_sr_key(tname), mesh=coll.mesh,
-                            qscale=qsc,
-                        ))
-                new_slots[ck] = _pin_replicated(coll.mesh, new_cache)
-                continue
-            # sharding-aware routing: fused row-sharded tables update inside
-            # an explicit shard_map (Pallas has no GSPMD partition rule)
-            if (coll.array_is_int8(tname)
-                    and state.tables[tname].ndim == 2):
-                # plain 2D int8 threads the separate qscale sidecar; fat
-                # int8 byte containers carry it in-line
-                qn = qscale_name(tname)
-                (new_tables[tname], new_slots[tname],
-                 new_tables[qn]) = coll.sparse_update(
-                    state.sparse_opt, tname,
-                    state.tables[tname], state.slots[tname], all_ids,
-                    all_grads, max_distinct=md, sr_key=_sr_key(tname),
-                    qscale=state.tables[qn],
-                )
-            else:
-                new_tables[tname], new_slots[tname] = coll.sparse_update(
-                    state.sparse_opt, tname,
-                    state.tables[tname], state.slots[tname], all_ids,
-                    all_grads, max_distinct=md, sr_key=_sr_key(tname),
-                )
+                    new_tables[tname], new_slots[tname] = coll.sparse_update(
+                        state.sparse_opt, tname,
+                        state.tables[tname], state.slots[tname], all_ids,
+                        all_grads, max_distinct=md, sr_key=_sr_key(tname),
+                    )
 
         # hot-head updates: per logical table, ONE one-hot MXU contraction
         # merges duplicates and a full dense [K, D] read-modify-write
         # applies the optimizer — no sort, no dedupe, no scatter (the
         # power-law head is where scatters hurt: most of the batch's ids
         # land here).  Cold hits carry hot_pos -1 and one-hot to zero rows.
-        for tname in hot_tables:
-            hname = coll.hot_array_name(tname)
-            feats = hot_by_table[tname]
-            hp_all = jnp.concatenate(
-                [hot_pos[f].reshape(-1) for f in feats])
-            obs_counters.emit(f"emb/{tname}/hot_ids",
-                              lambda h=hp_all: (h >= 0).sum())
-            g_all = jnp.concatenate([
-                g_embs[f].reshape(-1, g_embs[f].shape[-1]) for f in feats
-            ])
-            new_tables[hname], new_slots[hname] = state.sparse_opt.dense_update(
-                state.tables[hname], state.slots[hname], hp_all, g_all,
-                sr_key=_sr_key(hname),
-            )
+        with jax.named_scope("hot_update"):
+            for tname in hot_tables:
+                hname = coll.hot_array_name(tname)
+                feats = hot_by_table[tname]
+                hp_all = jnp.concatenate(
+                    [hot_pos[f].reshape(-1) for f in feats])
+                obs_counters.emit(f"emb/{tname}/hot_ids",
+                                  lambda h=hp_all: (h >= 0).sum())
+                g_all = jnp.concatenate([
+                    g_embs[f].reshape(-1, g_embs[f].shape[-1]) for f in feats
+                ])
+                new_tables[hname], new_slots[hname] = state.sparse_opt.dense_update(
+                    state.tables[hname], state.slots[hname], hp_all, g_all,
+                    sr_key=_sr_key(hname),
+                )
 
         return (
             SparseTrainState(
@@ -818,15 +825,17 @@ def make_pipelined_sparse_train_step(
                 return forward(dense_params, embs, batch, dropout_rng=step_rng)
             return forward(dense_params, embs, batch)
 
-        embs = coll.grouped_lookup(
-            state.tables, {f: ids[f] for f in grouped_feats}, ctx)
-        if rest_feats:
-            embs.update(coll.lookup(
-                state.tables, {f: ids[f] for f in rest_feats},
-                mode="alltoall"))
-        loss, (g_dense, g_embs) = jax.value_and_grad(
-            loss_from_embs, argnums=(0, 1), has_aux=with_aux
-        )(state.dense_params, embs)
+        with jax.named_scope("emb_lookup"):
+            embs = coll.grouped_lookup(
+                state.tables, {f: ids[f] for f in grouped_feats}, ctx)
+            if rest_feats:
+                embs.update(coll.lookup(
+                    state.tables, {f: ids[f] for f in rest_feats},
+                    mode="alltoall"))
+        with jax.named_scope("dense_fwd_bwd"):
+            loss, (g_dense, g_embs) = jax.value_and_grad(
+                loss_from_embs, argnums=(0, 1), has_aux=with_aux
+            )(state.dense_params, embs)
         aux = None
         if with_aux:
             loss, aux = loss
@@ -840,9 +849,10 @@ def make_pipelined_sparse_train_step(
             obs_counters.emit("param_norm", optax.global_norm(
                 (state.dense_params, state.tables)))
 
-        updates, new_opt_state = state.tx.update(
-            g_dense, state.opt_state, state.dense_params)
-        new_dense = optax.apply_updates(state.dense_params, updates)
+        with jax.named_scope("dense_update"):
+            updates, new_opt_state = state.tx.update(
+                g_dense, state.opt_state, state.dense_params)
+            new_dense = optax.apply_updates(state.dense_params, updates)
 
         # same SR keying as the eager step: state.step counts trained
         # batches, so pipelining does not shift the key stream
@@ -850,46 +860,47 @@ def make_pipelined_sparse_train_step(
             return (_make_sr_key(state.step, aname)
                     if _array_is_narrow(state, aname) else None)
 
-        new_tables = dict(state.tables)
-        new_slots = dict(state.slots)
-        g_narrow = any(_array_is_narrow(state, a) for a in grouped_arrays)
-        gt, gs = coll.grouped_update(
-            state.sparse_opt, state.tables, state.slots,
-            {f: ids[f] for f in grouped_feats},
-            {f: g_embs[f] for f in grouped_feats},
-            sr_key=(_make_sr_key(state.step, "__grouped_update__")
-                    if g_narrow else None))
-        new_tables.update(gt)
-        new_slots.update(gs)
-        for tname, feats in by_table_rest.items():
-            id_list, bound = [], 0
-            for f in feats:
-                _, spec, off = coll.resolve(f)
-                flat = jnp.where(ids[f] >= 0, ids[f] + off, -1).reshape(-1)
-                id_list.append(flat)
-                bound += min(flat.shape[0], spec.num_embeddings)
-            all_ids = jnp.concatenate(id_list)
-            all_grads = jnp.concatenate([
-                g_embs[f].reshape(-1, g_embs[f].shape[-1]) for f in feats])
-            md = -(-bound // 8) * 8 if bound < all_ids.shape[0] else None
-            if (coll.array_is_int8(tname)
-                    and state.tables[tname].ndim == 2):
-                # plain 2D int8 threads the separate qscale sidecar; fat
-                # int8 byte containers carry it in-line
-                qn = qscale_name(tname)
-                (new_tables[tname], new_slots[tname],
-                 new_tables[qn]) = coll.sparse_update(
-                    state.sparse_opt, tname,
-                    state.tables[tname], state.slots[tname], all_ids,
-                    all_grads, max_distinct=md, sr_key=_sr_key(tname),
-                    qscale=state.tables[qn],
-                )
-            else:
-                new_tables[tname], new_slots[tname] = coll.sparse_update(
-                    state.sparse_opt, tname,
-                    state.tables[tname], state.slots[tname], all_ids,
-                    all_grads, max_distinct=md, sr_key=_sr_key(tname),
-                )
+        with jax.named_scope("emb_update"):
+            new_tables = dict(state.tables)
+            new_slots = dict(state.slots)
+            g_narrow = any(_array_is_narrow(state, a) for a in grouped_arrays)
+            gt, gs = coll.grouped_update(
+                state.sparse_opt, state.tables, state.slots,
+                {f: ids[f] for f in grouped_feats},
+                {f: g_embs[f] for f in grouped_feats},
+                sr_key=(_make_sr_key(state.step, "__grouped_update__")
+                        if g_narrow else None))
+            new_tables.update(gt)
+            new_slots.update(gs)
+            for tname, feats in by_table_rest.items():
+                id_list, bound = [], 0
+                for f in feats:
+                    _, spec, off = coll.resolve(f)
+                    flat = jnp.where(ids[f] >= 0, ids[f] + off, -1).reshape(-1)
+                    id_list.append(flat)
+                    bound += min(flat.shape[0], spec.num_embeddings)
+                all_ids = jnp.concatenate(id_list)
+                all_grads = jnp.concatenate([
+                    g_embs[f].reshape(-1, g_embs[f].shape[-1]) for f in feats])
+                md = -(-bound // 8) * 8 if bound < all_ids.shape[0] else None
+                if (coll.array_is_int8(tname)
+                        and state.tables[tname].ndim == 2):
+                    # plain 2D int8 threads the separate qscale sidecar; fat
+                    # int8 byte containers carry it in-line
+                    qn = qscale_name(tname)
+                    (new_tables[tname], new_slots[tname],
+                     new_tables[qn]) = coll.sparse_update(
+                        state.sparse_opt, tname,
+                        state.tables[tname], state.slots[tname], all_ids,
+                        all_grads, max_distinct=md, sr_key=_sr_key(tname),
+                        qscale=state.tables[qn],
+                    )
+                else:
+                    new_tables[tname], new_slots[tname] = coll.sparse_update(
+                        state.sparse_opt, tname,
+                        state.tables[tname], state.slots[tname], all_ids,
+                        all_grads, max_distinct=md, sr_key=_sr_key(tname),
+                    )
 
         new_state = SparseTrainState(
             step=state.step + 1,

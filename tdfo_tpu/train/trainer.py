@@ -1152,12 +1152,13 @@ class Trainer:
 
     def train_epoch(self, epoch: int, *, start_step: int = 0,
                     loss_sum: float = 0.0, contributed: int = 0) -> float:
-        with self._jit_ctx():
-            return self._train_epoch(epoch, start_step=start_step,
+        with self._jit_ctx(), obs_trace.epoch_phases(epoch) as phases:
+            return self._train_epoch(epoch, phases, start_step=start_step,
                                      loss_sum=loss_sum, contributed=contributed)
 
-    def _train_epoch(self, epoch: int, *, start_step: int = 0,
-                     loss_sum: float = 0.0, contributed: int = 0) -> float:
+    def _train_epoch(self, epoch: int, phases: obs_trace.epoch_phases, *,
+                     start_step: int = 0, loss_sum: float = 0.0,
+                     contributed: int = 0) -> float:
         """One training epoch, resumable at step granularity.
 
         ``start_step`` (plus the matching partial ``loss_sum``/``contributed``
@@ -1178,14 +1179,19 @@ class Trainer:
         ``rollback`` record to metrics.jsonl.  The snapshot costs one extra
         state copy in device memory; set ``nonfinite_tolerance = 0`` to
         disable the guard (and the copy) on memory-tight runs.
+
+        Host-loop time is kept by ``phases`` (``obs.trace.epoch_phases``,
+        opened by ``train_epoch`` round this call): ``obs_trace.phase``
+        names where the loop waits — ``epoch_open`` (entry to the first
+        batch in hand), ``next_batch`` (children ``loader_next``/``h2d_put``
+        inside ``prefetch_to_mesh``), ``dispatch``, ``loss_sync`` (child
+        ``guard_snapshot``), ``cache_flush``, ``checkpoint_save``,
+        ``epoch_close`` — each also a ``tdfo:<name>`` span in a profiler
+        trace; the epoch line of metrics.jsonl carries their seconds.
         """
         cfg = self.config
         inj = _faults.active()
-        # host-loop wall time (throughput) via obs.trace's clock helpers —
-        # the single sanctioned monotonic-differencing site (time.time /
-        # perf_counter / raw monotonic differencing is rejected by
-        # tests/test_quality.py)
-        t0 = obs_trace.clock()
+        phase = obs_trace.phase
         n_steps = start_step
         step_ctrs: dict = {}  # latest step's device counter pytree
         # update-cache write-back schedule: the periodic flush runs async
@@ -1197,13 +1203,7 @@ class Trainer:
         pending_over: list[dict] = []
         next_log = start_step + cfg.log_every_n_steps
         profiled = cfg.profile and epoch == 0 and jax.process_index() == 0
-        # train-side streaming AUC on this epoch's predictions, folded ON
-        # DEVICE from the step's aux logits — no second forward pass
-        # (jax-flax/train_dp.py:190,219-220 parity).  Not persisted in the
-        # cursor (device histograms): after a mid-epoch resume the epoch AUC
-        # covers post-resume steps only.  State evolution is unaffected.
-        train_auc = (self._fresh_accumulator(AUC.empty())
-                     if self._train_auc_enabled else None)
+        train_auc = None
         # pipeline_overlap carry: (transformed batch, input-dist ctx) one
         # batch ahead of training.  Not persisted in cursors: n_steps counts
         # TRAINED batches, so a resume fast-forwards past exactly those and
@@ -1219,14 +1219,15 @@ class Trainer:
         consec_bad = 0
         snap = None  # (state, auc, loss_sum, contributed, global data step)
         steps_at_snap = n_steps
-        if guard:
-            snap = (_copy_tree(self.state), _copy_tree(train_auc),
-                    loss_sum, contributed, self._logged_steps + n_steps)
 
         def flush_checks() -> None:
             """Fetch queued losses: fold finite ones into the epoch sums,
             roll back on ``tol`` consecutive non-finite steps, refresh the
             snapshot after a clean window."""
+            with phase("loss_sync"):  # the host blocked on the device
+                _flush_checks()
+
+        def _flush_checks() -> None:
             nonlocal loss_sum, contributed, consec_bad, snap, train_auc
             nonlocal steps_at_snap, pending_steps
             for over in pending_over:
@@ -1247,8 +1248,10 @@ class Trainer:
                 # (device copy, no disk) and keep consuming data FORWARD —
                 # the poisoned window is skipped, not retried
                 state_c, auc_c, ls, ct, sg = snap
-                self.state = _copy_tree(state_c)  # snapshot must survive donation
-                train_auc = _copy_tree(auc_c)
+                with phase("guard_snapshot"):
+                    # the snapshot must survive donation
+                    self.state = _copy_tree(state_c)
+                    train_auc = _copy_tree(auc_c)
                 loss_sum, contributed = ls, ct
                 consec_bad = 0
                 rolled = True
@@ -1262,38 +1265,68 @@ class Trainer:
             pending_steps = 0
             if (guard and not rolled and consec_bad == 0
                     and n_steps - steps_at_snap >= cfg.snapshot_every_n_steps):
-                snap = (_copy_tree(self.state), _copy_tree(train_auc),
-                        loss_sum, contributed, self._logged_steps + n_steps)
+                with phase("guard_snapshot"):
+                    snap = (_copy_tree(self.state), _copy_tree(train_auc),
+                            loss_sum, contributed,
+                            self._logged_steps + n_steps)
                 steps_at_snap = n_steps
 
         ckpt_n = cfg.checkpoint_every_n_steps if self._ckpt is not None else 0
         next_ckpt = (n_steps // ckpt_n + 1) * ckpt_n if ckpt_n else None
         loss = None
+        with phase("epoch_open"):
+            # train-side streaming AUC on this epoch's predictions, folded ON
+            # DEVICE from the step's aux logits — no second forward pass
+            # (jax-flax/train_dp.py:190,219-220 parity).  Not persisted in
+            # the cursor (device histograms): after a mid-epoch resume the
+            # epoch AUC covers post-resume steps only.  State evolution is
+            # unaffected.
+            if self._train_auc_enabled:
+                train_auc = self._fresh_accumulator(AUC.empty())
+            if guard:
+                snap = (_copy_tree(self.state), _copy_tree(train_auc),
+                        loss_sum, contributed, self._logged_steps + n_steps)
+            # stream opened, pool filled, the first puts in flight
+            batches = iter(self._train_batches(epoch, skip=start_step))
+            item = next(batches, None)
         try:
-            for batch, k in self._train_batches(epoch, skip=start_step):
+            while item is not None:
+                batch, k = item
                 if profiled is True and n_steps >= 10:
-                    jax.profiler.start_trace(str(Path(cfg.checkpoint_dir or ".") / "profile"))
+                    # no Python tracer: it slows a host-bound loop by a
+                    # large factor; the host side is the tdfo: spans
+                    options = jax.profiler.ProfileOptions()
+                    options.python_tracer_level = 0
+                    jax.profiler.start_trace(
+                        str(Path(cfg.checkpoint_dir or ".") / "profile"),
+                        profiler_options=options)
                     profiled = "tracing"
-                if self._pipelined and carry is None:
-                    # pipeline prime: the first batch's input-dist only;
-                    # training starts next iteration
-                    carry = self._prime_step(batch)
-                    continue
-                if self._pipelined:
-                    if cfg.model == "bert4rec":
+                with phase("dispatch"):
+                    if self._pipelined and carry is None:
+                        # pipeline prime: the first batch's input-dist only;
+                        # training starts next iteration
+                        carry = self._prime_step(batch)
+                        out = None
+                    elif self._pipelined:
+                        if cfg.model == "bert4rec":
+                            out = self.train_step(
+                                self.state, batch, carry, self._dropout_rng)
+                            self.state, loss, carry = out[:3]
+                        else:
+                            out = self.train_step(
+                                self.state, batch, carry, train_auc)
+                            self.state, loss, carry, train_auc = out[:4]
+                    elif cfg.model == "bert4rec":
                         out = self.train_step(
-                            self.state, batch, carry, self._dropout_rng)
-                        self.state, loss, carry = out[:3]
+                            self.state, batch, self._dropout_rng)
+                        self.state, loss = out[:2]
                     else:
-                        out = self.train_step(
-                            self.state, batch, carry, train_auc)
-                        self.state, loss, carry, train_auc = out[:4]
-                elif cfg.model == "bert4rec":
-                    out = self.train_step(self.state, batch, self._dropout_rng)
-                    self.state, loss = out[:2]
-                else:
-                    out = self.train_step(self.state, batch, train_auc)
-                    self.state, loss, train_auc = out[:3]
+                        out = self.train_step(self.state, batch, train_auc)
+                        self.state, loss, train_auc = out[:3]
+                if out is None:  # primed: nothing trained yet
+                    with phase("next_batch"):
+                        item = next(batches, None)
+                    continue
                 if self._counters_on:
                     # DEVICE dict (the step's extra return) — floats are
                     # pulled at the log boundary with the train_loss fetch
@@ -1307,7 +1340,8 @@ class Trainer:
                 if next_flush is not None and n_steps >= next_flush:
                     # coalesced cache write-back: the ONLY big-table scatter
                     # in the cadence — one per flush_every steps
-                    pending_over.append(self._run_cache_flush())
+                    with phase("cache_flush"):
+                        pending_over.append(self._run_cache_flush())
                     next_flush = (n_steps // flush_n + 1) * flush_n
                 if pending_steps >= flush_every:
                     flush_checks()
@@ -1324,41 +1358,24 @@ class Trainer:
                     # checkpoints always hold flushed tables, so restores
                     # and exports never depend on cache contents
                     self._flush_cache_sync()
-                    self._ckpt.save(
-                        gstep, self.state, force=True,
-                        cursor={"epoch": epoch, "step": n_steps,
-                                "epoch_complete": False, "global_step": gstep,
-                                "loss_sum": loss_sum,
-                                "contributed": contributed},
-                        stamps=self._ckpt_stamps,
-                    )
+                    with phase("checkpoint_save"):
+                        self._ckpt.save(
+                            gstep, self.state, force=True,
+                            cursor={"epoch": epoch, "step": n_steps,
+                                    "epoch_complete": False,
+                                    "global_step": gstep,
+                                    "loss_sum": loss_sum,
+                                    "contributed": contributed},
+                            stamps=self._ckpt_stamps,
+                        )
                     next_ckpt = (n_steps // ckpt_n + 1) * ckpt_n
                 if inj is not None:
                     inj.maybe_stall(gstep)  # host-side sleep (watchdog test)
                     inj.maybe_kill(gstep)  # after the save: ckpt is durable
                 if n_steps >= next_log:
-                    rec = dict(epoch=epoch, step=n_steps, train_loss=float(loss))
-                    if self._a2a_overflow is not None:
-                        # ids dropped by the finite a2a capacity THIS batch
-                        # (zero vectors under skew — watch for quality decay)
-                        rec["a2a_overflow_ids"] = int(
-                            self._a2a_overflow(self.state, batch))
-                    if self._counters_on:
-                        # ONE host fetch of the latest step's counter pytree
-                        # — the same boundary the train_loss float() above
-                        # already syncs on, so the cadence is unchanged
-                        for ck, cv in {**step_ctrs, **self._flush_ctrs}.items():
-                            rec[ck] = float(cv)
-                        for ck in [c for c in rec
-                                   if c.endswith("cache_hit_rows")]:
-                            base = ck[: -len("hit_rows")]
-                            tot = rec[ck] + rec.get(base + "miss_rows", 0.0)
-                            if tot:
-                                rec[base + "hit_rate"] = rec[ck] / tot
-                        if self._a2a_fill is not None:
-                            fill, dropped = self._a2a_fill(self.state, batch)
-                            rec["a2a_fill"] = float(fill)
-                            rec["a2a_dropped_ids"] = int(dropped)
+                    with phase("loss_sync"):
+                        rec = self._step_record(epoch, n_steps, loss, batch,
+                                                step_ctrs)
                     # TB charts need a run-global x (per-epoch `step` resets,
                     # which would fold multi-epoch curves back on themselves)
                     rec["global_step"] = gstep
@@ -1371,16 +1388,20 @@ class Trainer:
                     # intervals; advance past n_steps so each interval logs
                     # at most once
                     next_log = n_steps + cfg.log_every_n_steps
+                with phase("next_batch"):
+                    item = next(batches, None)
             if self._pipelined and carry is not None:
                 # drain the pipeline: the last carried batch trains here
                 # (flush is prime's twin — together they shift every batch's
                 # training one call later without changing its math)
-                if cfg.model == "bert4rec":
-                    out = self._flush_step(self.state, carry, self._dropout_rng)
-                    self.state, loss = out[:2]
-                else:
-                    out = self._flush_step(self.state, carry, train_auc)
-                    self.state, loss, train_auc = out[:3]
+                with phase("dispatch"):
+                    if cfg.model == "bert4rec":
+                        out = self._flush_step(
+                            self.state, carry, self._dropout_rng)
+                        self.state, loss = out[:2]
+                    else:
+                        out = self._flush_step(self.state, carry, train_auc)
+                        self.state, loss, train_auc = out[:3]
                 carry = None
                 n_steps += 1
                 pending.append((loss, 1, self._logged_steps + n_steps))
@@ -1392,22 +1413,56 @@ class Trainer:
                 if loss is not None:
                     jax.block_until_ready(loss)
                 jax.profiler.stop_trace()
-        flush_checks()
-        self._flush_cache_sync()  # epoch boundary: leave the tables flushed
-        dt = obs_trace.elapsed_s(t0)
+        extra: dict[str, float] = {}
+        with phase("epoch_close"):
+            flush_checks()
+            self._flush_cache_sync()  # epoch boundary: leave the tables flushed
+            if train_auc is not None and n_steps:
+                extra["train_auc"] = float(train_auc.result())
         ran = n_steps - start_step  # steps actually executed THIS session
+        # the epoch's clock stops before the line is written: the line
+        # carries what the clock read
+        timed = phases.close(ran)
         self._logged_steps += n_steps
         avg = loss_sum / contributed if contributed else 0.0
-        extra: dict[str, float] = {}
-        if train_auc is not None and n_steps:
-            extra["train_auc"] = float(train_auc.result())
+        for name, (seconds, _, longest) in timed["phases"].items():
+            extra[f"phase_{name}_s"] = seconds
+            if name == "next_batch":
+                extra["phase_next_batch_max_ms"] = 1e3 * longest
         self.logger.log(
             epoch=epoch, train_loss_epoch=avg, steps=n_steps,
             examples_per_sec=ran * cfg.per_device_train_batch_size
-            * self.mesh.shape["data"] / max(dt, 1e-9),
-            **extra,
+            * self.mesh.shape["data"] / max(timed["loop_s"], 1e-9),
+            loop_s=timed["loop_s"], **extra,
         )
         return avg
+
+    def _step_record(self, epoch: int, n_steps: int, loss, batch,
+                     step_ctrs: dict) -> dict:
+        """The log-cadence line's device values, fetched: the loss and,
+        where they are on, the a2a and telemetry counters."""
+        rec = dict(epoch=epoch, step=n_steps, train_loss=float(loss))
+        if self._a2a_overflow is not None:
+            # ids dropped by the finite a2a capacity THIS batch
+            # (zero vectors under skew — watch for quality decay)
+            rec["a2a_overflow_ids"] = int(
+                self._a2a_overflow(self.state, batch))
+        if self._counters_on:
+            # ONE host fetch of the latest step's counter pytree
+            # — the same boundary the train_loss float() above
+            # already syncs on, so the cadence is unchanged
+            for ck, cv in {**step_ctrs, **self._flush_ctrs}.items():
+                rec[ck] = float(cv)
+            for ck in [c for c in rec if c.endswith("cache_hit_rows")]:
+                base = ck[: -len("hit_rows")]
+                tot = rec[ck] + rec.get(base + "miss_rows", 0.0)
+                if tot:
+                    rec[base + "hit_rate"] = rec[ck] / tot
+            if self._a2a_fill is not None:
+                fill, dropped = self._a2a_fill(self.state, batch)
+                rec["a2a_fill"] = float(fill)
+                rec["a2a_dropped_ids"] = int(dropped)
+        return rec
 
     def _fresh_accumulator(self, tree):
         """A zeroed on-device accumulator (train AUC, eval sums), placed as
@@ -1432,7 +1487,8 @@ class Trainer:
         epoch boundaries (no-op when the cache is off)."""
         if self._cache_flush is None:
             return
-        _check_cache_overflow(self._run_cache_flush())
+        with obs_trace.phase("cache_flush"):  # dispatch + the overflow fetch
+            _check_cache_overflow(self._run_cache_flush())
 
     # ----------------------------------------------------------------- eval
 

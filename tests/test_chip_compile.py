@@ -20,8 +20,11 @@ in the test's own process; the persistent compilation cache is off
 back without a chip.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
+
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -95,6 +98,8 @@ def test_fat_line_update_compiles(one_chip, d, kind, dtype, sr,
     before = PALLAS_CHOICES[("fat_line_update", "kernel", "tpu")]
     text = _compile(step, fat, slots, ulines, g_slots, touched, key)
     assert "tpu_custom_call" in text
+    # the kernel's name= reaches the compiled program (a trace's tf_op)
+    assert "fat_line_update/pallas_call" in text
     assert PALLAS_CHOICES[("fat_line_update", "kernel", "tpu")] == before + 1
 
 
@@ -121,6 +126,7 @@ def test_fat_line_update_routed_compiles(one_chip, d, kind, rows_per_line):
     text = _compile(step, fat, slots, idx, s((U, d), jnp.float32), idx, idx,
                     lines)
     assert "tpu_custom_call" in text
+    assert "fat_line_update_routed/pallas_call" in text
     assert PALLAS_CHOICES[
         ("fat_line_update_routed", "kernel", "tpu")] == before + 1
 
@@ -155,3 +161,7 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, shape, dtype):
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x, valid)
     assert text.count("tpu_custom_call") >= 3  # fwd, dq, dk/dv
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        # op_name="jit(loss)/transpose(jvp(flash_bwd_dq))/pallas_call"
+        assert re.search(rf'op_name="[^"]*\b{kernel}\b[^"]*/pallas_call"',
+                         text), kernel

@@ -258,14 +258,139 @@ def test_chrome_trace_shape(tmp_path):
     json.dumps(obj)  # the whole object must serialize
 
 
+# ------------------------------------------------- train-loop phases
+
+
+@pytest.fixture
+def fake_clock(monkeypatch):
+    """The phases' clock as a list holding the time: tests move it."""
+    t = [100.0]
+    monkeypatch.setattr(trace, "_now", lambda: t[0])
+    trace.reset_epoch_history()
+    yield t
+    trace.reset_epoch_history()
+
+
+def test_phase_outside_an_epoch_accumulates_nothing(fake_clock):
+    with trace.phase("next_batch"):
+        fake_clock[0] += 1.0
+        with trace.phase("loader_next"):
+            fake_clock[0] += 1.0
+    assert trace.epoch_history() == []
+    with pytest.raises(ZeroDivisionError):  # the body's error passes through
+        with trace.phase("dispatch"):
+            1 / 0
+    # an epoch that raises before close() records nothing and frees the thread
+    with pytest.raises(KeyError):
+        with trace.epoch_phases(0):
+            with trace.phase("dispatch"):
+                raise KeyError("lost batch")
+    assert trace.epoch_history() == []
+    with trace.epoch_phases(1) as ep:
+        ep.close(0)
+    assert [r["epoch"] for r in trace.epoch_history()] == [1]
+
+
+def test_nested_phases_sum_count_max_and_self_time(fake_clock):
+    t = fake_clock
+    with trace.epoch_phases(3) as ep:
+        t[0] += 1.0  # the loop's own time
+        for loader_s, put_s in ((2.0, 0.5), (4.0, 0.25)):
+            with trace.phase("next_batch"):
+                t[0] += 0.125  # next_batch's own
+                with trace.phase("loader_next"):
+                    t[0] += loader_s
+                with trace.phase("h2d_put"):
+                    t[0] += put_s
+            with trace.phase("dispatch"):
+                t[0] += 1.0
+        with pytest.raises(RuntimeError, match="already open"):
+            trace.epoch_phases(4).__enter__()
+        rec = ep.close(steps=2)
+        t[0] += 50.0  # after close: on nobody's clock
+    assert rec == trace.epoch_history()[-1]
+    assert (rec["epoch"], rec["steps"], rec["loop_s"]) == (3, 2, 10.0)
+    assert rec["phases"] == {
+        "next_batch": [7.0, 2, 4.375], "loader_next": [6.0, 2, 4.0],
+        "h2d_put": [0.75, 2, 0.5], "dispatch": [2.0, 2, 1.0]}
+    # a parent's self time is its seconds minus its children's
+    assert rec["self_s"] == {"next_batch": 0.25, "loader_next": 6.0,
+                             "h2d_put": 0.75, "dispatch": 2.0}
+    # loop_s minus the outermost phases: the Python loop's own time
+    assert rec["loop_self_s"] == 1.0
+    json.dumps(rec)
+
+
+def test_another_threads_phase_stays_out_of_this_epoch(fake_clock):
+    import threading
+
+    def worker():
+        with trace.phase("loader_next"):
+            pass
+        with trace.epoch_phases(9) as theirs:  # a thread has an epoch of its own
+            with trace.phase("dispatch"):
+                pass
+            theirs.close(1)
+
+    with trace.epoch_phases(1) as ep:
+        with trace.phase("dispatch"):
+            th = threading.Thread(target=worker)
+            th.start()
+            th.join(timeout=30)
+            assert not th.is_alive()
+            fake_clock[0] += 2.0
+        rec = ep.close(1)
+    assert rec["phases"] == {"dispatch": [2.0, 1, 2.0]}
+    assert [r["epoch"] for r in trace.epoch_history()] == [9, 1]
+
+
+def test_epoch_history_is_bounded_and_survives_configure(fake_clock, tmp_path):
+    for e in range(trace.HISTORY_EPOCHS + 6):
+        with trace.epoch_phases(e) as ep:
+            ep.close(1)
+    trace.configure(tmp_path)
+    trace.configure(None)
+    kept = [r["epoch"] for r in trace.epoch_history()]
+    assert kept == list(range(6, trace.HISTORY_EPOCHS + 6))
+    trace.reset_epoch_history()
+    assert trace.epoch_history() == []
+
+
+def test_phases_are_spans_of_a_profiler_session(tmp_path):
+    """With a session on, every occurrence of a phase is a ``tdfo:<name>``
+    event on a host thread's line of the profiler's own trace (the device
+    trace's clock) — inside an epoch, where one annotation object serves
+    every occurrence of its name, and outside one."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with trace.phase("h2d_put"):
+            pass
+        with trace.epoch_phases(0) as ep:
+            for _ in range(3):
+                with trace.phase("next_batch"):
+                    with trace.phase("loader_next"):
+                        pass
+            ep.close(3)
+    finally:
+        jax.profiler.stop_trace()
+        trace.reset_epoch_history()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    names = [ev.name for plane in
+             jax.profiler.ProfileData.from_file(str(path)).planes
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("tdfo:")]
+    assert sorted(names) == (["tdfo:h2d_put"] + ["tdfo:loader_next"] * 3
+                             + ["tdfo:next_batch"] * 3)
+
+
 # ----------------------------------------------- zero-cost jaxpr pin
 
 
-def test_trace_on_step_jaxpr_byte_identical(mesh8, tmp_path):
-    """``trace = true`` must add ZERO equations to the train step: spans
-    are host-side only, so the step jaxpr with a live trace sink is
-    byte-identical to the untraced build (the ``[telemetry] counters``
-    laziness pin of test_telemetry.py, applied to tracing)."""
+def _small_sparse_step(mesh8):
+    """An unjitted sparse step over two stacked tables, its state and a
+    batch."""
     from tdfo_tpu.models.dlrm import DLRMBackbone
     from tdfo_tpu.ops.sparse import sparse_optimizer
     from tdfo_tpu.parallel.embedding import (EmbeddingSpec,
@@ -296,13 +421,32 @@ def test_trace_on_step_jaxpr_byte_identical(mesh8, tmp_path):
              for c in cats}
     batch["x0"] = jnp.asarray(rr.random(16, dtype=np.float32))
     batch["label"] = jnp.asarray(rr.integers(0, 2, 16), jnp.float32)
+    return step, state, batch
 
+
+def test_trace_on_step_jaxpr_byte_identical(mesh8, tmp_path):
+    """``trace = true`` must add ZERO equations to the train step: spans
+    are host-side only, so the step jaxpr with a live trace sink is
+    byte-identical to the untraced build (the ``[telemetry] counters``
+    laziness pin of test_telemetry.py, applied to tracing)."""
+    step, state, batch = _small_sparse_step(mesh8)
     norm = lambda j: re.sub(r"0x[0-9a-f]+", "0xADDR", str(j))
     j_off = norm(jax.make_jaxpr(step)(state, batch))
     trace.configure(tmp_path)
     trace.emit("online", "stage", cycle=1, stage="probe")  # sink is LIVE
     j_on = norm(jax.make_jaxpr(step)(state, batch))
     assert j_on == j_off
+
+
+def test_sparse_step_sections_carry_named_scopes(mesh8):
+    """The step's sections are named in the device program (what a
+    profiler trace shows as ``tf_op``), so a reduction from trace to
+    metrics finds lookup / dense / update time after a refactor.  Names
+    ride the ops' metadata only: the jaxpr pin above is untouched."""
+    step, state, batch = _small_sparse_step(mesh8)
+    hlo = jax.jit(step).lower(state, batch).as_text(debug_info=True)
+    for scope in ("emb_lookup", "dense_fwd_bwd", "dense_update", "emb_update"):
+        assert re.search(rf'loc\("[^"]*\b{scope}\b', hlo), scope
 
 
 # ---------------------------------------------- rotation of sibling sinks

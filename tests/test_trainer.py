@@ -60,6 +60,20 @@ def test_twotower_trainer_fits_and_improves(prepared_dir, tmp_path):
     lines = [json.loads(l) for l in (tmp_path / "metrics.jsonl").read_text().splitlines()]
     assert any("train_loss_epoch" in l for l in lines)
     assert any("auc" in l for l in lines)
+    # every epoch line says where the host loop's time went (obs.trace phases)
+    epochs = [l for l in lines if "train_loss_epoch" in l]
+    assert [l["epoch"] for l in epochs] == [0, 1]
+    for l in epochs:
+        for name in ("epoch_open", "next_batch", "loader_next", "h2d_put",
+                     "dispatch", "loss_sync", "epoch_close"):
+            assert l[f"phase_{name}_s"] >= 0.0, name
+        assert 0.0 < (l["phase_next_batch_s"] + l["phase_dispatch_s"]
+                      + l["phase_loss_sync_s"]) <= l["loop_s"]
+        assert l["phase_loader_next_s"] + l["phase_h2d_put_s"] <= (
+            l["phase_next_batch_s"] + l["phase_epoch_open_s"])
+        assert l["phase_next_batch_max_ms"] <= 1e3 * l["phase_next_batch_s"]
+        assert l["examples_per_sec"] == pytest.approx(
+            l["steps"] * 16 * tr.mesh.shape["data"] / l["loop_s"])
 
 
 def test_bert4rec_trainer_model_parallel(prepared_dir, tmp_path):
