@@ -18,7 +18,8 @@ pyarrow-native pipeline with no per-row Python:
     full-permutation loader (``jax-flax/train.py:52-70`` parity).
   * :func:`prefetch_to_mesh` — double-buffered host->HBM transfer onto a
     named mesh (``flax.jax_utils.prefetch_to_device`` parity,
-    ``jax-flax/train_dp.py:211``), multihost-aware via
+    ``jax-flax/train_dp.py:211``) fed by a producer thread that decodes a
+    bounded queue of host batches ahead, multihost-aware via
     ``jax.make_array_from_process_local_data``.
 
 List-typed columns (Bert4Rec windows) are stacked into dense [B, T] arrays at
@@ -27,7 +28,10 @@ the arrow level.
 
 from __future__ import annotations
 
+import collections
 import glob as _glob
+import queue
+import threading
 import zlib
 from pathlib import Path
 from typing import Iterator, Sequence
@@ -103,6 +107,30 @@ def _concat_rows(parts: list[dict[str, np.ndarray]]) -> dict[str, np.ndarray]:
 
 def _take(d: dict[str, np.ndarray], idx) -> dict[str, np.ndarray]:
     return {k: v[idx] for k, v in d.items()}
+
+
+def _offer(q, item, stop) -> bool:
+    """Put ``item`` on a bounded queue for a consumer that may have left:
+    ``stop`` (a ``threading.Event``) is set when the consumer abandons its
+    generator (exception mid-epoch, generator GC), and a producer must
+    notice and leave instead of blocking on a full queue for ever, pinning
+    open readers and decoded batches."""
+    while not stop.is_set():
+        try:
+            q.put(item, timeout=0.2)
+            return True
+        except queue.Full:
+            continue
+    return False
+
+
+def _drain(q) -> None:
+    """Empty ``q`` so that a producer waiting on it full wakes at once."""
+    while True:
+        try:
+            q.get_nowait()
+        except queue.Empty:
+            return
 
 
 def resolve_files(data_dir: str | Path, pattern: str) -> list[str]:
@@ -244,36 +272,20 @@ class ParquetStream:
                     # max_bad_shards=0 on pods unless shards replicate.
                     self._quarantine(f, e)
             return
-        import collections
-        import queue as _queue
-        import threading
-
         _END = object()
-        # set when the consumer abandons the generator (exception mid-epoch,
-        # generator GC): workers must notice and exit instead of blocking on
-        # a full queue forever, pinning open readers and decoded batches
-        stop = threading.Event()
+        stop = threading.Event()  # the consumer has left: see _offer
 
         def start_reader(path: str):
-            q: _queue.Queue = _queue.Queue(maxsize=2)
-
-            def put(item) -> bool:
-                while not stop.is_set():
-                    try:
-                        q.put(item, timeout=0.2)
-                        return True
-                    except _queue.Full:
-                        continue
-                return False
+            q: queue.Queue = queue.Queue(maxsize=2)
 
             def worker():
                 try:
                     for d in self._file_batches(path):
-                        if not put(d):
+                        if not _offer(q, d, stop):
                             return
-                    put(_END)
+                    _offer(q, _END, stop)
                 except BaseException as e:  # surfaced on the consumer side
-                    put(e)
+                    _offer(q, e, stop)
 
             t = threading.Thread(target=worker, daemon=True)
             t.start()
@@ -305,11 +317,7 @@ class ParquetStream:
         finally:
             stop.set()
             for _, q in pending:  # unblock any waiting worker
-                while not q.empty():
-                    try:
-                        q.get_nowait()
-                    except _queue.Empty:
-                        break
+                _drain(q)
 
     def _batches_per_host(self) -> int | None:
         """Cross-host batch budget from parquet metadata (no communication).
@@ -624,25 +632,52 @@ class MapStream:
             yield _take(self.table, idx[i : i + self.batch_size])
 
 
-def prefetch_to_mesh(it, mesh, pspec=None, *, size: int = 2):
-    """Double-buffered host->device transfer onto a mesh.
+IN_FLIGHT = 2  # device batches put ahead of the one the consumer holds
 
-    ``jax-flax/train_dp.py:210-211`` parity (shard + prefetch_to_device(2)):
-    keeps ``size`` batches in flight; jax dispatches transfers asynchronously
-    so compute overlaps the next batch's copy.  Multihost: each host provides
-    its local rows via ``make_array_from_process_local_data``.
+
+def prefetch_to_mesh(it, mesh, pspec=None, *, size: int = 4):
+    """Host batches of ``it`` onto a mesh: decoded ahead by ONE producer
+    thread of this call's own, put ahead by the consumer's.
+
+    The producer thread (``tdfo-prefetch``, daemon) alone advances ``it``
+    (phase ``loader_next``: decode, shuffle pool, stacking) and hands the
+    host batches over a FIFO queue bounded at ``size``: it is never more
+    than ``size`` batches, and the one in its hand, ahead of what the
+    consumer has taken.  The consumer's ``next()`` takes one host batch from
+    the queue, puts it on the mesh (``h2d_put``) and yields the oldest of
+    the ``IN_FLIGHT`` device batches it keeps ahead: jax dispatches
+    transfers asynchronously, so compute overlaps the next batches' copies
+    (``jax-flax/train_dp.py:210-211`` parity: shard +
+    ``prefetch_to_device(2)``; the reference decodes inside ``next()`` too,
+    that part is off the consumer's thread here).  Batch n out is batch n
+    in.  An exception of the source is raised in the consumer where it
+    happened, after the batches before it.  A consumer that leaves early
+    (``break``, an epoch that raised, a generator that is collected) sets a
+    stop flag, and the producer leaves instead of waiting on a full queue.
+    Multihost: each host provides its local rows via
+    ``make_array_from_process_local_data``.
+
+    The put stays on the consumer's thread because it is device work: on
+    the v5e a second thread's buffer allocations starve behind the step's
+    own (each waits out a whole ``Execute``) and both run slower for it
+    (PERF.md section 6, PR 30).  ``size`` 4 covers one row group decoded
+    (16-22 ms there) at 6 ms a step.
+
+    The producer joins the ``obs.trace`` epoch open on the thread that
+    first advances this generator, so ``loader_next`` lands in that epoch's
+    record; with none open it only annotates.  The consumer tallies there
+    ``prefetch_depth`` (the queue's length at each take) and
+    ``prefetch_empty_takes`` (takes that found it empty and waited).
 
     Jagged batches need no special casing: per-host-packed ``values`` and
     ``lengths`` both ship batch-sharded ``P("data")`` (each process provides
     exactly its local slice), and ``jagged_to_dense_per_host`` reads the
     host-segmented layout back inside the step.
     """
-    import collections
-
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from tdfo_tpu.obs.trace import phase
+    from tdfo_tpu.obs.trace import current_epoch, join_epoch, phase, tally
 
     sharding = NamedSharding(mesh, pspec if pspec is not None else P("data"))
 
@@ -654,24 +689,53 @@ def prefetch_to_mesh(it, mesh, pspec=None, *, size: int = 2):
             }
         return jax.device_put(batch, sharding)
 
-    def put_next() -> bool:
-        """One host batch out of the stream (``loader_next``: decode, shuffle
-        pool, stacking) and onto the mesh (``h2d_put``).  The two phases
-        close before the caller yields."""
-        with phase("loader_next"):
-            batch = next(it, None)
-        if batch is None:
-            return False
-        with phase("h2d_put"):
-            q.append(put(batch))
-        return True
+    _END = object()
+    q: queue.Queue = queue.Queue(maxsize=size)
+    stop = threading.Event()  # the consumer has left: see _offer
+    epoch = current_epoch()
 
-    q = collections.deque()
-    it = iter(it)
-    for _ in range(size):
-        if not put_next():
-            break
-    while q:
-        b = q.popleft()
-        put_next()
-        yield b
+    def produce():
+        try:
+            with join_epoch(epoch):
+                src = iter(it)
+                while True:
+                    with phase("loader_next"):
+                        batch = next(src, _END)
+                    if not _offer(q, batch, stop) or batch is _END:
+                        return
+        except BaseException as e:  # raised again on the consumer's side
+            _offer(q, e, stop)
+
+    ahead: collections.deque = collections.deque()
+    ended = None  # what the stream ended in: _END or the source's exception
+
+    def take_and_put() -> None:
+        """One host batch off the queue and onto the mesh.  The phase
+        closes before the caller yields."""
+        nonlocal ended
+        if ended is not None:
+            return
+        depth = q.qsize()
+        tally("prefetch_depth", depth)
+        if not depth:
+            tally("prefetch_empty_takes")
+        item = q.get()
+        if item is _END or isinstance(item, BaseException):
+            ended = item
+            return
+        with phase("h2d_put"):
+            ahead.append(put(item))
+
+    threading.Thread(target=produce, name="tdfo-prefetch", daemon=True).start()
+    try:
+        for _ in range(IN_FLIGHT):
+            take_and_put()
+        while ahead:
+            b = ahead.popleft()
+            take_and_put()
+            yield b
+        if ended is not _END:
+            raise ended
+    finally:
+        stop.set()
+        _drain(q)

@@ -164,8 +164,9 @@ _session_on = TraceAnnotation.is_enabled  # the profiler's own atomic load
 
 
 class _Thread(threading.local):
-    epoch = None  # the accumulator open on this thread (a class default:
-    #               a missing attribute costs a raised AttributeError a read)
+    epoch = None  # this thread's chain: the epoch it opened, or its share of
+    #               one it joined (a class default: a missing attribute costs
+    #               a raised AttributeError a read)
 
 
 _THREAD = _Thread()
@@ -177,20 +178,38 @@ def phase(name: str):
     A ``jax.profiler.TraceAnnotation("tdfo:<name>")`` wherever it runs: an
     atomic load while no profiler session runs, a host span on the device
     trace's clock while one does.  If an ``epoch_phases`` accumulator is
-    open ON THIS THREAD the duration is also added to it under ``name``
-    (seconds, count, longest single occurrence, and self time: the part no
-    nested phase covers); anywhere else (eval, serving, another thread, a benchmark's
-    probe of the stream) it only annotates.  Class-based, no generator: it
-    runs several times a step.  A phase closes on the thread and inside the
-    ``next()`` it opened in (never hold one across a ``yield``), and does
-    not nest inside a phase of its own name."""
+    open ON THIS THREAD, or this thread has joined one (``join_epoch``),
+    the duration is also added to it under ``name`` (seconds, count,
+    longest single occurrence, and self time: the part no nested phase
+    covers); anywhere else (eval, serving, a thread that has not joined, a
+    benchmark's probe of the stream) it only annotates.  Class-based, no
+    generator: it runs several times a step.  A phase closes on the thread
+    and inside the ``next()`` it opened in (never hold one across a
+    ``yield``), and does not nest inside a phase of its own name."""
     acc = _THREAD.epoch
     if acc is None:
         return TraceAnnotation("tdfo:" + name)
     ph = acc._phases.get(name)
     if ph is None:
-        ph = acc._phases[name] = _Phase(name, acc)
+        with _LOCK:  # close() reads a joined thread's phases under it
+            ph = acc._phases[name] = _Phase(name, acc)
     return ph
+
+
+def tally(name: str, value: float = 1.0) -> None:
+    """Add ``value`` to the count ``name`` of the epoch this thread opened
+    or joined (``[sum, occurrences]`` under ``"tallies"`` of its record);
+    nothing anywhere else.  For what a phase's seconds cannot say: how deep
+    a queue stood at a take, how often it stood empty."""
+    acc = _THREAD.epoch
+    if acc is None:
+        return
+    t = acc._tallies.get(name)
+    if t is None:
+        with _LOCK:
+            t = acc._tallies[name] = [0.0, 0]
+    t[0] += value
+    t[1] += 1
 
 
 class _Phase:
@@ -199,7 +218,7 @@ class _Phase:
     __slots__ = ("_label", "_ann", "_acc", "_outer", "_inner_s", "_t0",
                  "seconds", "count", "max_seconds", "self_seconds")
 
-    def __init__(self, name: str, acc: "epoch_phases"):
+    def __init__(self, name: str, acc: "_Chain"):
         self._label = "tdfo:" + name
         self._ann = None
         self._acc = acc
@@ -234,7 +253,20 @@ class _Phase:
         return False
 
 
-class epoch_phases:
+class _Chain:
+    """One thread's phases inside one epoch: the ``_Phase`` of each name,
+    the innermost one open, the seconds of the outermost ones, the tallies.
+    An epoch has one of its own (the thread that opened it) and one for
+    every thread that joined it."""
+
+    def __init__(self):
+        self._phases: dict[str, _Phase] = {}
+        self._open: _Phase | None = None
+        self._top_s = 0.0
+        self._tallies: dict[str, list] = {}
+
+
+class epoch_phases(_Chain):
     """The accumulator round ONE ``_train_epoch`` call: ``with
     epoch_phases(epoch) as ep: ...; rec = ep.close(steps)``.
 
@@ -242,19 +274,22 @@ class epoch_phases:
 
         {"epoch", "steps", "loop_s", "loop_self_s",
          "phases": {name: [seconds, count, max_seconds]},
-         "self_s": {name: seconds}}
+         "self_s": {name: seconds}, "tallies": {name: [sum, occurrences]}}
 
     to the bounded in-memory history (``epoch_history()``).  ``loop_s`` is
     the whole call on the phases' clock; ``loop_self_s`` is ``loop_s`` minus
-    the outermost phases: the Python loop's own time.  Leaving the ``with``
-    without ``close`` (an epoch that raised) drops the accumulator and
-    records nothing."""
+    the outermost phases OF THE THREAD THAT OPENED THE EPOCH: its Python
+    loop's own time.  The phases of threads that joined (``join_epoch``)
+    are in ``phases`` and ``self_s`` under their names (seconds, counts and
+    self time added, the longest call the longest of any thread) and
+    nowhere else: they run beside the loop, not inside it.  Leaving the
+    ``with`` without ``close`` (an epoch that raised) drops the accumulator
+    and records nothing."""
 
     def __init__(self, epoch: int):
+        super().__init__()
         self.epoch = int(epoch)
-        self._phases: dict[str, _Phase] = {}
-        self._open: _Phase | None = None
-        self._top_s = 0.0
+        self._joined: list[_Chain] = []
 
     def __enter__(self):
         if _THREAD.epoch is not None:
@@ -267,19 +302,68 @@ class epoch_phases:
     def close(self, steps: int) -> dict:
         loop_s = _now() - self._t0
         _THREAD.epoch = None
-        record = {
-            "epoch": self.epoch, "steps": int(steps), "loop_s": loop_s,
-            "loop_self_s": loop_s - self._top_s,
-            "phases": {k: [p.seconds, p.count, p.max_seconds]
-                       for k, p in self._phases.items()},
-            "self_s": {k: p.self_seconds for k, p in self._phases.items()},
-        }
+        phases: dict[str, list] = {}
+        self_s: dict[str, float] = {}
+        tallies: dict[str, list] = {}
         with _LOCK:
+            for chain in (self, *self._joined):
+                for k, p in chain._phases.items():
+                    got = phases.setdefault(k, [0.0, 0, 0.0])
+                    got[0] += p.seconds
+                    got[1] += p.count
+                    got[2] = max(got[2], p.max_seconds)
+                    self_s[k] = self_s.get(k, 0.0) + p.self_seconds
+                for k, (total, n) in chain._tallies.items():
+                    got = tallies.setdefault(k, [0.0, 0])
+                    got[0] += total
+                    got[1] += n
+            record = {
+                "epoch": self.epoch, "steps": int(steps), "loop_s": loop_s,
+                "loop_self_s": loop_s - self._top_s,
+                "phases": phases, "self_s": self_s, "tallies": tallies,
+            }
             _HISTORY.append(record)
         return record
 
     def __exit__(self, exc_type, exc, tb):
         if _THREAD.epoch is self:
+            _THREAD.epoch = None
+        return False
+
+
+def current_epoch() -> "epoch_phases | None":
+    """The epoch open on this thread, to hand to a thread that will work
+    for it (``join_epoch``); ``None`` where none is open."""
+    acc = _THREAD.epoch
+    return acc if isinstance(acc, epoch_phases) else None
+
+
+class join_epoch:
+    """``with join_epoch(ep): ...`` on a thread that works FOR the epoch
+    ``ep`` of another thread (``ep = current_epoch()`` taken there): this
+    thread's phases add to ``ep``'s record under their names through an
+    open-chain of this thread's own, so two threads never share a nesting;
+    they stay out of ``loop_self_s`` and out of any phase of the opening
+    thread.  ``ep`` ``None`` joins nothing: the phases only annotate.  What
+    the thread adds after ``ep`` closed is dropped."""
+
+    def __init__(self, epoch: "epoch_phases | None"):
+        self._epoch = epoch
+
+    def __enter__(self):
+        if self._epoch is None:
+            return self
+        if _THREAD.epoch is not None:
+            raise RuntimeError("join_epoch: an epoch is already open on "
+                               "this thread")
+        chain = _Chain()
+        with _LOCK:
+            self._epoch._joined.append(chain)
+        _THREAD.epoch = chain
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._epoch is not None:
             _THREAD.epoch = None
         return False
 

@@ -1183,11 +1183,16 @@ class Trainer:
         Host-loop time is kept by ``phases`` (``obs.trace.epoch_phases``,
         opened by ``train_epoch`` round this call): ``obs_trace.phase``
         names where the loop waits — ``epoch_open`` (entry to the first
-        batch in hand), ``next_batch`` (children ``loader_next``/``h2d_put``
-        inside ``prefetch_to_mesh``), ``dispatch``, ``loss_sync`` (child
-        ``guard_snapshot``), ``cache_flush``, ``checkpoint_save``,
-        ``epoch_close`` — each also a ``tdfo:<name>`` span in a profiler
-        trace; the epoch line of metrics.jsonl carries their seconds.
+        batch in hand), ``next_batch`` (inside ``prefetch_to_mesh``: the
+        wait for its queue of host batches, and child ``h2d_put``),
+        ``dispatch``, ``loss_sync`` (child ``guard_snapshot``),
+        ``cache_flush``, ``checkpoint_save``, ``epoch_close`` — each also a
+        ``tdfo:<name>`` span in a profiler trace; the epoch line of
+        metrics.jsonl carries their seconds.  ``loader_next`` is on the same
+        line but not of this thread: ``prefetch_to_mesh``'s producer thread
+        decodes beside the loop and joins this epoch's record;
+        ``prefetch_empty_takes`` and ``prefetch_depth_mean`` say whether it
+        kept ahead.
         """
         cfg = self.config
         inj = _faults.active()
@@ -1429,6 +1434,11 @@ class Trainer:
             extra[f"phase_{name}_s"] = seconds
             if name == "next_batch":
                 extra["phase_next_batch_max_ms"] = 1e3 * longest
+        depth_sum, takes = timed["tallies"].get("prefetch_depth", (0.0, 0))
+        if takes:
+            extra["prefetch_depth_mean"] = depth_sum / takes
+            extra["prefetch_empty_takes"] = timed["tallies"].get(
+                "prefetch_empty_takes", (0.0, 0))[1]
         self.logger.log(
             epoch=epoch, train_loss_epoch=avg, steps=n_steps,
             examples_per_sec=ran * cfg.per_device_train_batch_size

@@ -5,13 +5,15 @@ files -> both ETLs -> loaders -> mesh-sharded device batches.
 """
 
 import glob
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pandas as pd
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from tdfo_tpu.data.ctr_preprocessing import (
     FINAL_COLUMNS,
@@ -21,6 +23,7 @@ from tdfo_tpu.data.ctr_preprocessing import (
     year_to_decade,
 )
 from tdfo_tpu.data.loader import (
+    IN_FLIGHT,
     ParquetStream,
     count_rows,
     load_parquet_table,
@@ -252,6 +255,124 @@ class TestPrefetch:
         out = list(prefetch_to_mesh(iter(batches), mesh_dp, P("data"), size=4))
         assert len(out) == 2
         assert float(out[1]["x"][0]) == 1.0
+
+    # the producer thread: one per call, alone on the source, a bounded
+    # FIFO of host batches ahead of the consumer, which puts IN_FLIGHT ahead
+
+    @staticmethod
+    def _producers():
+        return [t for t in threading.enumerate() if t.name == "tdfo-prefetch"]
+
+    @classmethod
+    def _wait(cls, holds, seconds=20.0):
+        """Poll ``holds()`` up to a bound; the producer is another thread."""
+        end = time.monotonic() + seconds
+        while not holds() and time.monotonic() < end:
+            time.sleep(0.002)
+        return holds()
+
+    def test_prefetch_keeps_a_shuffled_epochs_order_and_bits(
+            self, data_dir, ctr_size_map, mesh_dp):
+        files = resolve_files(data_dir, "parquet/train_part_*.parquet")
+
+        def stream():
+            s = ParquetStream(files, batch_size=64, buffer_size=128, seed=3,
+                              process_index=0, process_count=1)
+            s.set_epoch(2)
+            return s
+
+        sharding = NamedSharding(mesh_dp, P("data"))
+        by_hand = [jax.device_put(b, sharding) for b in stream()]
+        threaded = list(prefetch_to_mesh(stream(), mesh_dp, P("data")))
+        assert len(threaded) == len(by_hand) > 4
+        # shuffled: the epoch is not the files' order
+        assert not np.array_equal(
+            np.concatenate([np.asarray(b["user_id"]) for b in by_hand]),
+            np.concatenate([b["user_id"] for b in ParquetStream(
+                files, batch_size=64, shuffle=False, process_index=0,
+                process_count=1)]))
+        for got, want in zip(threaded, by_hand):
+            assert list(got) == list(want)
+            for k in want:
+                assert got[k].sharding == want[k].sharding
+                assert got[k].dtype == want[k].dtype
+                np.testing.assert_array_equal(np.asarray(got[k]),
+                                              np.asarray(want[k]))
+
+    @pytest.mark.parametrize("k", [0, 1, 5])
+    def test_prefetch_raises_the_sources_error_in_its_place(self, mesh_dp, k):
+        boom = KeyError("shard 7")
+
+        def source():
+            for i in range(k):
+                yield {"x": np.full((8,), i, np.float32)}
+            raise boom
+
+        got = []
+        with pytest.raises(KeyError) as caught:
+            for b in prefetch_to_mesh(source(), mesh_dp, P("data"), size=2):
+                got.append(float(b["x"][0]))
+        assert caught.value is boom
+        assert got == [float(i) for i in range(k)]
+        assert self._wait(lambda: not self._producers())
+
+    def test_prefetch_producer_leaves_when_the_consumer_does(self, mesh_dp):
+        assert self._wait(lambda: not self._producers())
+        made = []
+
+        def source():
+            for i in range(10_000):
+                made.append(i)
+                yield {"x": np.full((8,), i, np.float32)}
+
+        n = 0
+        for b in prefetch_to_mesh(source(), mesh_dp, P("data"), size=2):
+            n += 1
+            if n == 3:
+                assert len(self._producers()) == 1
+                break
+        # the loop let go of the generator: stop is set, the queue drained
+        assert self._wait(lambda: not self._producers())
+        assert len(made) <= 3 + IN_FLIGHT + 2 + 1 + 1  # + the drain's one
+        # an abandoned generator that is only collected, never closed by hand
+        g = prefetch_to_mesh(source(), mesh_dp, P("data"))
+        next(g)
+        del g
+        assert self._wait(lambda: not self._producers())
+
+    @pytest.mark.parametrize("size", [1, 2, 4])
+    def test_prefetch_is_at_most_size_and_one_in_hand_ahead(self, mesh_dp,
+                                                            size):
+        made = []
+
+        def source():
+            for i in range(40):
+                made.append(i)
+                yield {"x": np.full((8,), i, np.float32)}
+
+        # the consumer has taken what it yielded and what it put ahead
+        taken = IN_FLIGHT
+        for b in prefetch_to_mesh(source(), mesh_dp, P("data"), size=size):
+            taken += 1
+            assert float(b["x"][0]) == taken - IN_FLIGHT - 1
+            if taken <= 30:
+                # it does get ahead, as far as the bound and no further
+                assert self._wait(lambda: len(made) == taken + size + 1)
+            assert len(made) <= taken + size + 1
+        assert len(made) == 40 and taken == 40 + IN_FLIGHT
+
+    def test_prefetch_advances_the_source_from_one_thread_only(self, mesh_dp):
+        idents = []
+
+        def source():
+            idents.append(threading.get_ident())  # the first fill
+            for i in range(12):
+                yield {"x": np.full((8,), i, np.float32)}
+                idents.append(threading.get_ident())
+
+        assert len(list(prefetch_to_mesh(source(), mesh_dp, P("data")))) == 12
+        assert len(idents) == 13 and len(set(idents)) == 1
+        assert idents[0] != threading.get_ident()
 
 
 class TestMultihostBatchBudget:

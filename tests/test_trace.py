@@ -344,6 +344,156 @@ def test_another_threads_phase_stays_out_of_this_epoch(fake_clock):
     assert [r["epoch"] for r in trace.epoch_history()] == [9, 1]
 
 
+def _on_a_thread(fn, *args):
+    """Run ``fn`` on a thread of its own, to its end."""
+    import threading
+
+    errors = []
+
+    def body():
+        try:
+            fn(*args)
+        except BaseException as e:  # handed to the test's thread
+            errors.append(e)
+
+    th = threading.Thread(target=body)
+    th.start()
+    th.join(timeout=30)
+    assert not th.is_alive()
+    if errors:
+        raise errors[0]
+
+
+def test_a_joined_threads_phases_land_in_the_epochs_record(fake_clock):
+    """``prefetch_to_mesh``'s producer: its phases are in the record under
+    their names and in nobody's self time but their own."""
+    t = fake_clock
+
+    def producer(ep):
+        with trace.join_epoch(ep):
+            for loader_s, put_s in ((2.0, 0.5), (4.0, 0.25)):
+                with trace.phase("loader_next"):
+                    t[0] += loader_s
+                with trace.phase("h2d_put"):
+                    t[0] += put_s
+            trace.tally("prefetch_depth", 5)  # a joined thread's tally counts
+
+    with trace.epoch_phases(5) as ep:
+        assert trace.current_epoch() is ep
+        t[0] += 1.0  # the loop's own time
+        with trace.phase("next_batch"):
+            t[0] += 0.125
+            # the producer works WHILE the loop waits: 6.75 s pass on the
+            # clock inside next_batch, none of it next_batch's children
+            _on_a_thread(producer, trace.current_epoch())
+            trace.tally("prefetch_depth", 1)
+            trace.tally("prefetch_empty_takes")
+        with trace.phase("h2d_put"):  # a name both threads use adds up
+            t[0] += 1.0
+        with trace.phase("dispatch"):
+            t[0] += 1.0
+        rec = ep.close(steps=2)
+    assert trace.current_epoch() is None
+    assert rec["loop_s"] == 9.875
+    assert rec["phases"] == {
+        "next_batch": [6.875, 1, 6.875], "loader_next": [6.0, 2, 4.0],
+        "h2d_put": [1.75, 3, 1.0], "dispatch": [1.0, 1, 1.0]}
+    assert rec["self_s"] == {"next_batch": 6.875, "loader_next": 6.0,
+                             "h2d_put": 1.75, "dispatch": 1.0}
+    # the loop's own time is the opening thread's alone
+    assert rec["loop_self_s"] == 1.0
+    assert rec["tallies"] == {"prefetch_depth": [6.0, 2],
+                              "prefetch_empty_takes": [1.0, 1]}
+    json.dumps(rec)
+
+
+def test_join_epoch_of_nothing_only_annotates_and_a_late_join_is_dropped(
+        fake_clock):
+    def unjoined():
+        assert trace.current_epoch() is None
+        with trace.join_epoch(None):  # eval, a probe of the stream, online
+            with trace.phase("loader_next"):
+                fake_clock[0] += 1.0
+            trace.tally("prefetch_depth", 3)
+
+    def twice(ep):
+        with trace.join_epoch(ep):
+            # a thread's share of an epoch is not an epoch to hand on
+            assert trace.current_epoch() is None
+            with pytest.raises(RuntimeError, match="already open"):
+                trace.join_epoch(ep).__enter__()
+
+    def late(ep):
+        with trace.join_epoch(ep):
+            with trace.phase("h2d_put"):
+                fake_clock[0] += 1.0
+
+    trace.tally("prefetch_depth", 3)  # no epoch: nothing, and no error
+    with trace.epoch_phases(2) as ep:
+        _on_a_thread(unjoined)
+        _on_a_thread(twice, ep)
+        with pytest.raises(RuntimeError, match="already open"):
+            trace.join_epoch(ep).__enter__()  # the opening thread cannot join
+        rec = ep.close(1)
+        _on_a_thread(late, ep)
+    assert rec["phases"] == {} and rec["tallies"] == {}
+    assert trace.epoch_history() == [rec]
+    assert rec["loop_s"] == rec["loop_self_s"] == 1.0
+
+
+def test_two_threads_phases_at_once_keep_their_own_nesting():
+    """Real threads, real clock, a short switch interval: a phase closing
+    on one thread while another's is open must not become its child.  With
+    one shared open-chain the outer phases' self time would lose the other
+    thread's seconds."""
+    import sys
+    import threading
+
+    n = 3000
+    trace.reset_epoch_history()
+    go = threading.Event()
+
+    def nest(outer, inner):
+        go.wait(30)
+        for _ in range(n):
+            with trace.phase(outer):
+                with trace.phase(inner):
+                    pass
+
+    def producer(ep):
+        with trace.join_epoch(ep):
+            nest("loader_next", "decode")
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with trace.epoch_phases(0) as ep:
+            threads = [threading.Thread(target=producer, args=(ep,))
+                       for _ in range(3)]
+            for th in threads:
+                th.start()
+            go.set()
+            nest("next_batch", "take")
+            for th in threads:
+                th.join(timeout=60)
+            assert not any(th.is_alive() for th in threads)
+            assert ep._open is None and all(c._open is None for c in ep._joined)
+            rec = ep.close(n)
+    finally:
+        sys.setswitchinterval(interval)
+        trace.reset_epoch_history()
+    counts = {k: v[1] for k, v in rec["phases"].items()}
+    assert counts == {"next_batch": n, "take": n,
+                      "loader_next": 3 * n, "decode": 3 * n}
+    for outer, inner in (("next_batch", "take"), ("loader_next", "decode")):
+        assert rec["self_s"][inner] == rec["phases"][inner][0]
+        assert rec["self_s"][outer] == pytest.approx(
+            rec["phases"][outer][0] - rec["phases"][inner][0], abs=1e-6)
+        assert rec["self_s"][outer] >= 0.0
+    top = rec["loop_s"] - rec["loop_self_s"]
+    assert top == pytest.approx(rec["phases"]["next_batch"][0], abs=1e-6)
+
+
 def test_epoch_history_is_bounded_and_survives_configure(fake_clock, tmp_path):
     for e in range(trace.HISTORY_EPOCHS + 6):
         with trace.epoch_phases(e) as ep:

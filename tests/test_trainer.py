@@ -14,6 +14,7 @@ from tdfo_tpu.core.config import read_configs
 from tdfo_tpu.data.ctr_preprocessing import run_ctr_preprocessing
 from tdfo_tpu.data.seq_preprocessing import run_seq_preprocessing
 from tdfo_tpu.data.synthetic import write_synthetic_goodreads
+from tdfo_tpu.obs import trace as obs_trace
 from tdfo_tpu.train.trainer import Trainer, pad_batch
 
 
@@ -63,15 +64,26 @@ def test_twotower_trainer_fits_and_improves(prepared_dir, tmp_path):
     # every epoch line says where the host loop's time went (obs.trace phases)
     epochs = [l for l in lines if "train_loss_epoch" in l]
     assert [l["epoch"] for l in epochs] == [0, 1]
-    for l in epochs:
+    records = obs_trace.epoch_history()[-2:]
+    assert [r["epoch"] for r in records] == [0, 1]
+    for l, record in zip(epochs, records):
         for name in ("epoch_open", "next_batch", "loader_next", "h2d_put",
                      "dispatch", "loss_sync", "epoch_close"):
             assert l[f"phase_{name}_s"] >= 0.0, name
         assert 0.0 < (l["phase_next_batch_s"] + l["phase_dispatch_s"]
                       + l["phase_loss_sync_s"]) <= l["loop_s"]
-        assert l["phase_loader_next_s"] + l["phase_h2d_put_s"] <= (
+        assert l["phase_h2d_put_s"] <= (
             l["phase_next_batch_s"] + l["phase_epoch_open_s"])
         assert l["phase_next_batch_max_ms"] <= 1e3 * l["phase_next_batch_s"]
+        # the decode runs beside the loop on prefetch_to_mesh's producer
+        # thread, which joined the epoch: every batch decoded and put is
+        # counted, and the line says how far ahead the producer kept
+        _, decoded, _ = record["phases"]["loader_next"]
+        _, put, _ = record["phases"]["h2d_put"]
+        assert (decoded, put) == (l["steps"] + 1, l["steps"])  # + the end
+        assert record["tallies"]["prefetch_depth"][1] == l["steps"] + 1
+        assert 1 <= l["prefetch_empty_takes"] <= l["steps"] + 1
+        assert 0.0 <= l["prefetch_depth_mean"] <= 4.0  # the queue's bound
         assert l["examples_per_sec"] == pytest.approx(
             l["steps"] * 16 * tr.mesh.shape["data"] / l["loop_s"])
 
