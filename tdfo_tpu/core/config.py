@@ -370,6 +370,33 @@ class TrainSpec:
 
 
 @dataclass(frozen=True)
+class LmSpec:
+    """``[lm]`` config table: the ``olmo_hybrid`` decoder (``model =
+    "olmo_hybrid"``, ``tdfo_tpu/models/olmo_hybrid.py``).  Key names are the
+    published ``config.json``'s (huggingface ``model_type: olmo_hybrid``)
+    where it has one; a head count there is the PUBLISHED count (it sets the
+    head size), and the ``*_held`` keys say how many of them this process
+    group's layers hold (the chip's share of a deployment that divides each
+    layer by heads; 0 = all of them)."""
+
+    vocab_size: int = 0            # rows of the token table and of the head
+    hidden_size: int = 0
+    intermediate_size: int = 0     # SwiGLU width
+    # one entry a layer: "linear_attention" (Gated DeltaNet) | "full_attention"
+    layer_types: tuple[str, ...] = ()
+    # published, for both kinds of layer; head size = hidden / this
+    num_attention_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    # beta = 2 * sigmoid(.) (the state's eigenvalues may go negative)
+    linear_allow_neg_eigval: bool = True
+    rms_norm_eps: float = 1e-6
+    full_heads_held: int = 0
+    linear_heads_held: int = 0
+
+
+@dataclass(frozen=True)
 class TelemetrySpec:
     """``[telemetry]`` config table: flight-recorder knobs (``tdfo_tpu/obs``).
 
@@ -556,7 +583,7 @@ class Config:
     seed: int = 42
 
     # --- model (L2) ---
-    model: str = "twotower"  # "twotower" | "bert4rec" | "dlrm"
+    model: str = "twotower"  # "twotower" | "bert4rec" | "dlrm" | "olmo_hybrid"
     embed_dim: int = 16
     # custom CTR feature schema (dlrm only): categorical column names (one
     # embedding table each, vocab sizes from size_map) and continuous column
@@ -646,6 +673,8 @@ class Config:
     embeddings: EmbeddingsSpec = field(default_factory=EmbeddingsSpec)
     # [train] table: train-loop pipelining knobs
     train: TrainSpec = field(default_factory=TrainSpec)
+    # [lm] table: the olmo_hybrid decoder's architecture and share
+    lm: LmSpec = field(default_factory=LmSpec)
     # [serving] table: online-inference knobs (launch serve / tdfo_tpu.serve)
     serving: ServingSpec = field(default_factory=ServingSpec)
     # [loadgen] table: load-generation harness knobs (launch loadgen)
@@ -720,6 +749,41 @@ class Config:
     # --- preprocessing handshake ---
     size_map: Mapping[str, int] = field(default_factory=dict)
 
+    def _check_lm(self) -> None:
+        lm = self.lm
+        for key in ("vocab_size", "hidden_size", "intermediate_size",
+                    "num_attention_heads", "linear_key_head_dim",
+                    "linear_value_head_dim"):
+            if getattr(lm, key) <= 0:
+                raise ValueError(f"model = \"olmo_hybrid\" needs [lm] {key} > 0")
+        if not lm.layer_types or set(lm.layer_types) - {
+                "linear_attention", "full_attention"}:
+            raise ValueError(
+                "[lm] layer_types: one of \"linear_attention\" | "
+                f"\"full_attention\" a layer, got {lm.layer_types!r}")
+        if lm.hidden_size % lm.num_attention_heads:
+            raise ValueError("[lm] hidden_size must divide by num_attention_heads")
+        if not 0 <= lm.full_heads_held <= lm.num_attention_heads:
+            raise ValueError("[lm] full_heads_held must be in [0, num_attention_heads]")
+        if not 0 <= lm.linear_heads_held <= lm.num_attention_heads:
+            raise ValueError("[lm] linear_heads_held must be in [0, num_attention_heads]")
+        if lm.linear_conv_kernel_dim < 1:
+            raise ValueError("[lm] linear_conv_kernel_dim must be >= 1")
+        if self.nonfinite_tolerance != 0:
+            # the step donates its state (a dense state of many GiB cannot
+            # live twice), so there is nothing for the guard to roll back to
+            raise ValueError(
+                "model = \"olmo_hybrid\" donates its train state: set "
+                "nonfinite_tolerance = 0 (the non-finite guard keeps a second "
+                "copy of the state, which this model's state has no room for)")
+        if self.steps_per_execution != 1 or self.train.pipeline_overlap:
+            raise ValueError("model = \"olmo_hybrid\" runs single-step "
+                             "dispatches: steps_per_execution = 1, no "
+                             "train.pipeline_overlap")
+        if self.write_format != "parquet":
+            raise ValueError("model = \"olmo_hybrid\" reads parquet only "
+                             "(sequence columns are list-valued)")
+
     def __post_init__(self) -> None:
         if self.max_len < self.sliding_step:
             raise ValueError(
@@ -727,8 +791,13 @@ class Config:
             )
         if self.write_format not in ("parquet", "tfrecord"):
             raise ValueError(f"unsupported write_format: {self.write_format!r}")
-        if self.model not in ("twotower", "dlrm", "bert4rec"):
+        if self.model not in ("twotower", "dlrm", "bert4rec", "olmo_hybrid"):
             raise ValueError(f"unknown model: {self.model!r}")
+        if self.model == "olmo_hybrid":
+            self._check_lm()
+        elif self.lm != LmSpec():
+            raise ValueError("the [lm] table configures model = "
+                             "\"olmo_hybrid\" only")
         if ((self.categorical_features or self.continuous_features)
                 and self.model != "dlrm"):
             raise ValueError(
@@ -1183,6 +1252,7 @@ _MESH_FIELDS = {f.name for f in dataclasses.fields(MeshSpec)} - {"axis_names"}
 _FAULT_FIELDS = {f.name for f in dataclasses.fields(FaultSpec)}
 _EMBEDDINGS_FIELDS = {f.name for f in dataclasses.fields(EmbeddingsSpec)}
 _TRAIN_FIELDS = {f.name for f in dataclasses.fields(TrainSpec)}
+_LM_FIELDS = {f.name for f in dataclasses.fields(LmSpec)}
 _SERVING_FIELDS = {f.name for f in dataclasses.fields(ServingSpec)}
 _LOADGEN_FIELDS = {f.name for f in dataclasses.fields(LoadgenSpec)}
 _TELEMETRY_FIELDS = {f.name for f in dataclasses.fields(TelemetrySpec)}
@@ -1243,6 +1313,17 @@ def read_configs(config_path: str | os.PathLike | None = None, **overrides: Any)
             raise ValueError(
                 f"unknown train config keys: {sorted(unknown_train)}")
         train = TrainSpec(**train_raw)
+
+    lm_raw = raw.pop("lm", {})
+    if isinstance(lm_raw, LmSpec):
+        lm = lm_raw
+    else:
+        unknown_lm = set(lm_raw) - _LM_FIELDS
+        if unknown_lm:
+            raise ValueError(f"unknown lm config keys: {sorted(unknown_lm)}")
+        if "layer_types" in lm_raw:
+            lm_raw = dict(lm_raw, layer_types=tuple(lm_raw["layer_types"]))
+        lm = LmSpec(**lm_raw)
 
     serving_raw = raw.pop("serving", {})
     if isinstance(serving_raw, ServingSpec):
@@ -1309,7 +1390,7 @@ def read_configs(config_path: str | os.PathLike | None = None, **overrides: Any)
             raw[key] = tuple(raw[key])  # toml arrays / lists -> tuples
 
     cfg = Config(mesh=mesh, faults=faults, embeddings=embeddings, train=train,
-                 serving=serving, loadgen=loadgen, telemetry=telemetry,
+                 lm=lm, serving=serving, loadgen=loadgen, telemetry=telemetry,
                  online=online, planner=planner, **raw)
     if not cfg.size_map:
         size_map = load_size_map(cfg.data_dir)

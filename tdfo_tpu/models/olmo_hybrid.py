@@ -1,0 +1,349 @@
+"""``olmo_hybrid``: a decoder whose layers are, by ``layer_types``, either
+Gated DeltaNet (``linear_attention``) or causal full attention
+(``full_attention``), each followed by a SwiGLU MLP.
+
+Source: https://huggingface.co/allenai/Olmo-Hybrid-7B/blob/main/config.json
+(``model_type: olmo_hybrid``, whose key names :class:`OlmoHybridConfig`
+keeps); the linear-attention layer follows flash-linear-attention's
+``GatedDeltaNet`` (Yang, Kautz, Hatamizadeh, arXiv:2412.06464).  The
+equations are written out in ``benchmarks/reference/olmo_hybrid.py``, the
+plain float32 reference the tests hold this file to.  What the config does
+not state is the Olmo 2/3 family's convention: ``h = x + RMSNorm(Mixer(x))``,
+``y = h + RMSNorm(MLP(h))``, RMSNorm over the whole projection of q and of k,
+no rotary embedding (``rope_theta`` null).
+
+Heads held apart from heads published (the expert-parallel analogue for
+heads, ``/opt/skills/guides/model-configs`` section 4): a mixer is told how
+many heads it holds (``full_heads_held`` / ``linear_heads_held``) and, under
+a mesh, the axis they are shared over.  The Gated DeltaNet mixer is additive
+head by head.  The full-attention mixer's q/k RMSNorm runs over every
+chip's columns: with ``axis_name`` the mean square is summed over that axis
+(one scalar a token), with ``None`` over the held columns alone.  What the
+absent heads would add to ``W_o``'s output is left out and the partial
+result goes on to the block's RMSNorm; no code stands in for the absent chip.
+
+Parameters are float32 and cast to ``dtype`` where they are used; softmax,
+norms, gates, ``gamma`` and the delta-rule state are float32.  The embedding
+lives outside (a ``ShardedEmbeddingCollection`` table): :func:`forward_loss`
+takes the gathered vectors.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+
+from tdfo_tpu.ops.gated_delta import chunk_gated_delta_rule
+
+__all__ = ["OlmoHybridConfig", "init_olmo_hybrid", "forward_loss", "backbone",
+           "next_token_loss", "full_attention_mixer", "gated_delta_mixer",
+           "causal_document_attention", "rms_norm", "LAYER_KINDS"]
+
+LAYER_KINDS = ("linear_attention", "full_attention")
+# attention: queries (and keys) a block; the tests pass smaller ones
+QUERY_BLOCK = 1024
+
+
+@dataclass(frozen=True)
+class OlmoHybridConfig:
+    vocab_size: int
+    hidden_size: int
+    intermediate_size: int
+    layer_types: tuple[str, ...]
+    num_attention_heads: int          # published count: sets the head size
+    linear_key_head_dim: int
+    linear_value_head_dim: int
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = True
+    rms_norm_eps: float = 1e-6
+    # the chip's share: heads HELD here of each kind (0 = all published)
+    full_heads_held: int = 0
+    linear_heads_held: int = 0
+
+    def __post_init__(self):
+        bad = set(self.layer_types) - set(LAYER_KINDS)
+        if bad or not self.layer_types:
+            raise ValueError(f"layer_types must be of {LAYER_KINDS}, got "
+                             f"{sorted(bad) or 'none'}")
+        if self.hidden_size % self.num_attention_heads:
+            raise ValueError("hidden_size must divide by num_attention_heads")
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def full_heads(self) -> int:
+        return self.full_heads_held or self.num_attention_heads
+
+    @property
+    def linear_heads(self) -> int:
+        return self.linear_heads_held or self.num_attention_heads
+
+
+# ------------------------------------------------------------- parameters
+
+
+def _mixer_shapes(cfg: OlmoHybridConfig, kind: str) -> dict[str, tuple]:
+    d = cfg.hidden_size
+    if kind == "full_attention":
+        w = cfg.full_heads * cfg.head_dim
+        return {"wq": (d, w), "wk": (d, w), "wv": (d, w), "wo": (w, d),
+                "q_norm": (w,), "k_norm": (w,)}
+    h, kw = cfg.linear_heads, cfg.linear_conv_kernel_dim
+    qk, vw = h * cfg.linear_key_head_dim, h * cfg.linear_value_head_dim
+    return {"wq": (d, qk), "wk": (d, qk), "wv": (d, vw), "wg": (d, vw),
+            "wo": (vw, d), "wa": (d, h), "wb": (d, h),
+            "conv_q": (kw, qk), "conv_k": (kw, qk), "conv_v": (kw, vw),
+            "A_log": (h,), "dt_bias": (h,),
+            "o_norm": (cfg.linear_value_head_dim,)}
+
+
+def param_shapes(cfg: OlmoHybridConfig) -> dict:
+    """The dense parameter tree's shapes (nested like the tree)."""
+    d, f = cfg.hidden_size, cfg.intermediate_size
+    tree: dict = {}
+    for i, kind in enumerate(cfg.layer_types):
+        tree[f"layer_{i}"] = {
+            "mixer": _mixer_shapes(cfg, kind), "mixer_norm": (d,),
+            "mlp": {"gate": (d, f), "up": (d, f), "down": (f, d)},
+            "mlp_norm": (d,)}
+    tree["final_norm"] = (d,)
+    tree["head"] = (d, cfg.vocab_size)
+    return tree
+
+
+def init_leaf(rng: jax.Array, name: str, shape: tuple) -> jax.Array:
+    """One leaf's initial value, by its name: norm weights one; projections
+    normal(0, 0.02) (the family's); the rest as flash-linear-attention's
+    ``GatedDeltaNet``: depthwise convolutions uniform(+-1/sqrt(K)),
+    ``A_log = log(uniform(0, 16))``, ``dt_bias`` the inverse softplus of
+    ``dt`` log-uniform in [1e-3, 1e-1]."""
+    if name.endswith("norm"):
+        return jnp.ones(shape, jnp.float32)
+    if name.startswith("conv_"):
+        bound = 1.0 / math.sqrt(shape[0])
+        return jax.random.uniform(rng, shape, jnp.float32, -bound, bound)
+    if name == "A_log":
+        return jnp.log(jax.random.uniform(rng, shape, jnp.float32, 1e-3, 16.0))
+    if name == "dt_bias":
+        dt = jnp.exp(jax.random.uniform(rng, shape, jnp.float32,
+                                        math.log(1e-3), math.log(1e-1)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    return 0.02 * jax.random.normal(rng, shape, jnp.float32)
+
+
+def init_olmo_hybrid(rng: jax.Array, cfg: OlmoHybridConfig) -> dict:
+    shapes = param_shapes(cfg)
+    leaves, treedef = jax.tree.flatten_with_path(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
+    keys = jax.random.split(rng, len(leaves))
+    return jax.tree.unflatten(treedef, [
+        init_leaf(k, path[-1].key, shape)
+        for k, (path, shape) in zip(keys, leaves)])
+
+
+# ------------------------------------------------------------------ pieces
+
+
+def rms_norm(x, w, eps: float, *, axis_name: str | None = None):
+    """RMSNorm in float32 over the last axis; with ``axis_name`` the mean
+    square runs over every device's columns on that mesh axis."""
+    x32 = x.astype(jnp.float32)
+    ss = jnp.sum(x32 * x32, axis=-1, keepdims=True)
+    n = x.shape[-1]
+    if axis_name is not None:
+        ss = jax.lax.psum(ss, axis_name)
+        n = n * jax.lax.psum(1, axis_name)
+    return (x32 * jax.lax.rsqrt(ss / n + eps) * w).astype(x.dtype)
+
+
+def _proj(x, w):
+    return jnp.dot(x, w.astype(x.dtype))
+
+
+def causal_document_attention(q, k, v, segment, *,
+                              query_block: int = QUERY_BLOCK):
+    """``softmax(q k^T / sqrt(dh))`` over the keys of the same document at
+    positions ``<= t``.  ``q``, ``k``, ``v`` [B, T, H, dh]; ``segment``
+    [B, T].  Blockwise with an online softmax (the ``ring_block_k``
+    formulation of ``parallel/ring_attention.py``): a block of
+    ``query_block`` queries at a time against the keys up to the block's end
+    (the blocks above the diagonal are never formed), those keys a block at
+    a time in a ``lax.scan`` whose body is rematerialised, so nothing
+    [T, T] is ever alive; statistics in float32.  One masked softmax over
+    all keys of a query block measured 7x slower on the v5e: XLA fuses the
+    row maximum into a lane-reduce over [H, 1024, 8192] float32 that runs
+    far under the memory bandwidth (PERF.md, PR 31)."""
+    b, t, h, dh = q.shape
+    scale = 1.0 / math.sqrt(dh)
+    block = min(query_block, t)
+    pos = jnp.arange(t)
+    low = -1e30   # finite: a row whose keys so far are all masked stays exact
+
+    def rows(q_b, k_b, v_b, seg_q, seg_k, pos_q, pos_k):
+        n = k_b.shape[1] // block
+
+        def keys(carry, xs):
+            o, m, l = carry
+            k_i, v_i, seg_i, pos_i = xs
+            logits = jnp.einsum("bqhd,bkhd->bhqk", q_b, k_i,
+                                preferred_element_type=jnp.float32) * scale
+            ok = ((pos_i[None, :] <= pos_q[:, None])[None]
+                  & (seg_i[:, None, :] == seg_q[:, :, None]))[:, None]
+            logits = jnp.where(ok, logits, low)
+            m_new = jnp.maximum(m, logits.max(axis=-1))
+            p = jnp.where(ok, jnp.exp(logits - m_new[..., None]), 0.0)
+            shrink = jnp.exp(m - m_new)
+            l = l * shrink + p.sum(axis=-1)
+            o = o * shrink[..., None] + jnp.einsum(
+                "bhqk,bkhd->bhqd", p.astype(v_i.dtype), v_i,
+                preferred_element_type=jnp.float32)
+            return (o, m_new, l), None
+
+        split = lambda a: jnp.moveaxis(
+            a.reshape(a.shape[0], n, block, *a.shape[2:]), 1, 0)
+        # zeros made FROM the queries: under a shard_map they vary over the
+        # mesh axis as the body's outputs do
+        o0 = jnp.swapaxes(q_b, 1, 2).astype(jnp.float32) * 0.0
+        init = (o0, o0[..., 0] + low, o0[..., 0])
+        (o, _, l), _ = jax.lax.scan(
+            jax.checkpoint(keys), init,
+            (split(k_b), split(v_b), split(seg_k), pos_k.reshape(n, block)))
+        return jnp.swapaxes(o / l[..., None], 1, 2).astype(v_b.dtype)
+
+    out = []
+    for a in range(0, t, block):
+        e = min(a + block, t)
+        pad = -e % block   # a ragged last block: keys no query may see
+        widen = lambda x, fill=0: jnp.pad(
+            x[:, :e], [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2),
+            constant_values=fill)
+        out.append(rows(q[:, a:e], widen(k), widen(v), segment[:, a:e],
+                        widen(segment, -1), pos[a:e],
+                        jnp.pad(pos[:e], (0, pad), constant_values=t)))
+    return jnp.concatenate(out, axis=1) if len(out) > 1 else out[0]
+
+
+def full_attention_mixer(p, x, segment, cfg: OlmoHybridConfig, *,
+                         axis_name: str | None = None):
+    """``x`` [B, T, d] -> ``W_o``'s output over the heads held here."""
+    b, t, _ = x.shape
+    with jax.named_scope("full_attn"):
+        eps = cfg.rms_norm_eps
+        q = rms_norm(_proj(x, p["wq"]), p["q_norm"], eps, axis_name=axis_name)
+        k = rms_norm(_proj(x, p["wk"]), p["k_norm"], eps, axis_name=axis_name)
+        v = _proj(x, p["wv"])
+        heads = lambda a: a.reshape(b, t, -1, cfg.head_dim)
+        o = causal_document_attention(heads(q), heads(k), heads(v), segment)
+        return _proj(o.reshape(b, t, -1), p["wo"])
+
+
+def _causal_conv(x, w, segment):
+    """Depthwise causal convolution over time; taps before the token's
+    document start are zero.  ``x`` [B, T, C], ``w`` [K, C]."""
+    t, kw = x.shape[1], w.shape[0]
+    w = w.astype(x.dtype)
+    y = x * w[kw - 1]
+    for s in range(1, kw):
+        xs = jnp.pad(x, ((0, 0), (s, 0), (0, 0)))[:, :t]
+        seg = jnp.pad(segment, ((0, 0), (s, 0)), constant_values=-1)[:, :t]
+        y = y + jnp.where((seg == segment)[..., None], xs, 0) * w[kw - 1 - s]
+    return y
+
+
+def gated_delta_mixer(p, x, segment, cfg: OlmoHybridConfig):
+    """``x`` [B, T, d] -> ``W_o``'s output over the heads held here."""
+    b, t, _ = x.shape
+    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    f32 = jnp.float32
+    with jax.named_scope("deltanet_proj"):
+        q, k, v = _proj(x, p["wq"]), _proj(x, p["wk"]), _proj(x, p["wv"])
+        gate = _proj(x, p["wg"])
+        a = jnp.dot(x, p["wa"].astype(x.dtype), preferred_element_type=f32)
+        bb = jnp.dot(x, p["wb"].astype(x.dtype), preferred_element_type=f32)
+        beta = jax.nn.sigmoid(bb) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
+        g = -jnp.exp(p["A_log"]) * jax.nn.softplus(a + p["dt_bias"])
+    with jax.named_scope("deltanet_conv"):
+        conv = lambda a, w: jax.nn.silu(_causal_conv(a, w, segment))
+        q = conv(q, p["conv_q"]).reshape(b, t, -1, dk).astype(f32)
+        k = conv(k, p["conv_k"]).reshape(b, t, -1, dk).astype(f32)
+        v = conv(v, p["conv_v"]).reshape(b, t, -1, dv)
+        unit = lambda a: a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True))
+        q = (unit(q) / math.sqrt(dk)).astype(x.dtype)
+        k = unit(k).astype(x.dtype)
+    with jax.named_scope("deltanet_scan"):
+        starts = jnp.concatenate(
+            [jnp.ones((b, 1), bool), segment[:, 1:] != segment[:, :-1]], axis=1)
+        o = chunk_gated_delta_rule(q, k, v, g, beta, starts)
+    with jax.named_scope("deltanet_proj"):
+        y = rms_norm(o, p["o_norm"], cfg.rms_norm_eps)
+        y = y * jax.nn.silu(gate.reshape(b, t, -1, dv))
+        return _proj(y.reshape(b, t, -1), p["wo"])
+
+
+def _block(p, x, segment, kind: str, cfg: OlmoHybridConfig, axis_name):
+    eps = cfg.rms_norm_eps
+    if kind == "full_attention":
+        mixed = full_attention_mixer(p["mixer"], x, segment, cfg,
+                                     axis_name=axis_name)
+    else:
+        mixed = gated_delta_mixer(p["mixer"], x, segment, cfg)
+    h = x + rms_norm(mixed, p["mixer_norm"], eps)
+    with jax.named_scope("mlp"):
+        m = p["mlp"]
+        y = _proj(jax.nn.silu(_proj(h, m["gate"])) * _proj(h, m["up"]), m["down"])
+        return h + rms_norm(y, p["mlp_norm"], eps)
+
+
+def backbone(params, x, segment, cfg: OlmoHybridConfig, *,
+             axis_name: str | None = None):
+    """``x`` [B, T, d] through every layer, each rematerialised in the
+    backward pass.  A layer keeps its input and the outputs of its
+    projections (matrix products with no batch dimension: about 0.6 GB a
+    layer at 8k tokens and the published widths); everything else runs
+    again.  Keeping the input alone read 472 against 442 ms a step at the
+    SAME peak memory on the v5e (PERF.md, PR 31)."""
+    keep = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    for i, kind in enumerate(cfg.layer_types):
+        layer = jax.checkpoint(
+            lambda p, x, kind=kind: _block(p, x, segment, kind, cfg, axis_name),
+            policy=keep)
+        x = layer(params[f"layer_{i}"], x)
+    return x
+
+
+def next_token_loss(params, x, token, segment, cfg: OlmoHybridConfig):
+    """Mean cross-entropy of token ``t + 1`` at position ``t`` where both
+    lie in one document; ``(loss, labelled positions)``.  The [T, V] logits
+    are float32 and rematerialised in the backward pass."""
+
+    @jax.checkpoint
+    def loss(final_norm, head, x):
+        h = rms_norm(x, final_norm, cfg.rms_norm_eps)
+        logits = jnp.dot(h, head.astype(h.dtype),
+                         preferred_element_type=jnp.float32)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nxt = jnp.concatenate([token[:, 1:], token[:, :1]], axis=1)
+        labelled = jnp.concatenate(
+            [segment[:, 1:] == segment[:, :-1],
+             jnp.zeros((token.shape[0], 1), bool)], axis=1)
+        picked = jnp.take_along_axis(logp, nxt[..., None], axis=-1)[..., 0]
+        n = jnp.maximum(labelled.sum(), 1)
+        return -jnp.where(labelled, picked, 0.0).sum() / n, n
+
+    with jax.named_scope("lm_head_loss"):
+        return loss(params["final_norm"], params["head"], x)
+
+
+def forward_loss(params, embedded, token, segment, cfg: OlmoHybridConfig, *,
+                 dtype=jnp.float32, axis_name: str | None = None):
+    """The training forward: gathered vectors ``embedded`` [B, T, d] ->
+    scalar next-token loss."""
+    with jax.named_scope("lm_embed"):
+        x = embedded.astype(dtype)
+    x = backbone(params, x, segment, cfg, axis_name=axis_name)
+    return next_token_loss(params, x, token, segment, cfg)[0]

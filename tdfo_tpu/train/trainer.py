@@ -406,6 +406,32 @@ def _copy_tree(tree):
         lambda x: jnp.copy(x) if isinstance(x, jax.Array) else x, tree)
 
 
+# second-moment decay of the decoder's AdamW and table Adam (betas 0.9, 0.95:
+# the family's pre-training recipe)
+_LM_ADAM_B2 = 0.95
+
+
+def _count_lm_batches(stream, seq_len: int):
+    """Packed-sequence batches (``token`` and ``segment`` of [B, T] int32) as
+    the step takes them, counted on the way: ``lm_tokens``, ``lm_docs``
+    (document starts) and ``lm_label_tokens`` (positions whose next token
+    lies in the same document) go to the open epoch's record
+    (``obs.trace.tally``; this runs on ``prefetch_to_mesh``'s producer
+    thread, which has joined the epoch) and from there to the epoch line."""
+    for b in stream:
+        token = np.ascontiguousarray(b["token"], np.int32)
+        segment = np.ascontiguousarray(b["segment"], np.int32)
+        if token.shape[1:] != (seq_len,) or segment.shape != token.shape:
+            raise ValueError(
+                f"olmo_hybrid: the data holds sequences of {token.shape[1:]} "
+                f"tokens (segment {segment.shape}), max_len says {seq_len}")
+        same = segment[:, 1:] == segment[:, :-1]
+        obs_trace.tally("lm_tokens", token.size)
+        obs_trace.tally("lm_docs", int(same.size - same.sum()) + len(segment))
+        obs_trace.tally("lm_label_tokens", int(same.sum()))
+        yield {"token": token, "segment": segment}
+
+
 def _check_cache_overflow(overflow: dict) -> None:
     """Fail LOUDLY on update-cache admission overflow: ids past the free
     capacity never entered the cache, so their updates were silently lost
@@ -500,6 +526,8 @@ class Trainer:
             self._build_ctr()
         elif cfg.model == "bert4rec":
             self._build_bert4rec()
+        elif cfg.model == "olmo_hybrid":
+            self._build_olmo_hybrid()
         else:
             raise ValueError(f"unknown model {cfg.model!r}")
         # model.tabulate-equivalent observability (jax-flax/models.py:154-155)
@@ -1030,6 +1058,85 @@ class Trainer:
 
         self.eval_accum = eval_accum
 
+    def _build_olmo_hybrid(self) -> None:
+        """The ``olmo_hybrid`` decoder in the DMP regime, wired as
+        ``_build_bert4rec`` wires its model-parallel one: the vocabulary
+        (slice) as one table of the collection (under the fused threshold it
+        is a plain table: gather lookup, row-sparse Adam on touched rows),
+        backbone and head as dense leaves under AdamW, one
+        ``make_sparse_train_step`` whose forward is the backbone plus
+        next-token cross-entropy.  The dense state is many GiB, so unlike
+        the other builders this one DONATES the step's state (config
+        requires ``nonfinite_tolerance = 0``: the guard's second copy has no
+        room), rematerialises by layer (``models/olmo_hybrid.backbone``)
+        and computes in bfloat16 on TPU under ``mixed_precision`` with
+        float32 parameters, optimizer state and softmax."""
+        from tdfo_tpu.core.precision import compute_dtype
+        from tdfo_tpu.models.olmo_hybrid import (
+            OlmoHybridConfig, forward_loss, init_olmo_hybrid)
+        from tdfo_tpu.ops.sparse import sparse_optimizer
+        from tdfo_tpu.parallel.embedding import (
+            EmbeddingSpec, ShardedEmbeddingCollection)
+        from tdfo_tpu.train.sparse_step import (
+            SparseTrainState, make_sparse_train_step)
+
+        cfg, lm = self.config, self.config.lm
+        # the [lm] table's keys are the model configuration's own (its chunk
+        # and block sizes are the model's constants, measured on the v5e)
+        self.model_cfg = OlmoHybridConfig(**{
+            f.name: getattr(lm, f.name) for f in dataclasses.fields(lm)})
+        sharding = cfg.embedding_sharding if cfg.model_parallel else "replicated"
+        fused_at = cfg.effective_fused_threshold
+        self.coll = ShardedEmbeddingCollection(
+            [EmbeddingSpec(
+                "token_embedding", num_embeddings=lm.vocab_size,
+                embedding_dim=lm.hidden_size, features=("token",),
+                sharding=sharding, init_scale=0.02,
+                fused=(fused_at is not None
+                       and sharding in ("row", "replicated")
+                       and lm.vocab_size > fused_at))],
+            mesh=self.mesh, fused_kind=cfg.sparse_optimizer)
+        k_table, k_dense = jax.random.split(jax.random.key(cfg.seed))
+        tables = self.coll.init(k_table)
+        # initialised under jit so that every leaf is made where it lives
+        dense = jax.jit(lambda k: init_olmo_hybrid(k, self.model_cfg),
+                        out_shardings=NamedSharding(self.mesh, P()))(k_dense)
+        self.state = _commit_replicated(SparseTrainState.create(
+            dense_params=dense,
+            tx=optax.adamw(cfg.learning_rate, b2=_LM_ADAM_B2,
+                           weight_decay=cfg.weight_decay),
+            tables=tables,
+            # the table takes Adam without decay (no decay on embeddings)
+            sparse_opt=sparse_optimizer(
+                cfg.sparse_optimizer, lr=cfg.learning_rate, weight_decay=0.0,
+                b2=_LM_ADAM_B2),
+        ), self.mesh)
+        dtype = compute_dtype(cfg.mixed_precision, mesh_platform(self.mesh))
+        model_cfg = self.model_cfg
+
+        def forward(dense_params, embs, batch):
+            return forward_loss(dense_params, embs["token"], batch["token"],
+                                batch["segment"], model_cfg, dtype=dtype)
+
+        step = make_sparse_train_step(
+            self.coll, forward, mode=cfg.lookup_mode,
+            jit=not self._counters_on, dedup_lookup=cfg.dedup_lookup)
+        self.train_step = (_wrap_counters_step(step, donate_state=True)
+                           if self._counters_on else step)
+        self._train_auc_enabled = False
+        self._dropout_rng = jax.random.key(cfg.seed + 1)  # the step's rng slot
+        self._stream_cls = ParquetStream
+        self._train_pattern = str(Path("parquet_lm") / cfg.train_data)
+        self._eval_pattern = str(Path("parquet_lm") / cfg.eval_data)
+        coll, mode = self.coll, cfg.lookup_mode
+
+        @jax.jit
+        def eval_loss(state, batch):
+            embs = coll.lookup(state.tables, {"token": batch["token"]}, mode=mode)
+            return forward(state.dense_params, embs, batch)
+
+        self._eval_loss = eval_loss
+
     # --------------------------------------------------------------- epochs
 
     def _stream(self, pattern: str, *, train: bool):
@@ -1106,6 +1213,8 @@ class Trainer:
             renamed = (
                 {"item": b["train_interactions"], "label": b["labels"]} for b in stream
             )
+        elif cfg.model == "olmo_hybrid":
+            renamed = _count_lm_batches(stream, cfg.max_len)
         else:
             renamed = iter(stream)
         inj = _faults.active()
@@ -1321,7 +1430,7 @@ class Trainer:
                             out = self.train_step(
                                 self.state, batch, carry, train_auc)
                             self.state, loss, carry, train_auc = out[:4]
-                    elif cfg.model == "bert4rec":
+                    elif cfg.model in ("bert4rec", "olmo_hybrid"):
                         out = self.train_step(
                             self.state, batch, self._dropout_rng)
                         self.state, loss = out[:2]
@@ -1434,11 +1543,14 @@ class Trainer:
             extra[f"phase_{name}_s"] = seconds
             if name == "next_batch":
                 extra["phase_next_batch_max_ms"] = 1e3 * longest
-        depth_sum, takes = timed["tallies"].get("prefetch_depth", (0.0, 0))
+        tallies = dict(timed["tallies"])
+        depth_sum, takes = tallies.pop("prefetch_depth", (0.0, 0))
+        empty = tallies.pop("prefetch_empty_takes", (0.0, 0))[1]
         if takes:
             extra["prefetch_depth_mean"] = depth_sum / takes
-            extra["prefetch_empty_takes"] = timed["tallies"].get(
-                "prefetch_empty_takes", (0.0, 0))[1]
+            extra["prefetch_empty_takes"] = empty
+        for name, (total, _) in tallies.items():  # counts: lm_tokens, ...
+            extra[name] = int(total)
         self.logger.log(
             epoch=epoch, train_loss_epoch=avg, steps=n_steps,
             examples_per_sec=ran * cfg.per_device_train_batch_size
@@ -1509,6 +1621,8 @@ class Trainer:
         with self._jit_ctx():
             if self.config.model == "bert4rec":
                 return self._evaluate_bert4rec(epoch)
+            if self.config.model == "olmo_hybrid":
+                return self._evaluate_lm(epoch)
             return self._evaluate_twotower(epoch)
 
     def _eval_batches(self, rename: Callable[[dict], dict] | None = None,
@@ -1593,6 +1707,21 @@ class Trainer:
             "eval_loss": float(acc["loss_sum"]) / w,
             "auc": float(acc["auc"].result()),
         }
+        self.logger.log(epoch=epoch, **metrics)
+        return metrics
+
+    def _evaluate_lm(self, epoch: int) -> dict[str, float]:
+        """Mean next-token loss over the eval shards' whole batches (a batch
+        weighs the same whatever its labelled positions)."""
+        stream = self._stream(self._eval_pattern, train=False)
+        losses = []
+        full = (b for b in _count_lm_batches(stream, self.config.max_len)
+                if len(b["token"]) == stream.batch_size)
+        for batch in prefetch_to_mesh(full, self.mesh, P("data")):
+            losses.append(self._eval_loss(self.state, batch))
+        if not losses:
+            return {}
+        metrics = {"eval_loss": float(jnp.mean(jnp.stack(losses)))}
         self.logger.log(epoch=epoch, **metrics)
         return metrics
 
