@@ -161,8 +161,30 @@ def rms_norm(x, w, eps: float, *, axis_name: str | None = None):
     return (x32 * jax.lax.rsqrt(ss / n + eps) * w).astype(x.dtype)
 
 
+@jax.custom_vjp
+def _grad_apart(w):
+    """Identity on a weight as its product reads it (cast to the compute
+    dtype).  Its cotangent, the weight-gradient product ``x^T dy`` in that
+    dtype, passes through an ``optimization_barrier`` of its own, so it is
+    materialised (85 MB in bfloat16 for a SwiGLU leaf) before the cast to
+    float32 and the optimizer see it.  Without it XLA makes ONE fusion a
+    leaf of the operands' producers (the norm of ``x``, the activation's
+    backward), the product, the cast and AdamW's sweep of parameter and
+    both moments, tiled for the three float32 outputs: 8.0-11.4 ms a
+    [3840, 11008] leaf on the v5e, a third of the MXU peak; apart the
+    product takes under 4.8 ms and the sweep 1.65, and the step 395 for 442
+    ms (PERF.md, PR 33).  One barrier a leaf: one over the whole gradient
+    tree would keep every leaf's gradient alive at once.  Nothing is saved
+    for the backward pass and the same values come out."""
+    return w
+
+
+_grad_apart.defvjp(lambda w: (w, None),
+                   lambda _, g: (jax.lax.optimization_barrier(g),))
+
+
 def _proj(x, w):
-    return jnp.dot(x, w.astype(x.dtype))
+    return jnp.dot(x, _grad_apart(w.astype(x.dtype)))
 
 
 def causal_document_attention(q, k, v, segment, *,
@@ -324,7 +346,7 @@ def next_token_loss(params, x, token, segment, cfg: OlmoHybridConfig):
     @jax.checkpoint
     def loss(final_norm, head, x):
         h = rms_norm(x, final_norm, cfg.rms_norm_eps)
-        logits = jnp.dot(h, head.astype(h.dtype),
+        logits = jnp.dot(h, _grad_apart(head.astype(h.dtype)),
                          preferred_element_type=jnp.float32)
         logp = jax.nn.log_softmax(logits, axis=-1)
         nxt = jnp.concatenate([token[:, 1:], token[:, :1]], axis=1)

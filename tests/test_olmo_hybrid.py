@@ -292,3 +292,135 @@ def test_packed_documents_fill_the_sequence():
     for row in segment:
         sizes = np.bincount(row)
         assert (sizes[:-1] >= 16).all() and sizes.max() <= 256
+
+
+# ---- the weight gradients are held apart from the optimizer's sweep (PR 33)
+
+
+def plain_products(monkeypatch):
+    """The formulation before PR 33, kept here: ``x @ w`` with the weight
+    cast where it is used and nothing between its gradient and the
+    optimizer."""
+    monkeypatch.setattr(M, "_proj", lambda x, w: jnp.dot(x, w.astype(x.dtype)))
+    monkeypatch.setattr(M, "_grad_apart", lambda w: w)
+
+
+def product_leaves(params) -> list[tuple]:
+    """Shapes of the leaves whose products go through ``_proj`` or the
+    head's ``jnp.dot``: every matrix but the delta-rule layers' tiny ``wa``
+    / ``wb`` and the convolutions."""
+    return sorted(
+        a.shape for path, a in jax.tree.leaves_with_path(params)
+        if a.ndim == 2 and path[-1].key not in ("wa", "wb")
+        and not path[-1].key.startswith("conv_"))
+
+
+def barriers(jaxpr) -> list[list[tuple]]:
+    """The operand shapes of every ``optimization_barrier`` in a jaxpr and
+    in the jaxprs nested in it."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "optimization_barrier":
+            found.append([v.aval.shape for v in eqn.invars])
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += barriers(sub)
+    return found
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_gradients_are_those_of_the_plain_products(dtype, monkeypatch):
+    cfg = model_cfg(layer_types=("linear_attention", "full_attention"))
+    params = M.init_olmo_hybrid(jax.random.key(2), cfg)
+    rng = np.random.default_rng(2)
+    token, segment = packed(rng, 2, 40)
+    emb = 0.1 * rng.normal(size=(2, 40, 32)).astype(np.float32)
+
+    def value_and_grads():
+        return jax.jit(jax.value_and_grad(lambda p, e: M.forward_loss(
+            p, e, token, segment, cfg, dtype=dtype), (0, 1)))(params, emb)
+
+    loss, grads = value_and_grads()
+    plain_products(monkeypatch)
+    want_loss, want = value_and_grads()
+    assert float(loss) == float(want_loss)
+    for (path, a), b in zip(jax.tree.leaves_with_path(grads),
+                            jax.tree.leaves(want)):
+        assert a.dtype == jnp.float32
+        np.testing.assert_array_equal(a, b, err_msg=str(path))
+
+
+def test_three_trainer_steps_are_those_of_the_plain_products(
+        tmp_path, monkeypatch):
+    from tdfo_tpu.train.trainer import Trainer
+
+    write_epoch(tmp_path, *draw_sequences(0, 6, 48, 50, documents(4, 48)),
+                files=2)
+
+    def three_steps():
+        trainer = Trainer(trainer_config(tmp_path), devices=jax.devices()[:1])
+        loss = trainer.train_epoch(0)
+        trainer.logger.close()
+        assert int(trainer.state.step) == 3
+        return loss, jax.device_get(
+            (trainer.state.dense_params, trainer.state.opt_state,
+             trainer.state.tables))
+
+    loss, state = three_steps()
+    plain_products(monkeypatch)
+    want_loss, want = three_steps()
+    assert loss == want_loss
+    for (path, a), b in zip(jax.tree.leaves_with_path(state),
+                            jax.tree.leaves(want)):
+        np.testing.assert_array_equal(a, b, err_msg=str(path))
+
+
+def test_the_step_holds_every_product_leafs_gradient_apart(tmp_path):
+    """One barrier a ``_proj`` leaf (and the head), each over that leaf's
+    gradient alone and none over the whole tree: a later edit cannot put
+    the product + AdamW fusion back in silence, nor hold every gradient
+    alive at once."""
+    from tdfo_tpu.train.trainer import Trainer
+
+    trainer = Trainer(trainer_config(tmp_path, lm=LM),
+                      devices=jax.devices()[:1])
+    batch = {k: jnp.zeros((2, 48), jnp.int32) for k in ("token", "segment")}
+    jaxpr = jax.make_jaxpr(trainer.train_step)(
+        trainer.state, batch, trainer._dropout_rng)
+    trainer.logger.close()
+    held = barriers(jaxpr.jaxpr)
+    assert all(len(shapes) == 1 for shapes in held), held
+    want = product_leaves(trainer.state.dense_params)
+    assert len(want) == 15 + 4 + 12 + 1
+    assert sorted(shapes[0] for shapes in held) == want
+
+
+@pytest.mark.parametrize("kind", M.LAYER_KINDS)
+def test_a_rematerialised_layer_saves_what_the_plain_products_save(
+        kind, monkeypatch, capsys):
+    from jax.ad_checkpoint import print_saved_residuals
+
+    cfg = model_cfg(layer_types=(kind,))
+    params = M.init_olmo_hybrid(jax.random.key(3), cfg)
+    rng = np.random.default_rng(3)
+    x = rng.normal(size=(2, 40, 32)).astype(np.float32)
+    _, segment = packed(rng, 2, 40)
+
+    def saved():
+        """``(type, what it is)`` of every residual, less where in the
+        source it was made."""
+        print_saved_residuals(
+            lambda p, x: M.backbone(p, x, segment, cfg).sum(), params, x)
+        return sorted(line.split(" from ")[0]
+                      for line in capsys.readouterr().out.splitlines())
+
+    got = saved()
+    plain_products(monkeypatch)
+    assert got == saved()
+    # the policy keeps the products' outputs (it marks each with a
+    # ``reduce_precision``), the new form among them: nothing runs twice
+    # that did not before
+    kept = [line for line in got if "reduce_precision" in line]
+    assert len(kept) == (6 if kind == "full_attention" else 9), got
