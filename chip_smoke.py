@@ -53,15 +53,17 @@ from tdfo_tpu.obs import trace as obs_trace  # the repo's one host-clock site
 
 DLRM_CRITEO_TOML = Path(__file__).resolve().parent / "configs" / "dlrm-criteo.toml"
 
-# Criteo-Kaggle per-column vocabularies (= bench.py CRITEO_KAGGLE_VOCABS,
-# pinned equal by tests/test_chip_smoke.py): 33,762,577 rows in 26 tables.
+# Criteo-Kaggle per-column vocabularies (the standard 26-table profile of the
+# public DLRM benchmarks; tests/test_chip_smoke.py pins them equal to
+# tests/test_planner.py's copy): 33,762,577 rows in 26 tables.
 CRITEO_KAGGLE_VOCABS = (
     1460, 583, 10131227, 2202608, 305, 24, 12517, 633, 3, 93145, 5683,
     8351593, 3194, 27, 14992, 5461306, 10, 5652, 2173, 4, 7046547, 18, 15,
     286181, 105, 142572,
 )
-# TwoTower vocabularies of the kernels phase (= bench.py SIZE_MAP): user and
-# item sit above the default fused_table_threshold, the rest below it.
+# TwoTower vocabularies of the kernels phase (pinned by literal in
+# tests/test_chip_smoke.py): user and item sit above the default
+# fused_table_threshold, the rest below it.
 TWOTOWER_SIZE_MAP = {
     "user": 500_000, "item": 200_000, "language": 32, "is_ebook": 2,
     "format": 16, "publisher": 5_000, "pub_decade": 16,

@@ -347,9 +347,10 @@ class LoadgenSpec:
     # rng seed for the request stream (ids, continuous features, arrival
     # jitter) — a fixed seed makes knee runs comparable across builds.
     seed: int = 606
-    # the SLO the knee is measured against: bench.py serve_fleet reports
-    # sustained QPS/replica at this p99 bound, and past the knee admitted
-    # requests must still meet it while sheds are counted, never silent.
+    # the SLO the knee is measured against: serve/loadgen.py `knee()`
+    # reports sustained QPS/replica at this p99 bound, and past the knee
+    # admitted requests must still meet it while sheds are counted, never
+    # silent.
     p99_slo_ms: float = 50.0
 
 
@@ -624,7 +625,8 @@ class Config:
     # (sequence-parallel over the seq mesh axis; XLA blockwise innards —
     # the fastest long-T path measured on v5e), "ring_flash" (ring with the
     # Pallas flash kernels inside each ring step; ~2.4x slower than "ring"
-    # at dh=64 on v5e — see bench_kernels.bench_ring_flash), "flash"
+    # at dh=64 on v5e — builders' reading, round 4; no program in the
+    # tree reproduces it), "flash"
     # (single-device Pallas O(T) kernel; compiled, its blocks are whole
     # 128-lane tiles, so T pads up to a multiple of 128 — max_len = 20
     # runs as one masked 128-block: it compiles and is exact, and wastes
@@ -662,8 +664,9 @@ class Config:
     # (ops/pallas_kernels.line_layout + the in-place DMA update kernel,
     # available for EVERY sparse_optimizer kind); smaller tables take the
     # gather/scatter or one-hot MXU tiers.  0 fuses every table; -1 disables
-    # fused storage entirely (every table stays plain 2D — the measured-
-    # faster choice at the DLRM-Criteo profile, docs/BUDGET.md).  The kernel
+    # fused storage entirely (every table stays plain 2D — the faster
+    # choice at the DLRM-Criteo profile in the builders' round-4 readings,
+    # docs/BUDGET.md "Fused fat-line findings").  The kernel
     # choice itself follows the platform of the devices the tables live on
     # (core/mesh.pallas_impl: the Mosaic kernel on TPU devices or an error,
     # the XLA formulation on CPU devices) — there is no "use pallas" switch

@@ -111,8 +111,8 @@ def spoof_cpu_devices(n: int = 8) -> None:
 def configure_compile_cache() -> str:
     """Place JAX's persistent compilation cache; returns the directory.
 
-    Call before the first compile (``launch.main``, ``chip_smoke.py`` and the
-    benches do).  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+    Call before the first compile (``launch.main`` and ``chip_smoke.py``
+    do).  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
     itself and nothing is set in code.  Otherwise the cache lives at the fixed
     ``<checkout>/.jax_cache`` — the directory is part of every entry's key,
     so a temp name, pid or timestamp in it would mean a cache that never

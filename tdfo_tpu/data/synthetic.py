@@ -17,20 +17,8 @@ from pathlib import Path
 import numpy as np
 import pandas as pd
 
-__all__ = ["write_synthetic_goodreads", "write_synthetic_criteo", "zipf_ids"]
+__all__ = ["write_synthetic_goodreads", "write_synthetic_criteo"]
 
-
-def zipf_ids(rng: np.random.Generator, vocab: int, size,
-             *, a: float = 1.2) -> np.ndarray:
-    """Frequency-RANKED power-law ids: id ``i`` drawn with mass ∝ (i+1)^-a,
-    so low ids are the hot head — exactly the layout the Criteo ETL
-    produces (ids assigned by descending frequency, 0 = OOV absorbing the
-    folded tail).  Samples past the vocab wrap onto the head (they carry
-    the zipf tail's negligible mass).  The bench harness uses this to
-    model real power-law lookup traffic; uniform ids would understate
-    every frequency-partitioned optimisation."""
-    ids = rng.zipf(a, size).astype(np.int64) - 1
-    return (ids % vocab).astype(np.int32)
 
 _LANGS = ["eng", "en-US", "spa", "fre", "ger", ""]
 _FORMATS = ["Paperback", "Hardcover", "ebook", "Audio CD", ""]

@@ -96,9 +96,10 @@ def clock() -> float:
     (the one sanctioned monotonic-differencing site) so callers never
     lexically difference a clock, and the quality gate can audit every
     wall-time measurement in one place.  For device work the interval
-    must end in ``block_until_ready`` or a value fetch; ``bench.chain_time``
-    is the inherited chain-differencing method (to be re-validated,
-    ROADMAP S0)."""
+    must end in ``block_until_ready`` or a value fetch.  Speed is measured
+    on the benchmark's clock: ``benchmarks/lib/monitor.py::now`` around
+    whole ``Trainer.train_epoch`` calls that end in a value fetch, device
+    times from the profiler trace (``PERF.md`` section 2)."""
     return time.monotonic()
 
 

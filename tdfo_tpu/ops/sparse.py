@@ -413,7 +413,8 @@ def dense_lazy_rowwise_adagrad(table, accum, ids, grads, *, lr, eps=1e-10,
 #
 # fbgemm's cached TBE (``EmbeddingLocation.MANAGED_CACHING`` + ``lxu_cache``)
 # rebuilt for a chip whose scatter costs ~60-110 ns/slot regardless of hints
-# (docs/BUDGET.md): the step's touched rows live in a small dense cache —
+# (docs/BUDGET.md, the DLRM-Criteo table): the step's touched rows live in a
+# small dense cache —
 # sorted-id directory, [C, d] value array, optimizer-slot mirrors, dirty mask,
 # frequency/recency counters — all plain arrays carried in the train state.
 # Misses are ADMITTED (a gather-only copy of the authoritative big-table row),
@@ -728,8 +729,9 @@ def _fat_apply_rows_int8(fat, uids, g, *, layout, lr, b1=0.9, b2=0.999,
     for sgd, ``component_key(key, 0)`` otherwise, mirroring
     :func:`_requantize_scatter` callers), re-encode, scatter the rows back.
     Sentinel uids (int32 max) clamp on the gather and drop on the scatter.
-    The flattening view reshape materialises on TPU (docs/BUDGET.md prices
-    it); the in-place DMA kernel does not cover int8 lines yet."""
+    The flattening view reshape materialises on TPU
+    (``plan/costs.RESHAPE_MS_PER_GB`` prices it); the in-place DMA kernel
+    does not cover int8 lines yet."""
     from tdfo_tpu.ops.pallas_kernels import fat_view
     from tdfo_tpu.ops.quant import bytes_to_f32, f32_to_bytes
 

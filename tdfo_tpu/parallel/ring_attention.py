@@ -201,12 +201,13 @@ def ring_flash_attention(
     full lap.  Numerics match :func:`ring_attention` (same online softmax,
     f32 statistics).  Must run inside ``shard_map`` like ring_attention.
 
-    Measured on v5e (T=8192, Dh=64, fwd+bwd): the XLA ring with
-    ``ring_block_k`` is ~2.4x FASTER than this path (4.9 ms vs 11.7 ms,
-    ``bench_kernels.bench_ring_flash``) — the FlashAttention-2 backward pays
-    two probability recomputes (separate dQ and dK/dV kernels) where XLA's
-    rematerialised blockwise scan pays one, and XLA already pipelines the
-    blockwise forward well.  ``impl="xla"`` therefore stays the default;
+    Builders' reading on v5e, round 4 (T=8192, Dh=64, fwd+bwd; no program
+    in the tree reproduces it): the XLA ring with ``ring_block_k`` is ~2.4x
+    FASTER than this path (4.9 ms vs 11.7 ms) — the FlashAttention-2
+    backward pays two probability recomputes (separate dQ and dK/dV
+    kernels) where XLA's rematerialised blockwise scan pays one, and XLA
+    already pipelines the blockwise forward well.  ``impl="xla"``
+    therefore stays the default;
     this path exists for parity with kernel-based stacks and for shapes
     where hand scheduling wins (wider Dh, fused downstream ops).
     """

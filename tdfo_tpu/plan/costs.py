@@ -1,12 +1,15 @@
 """Measured v5e step-cost table — docs/BUDGET.md as an executable model.
 
 Every constant in this module is a per-descriptor cost fitted to the
-chain-differenced IN-SITU ablations in ``docs/BUDGET.md`` (the cumulative
-piece tables measured on the real chip, NOT isolated-op microbenchmarks —
-the fat-line kernel measured 3x slower in situ than isolated, so isolated
-numbers are banned here).  This is the single sanctioned home for numeric
-cost constants: ``tests/test_quality.py`` rejects ``*_NS``/``*_US``/``*_MS``
-constants anywhere else in the tree, so the measured numbers cannot fork.
+builders' round-4 IN-SITU ablations in ``docs/BUDGET.md`` (the cumulative
+piece tables taken on the real chip by chain differencing, with a harness
+since deleted; NOT isolated-op microbenchmarks — the fat-line kernel
+measured 3x slower in situ than isolated, so isolated numbers are banned
+here).  None is a ledger number; where a constant rests on an expectation
+that was never measured, its comment says so.  This is the single
+sanctioned home for numeric cost constants: ``tests/test_quality.py``
+rejects ``*_NS``/``*_US``/``*_MS`` constants anywhere else in the tree, so
+the measured numbers cannot fork.
 
 Calibration contract (``tests/test_planner.py``): :func:`estimate_step_ms`
 must reproduce BOTH BUDGET.md in-situ step budgets with the correct
@@ -91,9 +94,10 @@ SEGSUM_NS_PER_TARGET = 39.0
 SCATTER_NS_PER_SLOT_PER_BUFFER = 54.0
 
 # update-cache scatters target the small [C, d] cache arrays (MBs, not
-# GBs) — BUDGET.md's cache_zipf section brackets them 0.05-0.5 ms for ~3k
-# rows x 2 buffers (8-80 ns/slot/buffer, the open question being whether
-# a cache-resident target beats the multi-GB floor).  27 = half the
+# GBs) — never measured on the chip: the builders' expectation (round 4)
+# brackets them 0.05-0.5 ms for ~3k rows x 2 buffers (8-80
+# ns/slot/buffer, the open question being whether a cache-resident
+# target beats the multi-GB floor).  27 = half the
 # big-table floor is the bracket's middle; the planner only reaches for
 # it on int8 plans, where the eager path's extra sidecar buffer and
 # requantize RMW shift the break-even structurally (module docstring of
@@ -101,8 +105,8 @@ SCATTER_NS_PER_SLOT_PER_BUFFER = 54.0
 CACHE_SCATTER_NS_PER_SLOT_PER_BUFFER = 27.0
 
 # cache directory route: `searchsorted method="sort"` of the deduped ids
-# into the [C] sorted directory + the admission pair-sorts (BUDGET.md
-# cache_zipf "directory route" + "admission" rows: ~0.15-0.3 ms for 8k
+# into the [C] sorted directory + the admission pair-sorts (builders'
+# expectation, round 4, never measured on the chip: ~0.15-0.3 ms for 8k
 # ids into 131k).
 CACHE_ROUTE_NS_PER_ID = 25.0
 
@@ -129,18 +133,20 @@ LINE_GATHER_BASE_NS = 45.0
 LINE_DMA_BASE_NS_PER_DIR = 30.0
 
 # all-to-all launch allowance per sharded table per step (2 collectives
-# per direction): the single-chip bench (bench.py alltoall_per_table8)
-# measures PROGRAM OVERHEAD only and multichip ICI is unmeasured
-# (BUDGET.md grouped-exchange section), so this is a nominal launch cost,
-# not a measured ICI number — it exists so replication wins tiny tables
-# (no exchange) while row sharding wins big ones (descriptor work / n).
+# per direction): on one chip the exchange is degenerate (PROGRAM
+# OVERHEAD only) and multichip ICI is unmeasured (ROADMAP.md W2), so
+# this is a nominal launch cost, not a measured ICI number, and no
+# program in the tree reproduces it — it exists so replication wins tiny
+# tables (no exchange) while row sharding wins big ones (descriptor
+# work / n).
 A2A_US_PER_TABLE = 20.0
 
 # one-hot MXU segment-sum update for a replicated hot head / small table:
 # ~100-350 us for vocabs 5k-16k (CLAUDE.md; XLA fuses the one-hot away).
 # Modeled linear in the head size over that range with a floor — the
-# CEILING end of BUDGET.md's hot/cold expected-budget table, because the
-# per-table updates serialize in situ (the fat-line 3x lesson).
+# CEILING end of that range, because the per-table updates serialize in
+# situ (the fat-line 3x lesson); the hot/cold step itself was never
+# measured on the chip.
 ONE_HOT_BASE_US = 100.0
 ONE_HOT_BASE_VOCAB = 5000
 ONE_HOT_US_PER_ROW = (350.0 - 100.0) / (16384 - 5000)
@@ -412,7 +418,7 @@ def estimate_step_ms(
         ``parallel/embedding.cached_array_names``);
       * a ``hot_k`` head removes ``hot_mass`` of the table's traffic from
         the scattered path and pays one one-hot MXU update per table
-        (heads are per-table and serialize — BUDGET.md hot/cold table).
+        (heads are per-table and serialize — ``ONE_HOT_*`` above).
 
     Row-sharded groups divide descriptor counts by ``n_devices`` (balanced
     shards) and pay the a2a launch allowance; replicated and table-wise

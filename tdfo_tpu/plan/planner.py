@@ -20,16 +20,16 @@ deterministic order, re-pricing the whole step for each candidate, until
 a sweep changes nothing.  Tables are few (dozens) and the estimator is
 O(tables), so this is milliseconds of host work.
 
-Deliberately conservative stances (all provenanced in docs/BUDGET.md):
+Deliberately conservative stances (none rests on a chip measurement):
 
   * bf16 storage is priced step-time-NEUTRAL — the fat-line bf16 ablation
-    was never measured on the chip (BUDGET.md quantized-storage
-    section records the expected ~1.7x as UNMEASURED), so dtype is chosen
+    was never measured on the chip (the builders expected ~1.7x from the
+    DMA-byte ratio; UNMEASURED), so dtype is chosen
     only as an HBM lever (it halves allocated bytes — that part IS
     measured) during budget demotion, never on predicted speed.
   * the update cache is considered ONLY for plans that carry plain int8
     storage.  For f32/bf16 the stance stays at the pessimistic end of
-    BUDGET.md's cache_zipf expectation (break-even-to-loss: the cache
+    the builders' round-4 expectation (break-even-to-loss: the cache
     moves scatters, it does not remove them), so pure-float plans keep
     emitting ``cache_rows: 0`` and an operator opts in by hand after
     measuring.  Plain int8 shifts the break-even structurally — the
@@ -346,7 +346,7 @@ def plan_tables(
         names, stats, decisions, dim=dim, optimizer=optimizer,
         slot_dtype=slot_dtype, n_devices=n_devices)
 
-    # the all-defaults baseline the CLI/bench compare against: what the
+    # the all-defaults baseline the CLI compares against: what the
     # config defaults would build — row-sharded, fat-line storage above
     # the default fused_table_threshold, f32, no hot split
     defaults = {

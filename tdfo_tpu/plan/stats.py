@@ -69,7 +69,7 @@ BATCH_GRID = (1024, 2048, 4096, 8192, 16384, 32768,
 HEAD_K_GRID = (1024, 4096, 8192, 16384)
 
 # largest hot head the planner may choose — matches the one-hot MXU update
-# range the chip measurements cover (docs/BUDGET.md hot/cold table)
+# range the chip measurements cover (CLAUDE.md: vocabs 5k-16k)
 HEAD_IDS_CAP = 16384
 
 _TABLE_KEYS = {"vocab", "total_count", "unique_per_batch", "head_mass",
@@ -327,7 +327,7 @@ def refine_stats_from_metrics(
 
 
 def _expected_unique(vocab: int, batch: int) -> float:
-    """Uniform-traffic occupancy (used by tests/bench synthetic profiles):
+    """Uniform-traffic occupancy (used by the tests' synthetic profiles):
     ``v * (1 - (1 - 1/v)^B``)."""
     if vocab <= 0:
         return 0.0

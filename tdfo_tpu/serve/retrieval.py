@@ -293,7 +293,7 @@ def make_retrieval(
 def _bind(jitted, corpus: Corpus, *, with_qscale: bool | None = None):
     """Close the corpus over a jitted program as jit ARGUMENTS; ``.jitted``
     stays reachable for lowering inspection and compile-cache accounting
-    (``tests/test_serve_frontend.py``, bench).  Float exact programs keep
+    (``tests/test_serve_frontend.py``).  Float exact programs keep
     the historical ``(queries, vectors, ids)`` signature; qscale-bearing
     programs take ``(queries, vectors, qscale, ids)`` (two-stage programs
     always do — ``qscale`` rides as ``None`` for float corpora)."""

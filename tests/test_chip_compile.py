@@ -36,7 +36,7 @@ from tdfo_tpu.ops.pallas_kernels import (
     line_layout,
 )
 
-V, U = 500_000, 8192  # table rows, ids per step (bench.py SIZE_MAP / B)
+V, U = 500_000, 8192  # table rows, ids per step (chip_smoke's user table, BATCH)
 
 
 @pytest.fixture(scope="module")

@@ -25,12 +25,17 @@ TINY_VOCABS = tuple([4000, 2500] + [3 + (i * 37) % 90 for i in range(24)])
 BATCH, STEPS = 32, 6
 
 
-def test_constants_are_the_bench_profiles():
-    import bench
+def test_constants_are_the_published_profiles():
+    from test_planner import CRITEO_VOCABS  # pytest puts tests/ on sys.path
 
-    assert chip_smoke.CRITEO_KAGGLE_VOCABS == bench.CRITEO_KAGGLE_VOCABS
-    assert sum(chip_smoke.CRITEO_KAGGLE_VOCABS) == 33_762_577
-    assert chip_smoke.TWOTOWER_SIZE_MAP == bench.SIZE_MAP
+    vocabs = chip_smoke.CRITEO_KAGGLE_VOCABS
+    assert len(vocabs) == 26 and sum(vocabs) == 33_762_577
+    assert max(vocabs) == 10_131_227
+    assert vocabs == CRITEO_VOCABS  # the planner's calibration profile
+    assert chip_smoke.TWOTOWER_SIZE_MAP == {
+        "user": 500_000, "item": 200_000, "language": 32, "is_ebook": 2,
+        "format": 16, "publisher": 5_000, "pub_decade": 16,
+    }
 
 
 def test_data_generator_writes_the_preprocess_criteo_format(tmp_path):

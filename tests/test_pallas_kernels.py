@@ -1,7 +1,8 @@
 """Pallas kernels in interpreter mode vs XLA references (CPU-exact).
 
-The compiled path runs on the real chip via bench_kernels.py; here the same
-kernel code executes interpreted so the math is verified everywhere.
+The compiled path is checked for the chip by tests/test_chip_compile.py and
+run there by chip_smoke.py's kernels phase; here the same kernel code
+executes interpreted so the math is verified everywhere.
 """
 
 import jax

@@ -1,11 +1,11 @@
 """Cost-model-driven auto-sharding planner tests (``tdfo_tpu/plan``).
 
 The calibration contract is the load-bearing piece: ``estimate_step_ms``
-must reproduce BOTH docs/BUDGET.md in-situ step budgets — DLRM-Criteo
-plain 22.4 ms vs fused 29-32 ms, TwoTower fused 1.40 ms vs plain ~2.8 ms
-— with the correct plain-vs-fused ORDERING on each profile, because that
-ordering is exactly the decision the planner exists to make.  On top of
-that: the stats artifact round trip (preprocessing -> table_stats.json ->
+must reproduce BOTH docs/BUDGET.md in-situ step budgets (builders' round-4
+readings, not ledger numbers) — DLRM-Criteo plain 22.4 ms vs fused 29-32 ms,
+TwoTower fused 1.40 ms vs plain ~2.8 ms — with the correct plain-vs-fused
+ORDERING on each profile, because that ordering is exactly the decision the
+planner exists to make.  On top of that: the stats artifact round trip (preprocessing -> table_stats.json ->
 planner), plan determinism/byte-identity, the HBM budget repair, the
 telemetry-refinement round trip, and the trainer-level wiring (plan ->
 actual spec/array placement, trajectory equivalence with hand-set knobs,
@@ -54,7 +54,8 @@ from tdfo_tpu.plan.stats import (
 
 # ---------------------------------------------------- calibration profiles
 #
-# Pinned to the docs/BUDGET.md chip facts (bench.py CRITEO_KAGGLE_VOCABS +
+# Pinned to the docs/BUDGET.md chip facts (the Criteo-Kaggle vocabularies,
+# equal to chip_smoke.CRITEO_KAGGLE_VOCABS by tests/test_chip_smoke.py, +
 # the measured per-step touch counts): 26 tables, 213k ids/step deduping to
 # ~102k touched rows / ~77k touched fat lines at B=8192.  Uniques are the
 # per-table occupancy expectations rescaled to land the MEASURED totals —
@@ -69,7 +70,7 @@ CRITEO_VOCABS = (
 CRITEO_TOUCHED_ROWS = 102_000
 CRITEO_TOUCHED_LINES = 77_000
 
-# TwoTower bench profile (docs/BUDGET.md TwoTower table): ~8k touched rows
+# TwoTower profile (docs/BUDGET.md "TwoTower DMP" table): ~8k touched rows
 # across the 7 tables at B=8192 under the power-law goodreads traffic.
 TWOTOWER_PROFILE = {
     "user": (1_600_000, 4000.0),
@@ -294,9 +295,9 @@ def _criteo_plan(criteo_stats, **kw):
 
 
 def test_planner_keeps_criteo_big_tables_plain(criteo_stats):
-    """The BUDGET.md headline decision: at the Criteo profile every
-    fused-eligible table stays on the plain-scatter path, and the plan
-    beats the all-defaults (fused) baseline it reports."""
+    """The docs/BUDGET.md "Fused fat-line findings" decision: at the Criteo
+    profile every fused-eligible table stays on the plain-scatter path, and
+    the plan beats the all-defaults (fused) baseline it reports."""
     plan = _criteo_plan(criteo_stats)
     big = {n: e for n, e in plan["tables"].items()
            if e["vocab"] > FUSED_MIN_VOCAB}
@@ -385,8 +386,8 @@ def test_planner_demotes_to_int8_under_tight_budget(criteo_stats):
 @pytest.fixture(scope="module")
 def criteo_zipf_stats():
     """Zipf(1.2) traffic over the Criteo vocabs: heavy reuse inside a
-    flush interval, the regime the update cache was measured in
-    (docs/BUDGET.md cache_zipf brackets)."""
+    flush interval, the regime the update cache is meant for (builders'
+    expectation, round 4; never measured on the chip)."""
     stats = {}
     for i, v in enumerate(CRITEO_VOCABS):
         p = np.arange(1, v + 1, dtype=np.float64) ** -1.2

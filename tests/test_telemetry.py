@@ -317,7 +317,11 @@ def test_watchdog_thread_lifecycle(tmp_path):
     assert wd._thread is not None and wd._thread.daemon
     import time as _time
 
-    _time.sleep(0.3)  # several poll intervals with no beat -> stall fires
+    # no beat -> the daemon's poll fires a stall; under loaded xdist workers
+    # that takes longer than a few poll intervals, so wait for it
+    deadline = _time.monotonic() + 10.0
+    while not wd.stall_events and _time.monotonic() < deadline:
+        _time.sleep(0.02)
     wd.stop()
     assert wd._thread is None
     assert wd.stall_events  # the daemon itself detected the silence
