@@ -162,29 +162,53 @@ def rms_norm(x, w, eps: float, *, axis_name: str | None = None):
 
 
 @jax.custom_vjp
-def _grad_apart(w):
-    """Identity on a weight as its product reads it (cast to the compute
-    dtype).  Its cotangent, the weight-gradient product ``x^T dy`` in that
-    dtype, passes through an ``optimization_barrier`` of its own, so it is
-    materialised (85 MB in bfloat16 for a SwiGLU leaf) before the cast to
-    float32 and the optimizer see it.  Without it XLA makes ONE fusion a
-    leaf of the operands' producers (the norm of ``x``, the activation's
-    backward), the product, the cast and AdamW's sweep of parameter and
-    both moments, tiled for the three float32 outputs: 8.0-11.4 ms a
-    [3840, 11008] leaf on the v5e, a third of the MXU peak; apart the
-    product takes under 4.8 ms and the sweep 1.65, and the step 395 for 442
-    ms (PERF.md, PR 33).  One barrier a leaf: one over the whole gradient
-    tree would keep every leaf's gradient alive at once.  Nothing is saved
-    for the backward pass and the same values come out."""
-    return w
+def _cotangent_once(y):
+    """Identity on a product's OUTPUT whose cotangent ``dy`` passes an
+    ``optimization_barrier`` of its own, so ``dy`` is ONE array, in the
+    dtype the products read it in (bfloat16 in the cell, float32 for the
+    head's logits), before the two products that need it run: ``dx = dy
+    w^T`` and ``dw = x^T dy``.  Without it XLA fuses the expression that
+    makes ``dy`` (SwiGLU's backward from three [8192, 11008] arrays, a
+    norm's backward, the softmax's) INTO both and evaluates it again for
+    every output tile: ``dx`` through ``gate`` 7.4 ms for 3.5 at the MXU
+    peak, the head's ``dx`` 9.6 for 4.0 (v5e; PERF.md, PR 35).  One barrier
+    a site, on the sites where the step measured faster for it: the head,
+    ``gate`` / ``up`` / ``down``, the delta-rule layers' ``wv`` / ``wg``.
+    With ``dy`` and the left operands arrays (:func:`_made_once`) the
+    weight-gradient product runs FUSED with AdamW's sweep of the leaf and
+    its moments, 3.95-6.2 ms a [3840, 11008] leaf; PR 33's barrier between
+    the two (``_grad_apart``: product 3.9 + sweep 1.65) measured 8 ms a
+    step slower from there and is gone.  Nothing is saved for the backward
+    pass and the same values come out."""
+    return y
 
 
-_grad_apart.defvjp(lambda w: (w, None),
-                   lambda _, g: (jax.lax.optimization_barrier(g),))
+_cotangent_once.defvjp(lambda y: (y, None),
+                       lambda _, g: (jax.lax.optimization_barrier(g),))
+
+
+@jax.custom_vjp
+def _made_once(x):
+    """A product's left operand that is an expression (``x + rms_norm(mixed)``
+    into ``gate`` and ``up``; the normed and gated delta-rule output into
+    ``wo``), made as ONE array on the forward side, so the weight-gradient
+    product of the rematerialised layer reads an array and not the
+    expression again a tile: 19 and 2 ms of the step (PERF.md, PR 35;
+    ``silu(gate) * up`` into ``down`` measured 6 ms SLOWER made once, and
+    the final norm 1: they are left expressions).  The mirror of
+    :func:`_cotangent_once`: the barrier is on the value, the cotangent
+    passes as it is (a sum of products' outputs: barred, its bfloat16
+    rounding differs on the CPU and the step measured slower).  Inside a
+    rematerialised layer the array lives in the backward pass only."""
+    return jax.lax.optimization_barrier(x)
+
+
+_made_once.defvjp(lambda x: (jax.lax.optimization_barrier(x), None),
+                  lambda _, g: (g,))
 
 
 def _proj(x, w):
-    return jnp.dot(x, _grad_apart(w.astype(x.dtype)))
+    return jnp.dot(x, w.astype(x.dtype))
 
 
 def causal_document_attention(q, k, v, segment, *,
@@ -283,8 +307,9 @@ def gated_delta_mixer(p, x, segment, cfg: OlmoHybridConfig):
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
     f32 = jnp.float32
     with jax.named_scope("deltanet_proj"):
-        q, k, v = _proj(x, p["wq"]), _proj(x, p["wk"]), _proj(x, p["wv"])
-        gate = _proj(x, p["wg"])
+        q, k = _proj(x, p["wq"]), _proj(x, p["wk"])
+        v = _cotangent_once(_proj(x, p["wv"]))
+        gate = _cotangent_once(_proj(x, p["wg"]))
         a = jnp.dot(x, p["wa"].astype(x.dtype), preferred_element_type=f32)
         bb = jnp.dot(x, p["wb"].astype(x.dtype), preferred_element_type=f32)
         beta = jax.nn.sigmoid(bb) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
@@ -304,7 +329,7 @@ def gated_delta_mixer(p, x, segment, cfg: OlmoHybridConfig):
     with jax.named_scope("deltanet_proj"):
         y = rms_norm(o, p["o_norm"], cfg.rms_norm_eps)
         y = y * jax.nn.silu(gate.reshape(b, t, -1, dv))
-        return _proj(y.reshape(b, t, -1), p["wo"])
+        return _proj(_made_once(y.reshape(b, t, -1)), p["wo"])
 
 
 def _block(p, x, segment, kind: str, cfg: OlmoHybridConfig, axis_name):
@@ -314,10 +339,12 @@ def _block(p, x, segment, kind: str, cfg: OlmoHybridConfig, axis_name):
                                      axis_name=axis_name)
     else:
         mixed = gated_delta_mixer(p["mixer"], x, segment, cfg)
-    h = x + rms_norm(mixed, p["mixer_norm"], eps)
+    h = _made_once(x + rms_norm(mixed, p["mixer_norm"], eps))
     with jax.named_scope("mlp"):
         m = p["mlp"]
-        y = _proj(jax.nn.silu(_proj(h, m["gate"])) * _proj(h, m["up"]), m["down"])
+        gate = _cotangent_once(_proj(h, m["gate"]))
+        up = _cotangent_once(_proj(h, m["up"]))
+        y = _cotangent_once(_proj(jax.nn.silu(gate) * up, m["down"]))
         return h + rms_norm(y, p["mlp_norm"], eps)
 
 
@@ -346,8 +373,8 @@ def next_token_loss(params, x, token, segment, cfg: OlmoHybridConfig):
     @jax.checkpoint
     def loss(final_norm, head, x):
         h = rms_norm(x, final_norm, cfg.rms_norm_eps)
-        logits = jnp.dot(h, _grad_apart(head.astype(h.dtype)),
-                         preferred_element_type=jnp.float32)
+        logits = _cotangent_once(jnp.dot(h, head.astype(h.dtype),
+                                         preferred_element_type=jnp.float32))
         logp = jax.nn.log_softmax(logits, axis=-1)
         nxt = jnp.concatenate([token[:, 1:], token[:, :1]], axis=1)
         labelled = jnp.concatenate(

@@ -161,13 +161,17 @@ def _slice_heads(p, kind, cfg, lo, hi):
 
 
 @pytest.mark.parametrize("kind", M.LAYER_KINDS)
-def test_two_head_shares_add_up_to_the_uncut_layer(kind):
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "gradient"])
+def test_two_head_shares_add_up_to_the_uncut_layer(kind, grad):
     """Each of two chips holds half the heads; their ``W_o`` outputs, before
     the block's norm, add up to what the uncut reference gives for the whole
     layer.  The delta-rule mixer is additive as it stands; the full layer's
     q/k RMSNorm needs the one scalar a token the two chips exchange (the
     mean square summed over the ``model`` axis), so it runs as a two-device
-    ``shard_map``."""
+    ``shard_map``.  ``gradient``: so do the gradients with respect to the
+    input and to each share's weights, which are the uncut layer's gradient
+    cut the same way: the products' barriers are traced and transposed
+    under the mesh axis."""
     whole = model_cfg(layer_types=(kind,))
     share = model_cfg(layer_types=(kind,), full_heads_held=2,
                       linear_heads_held=2)
@@ -175,13 +179,19 @@ def test_two_head_shares_add_up_to_the_uncut_layer(kind):
     rng = np.random.default_rng(1)
     x = rng.normal(size=(2, 60, 32)).astype(np.float32)
     _, segment = packed(rng, 2, 60, p_start=0.08)
+    w = rng.normal(size=(2, 60, 32)).astype(np.float32)
     m = reference_model(whole)
-    want = jax.jit(jax.vmap(
-        lambda xs, ss: R.MIXERS[kind](p, xs, ss, m, None)))(x, segment)
-    halves = [_slice_heads(p, kind, whole, 0, 2), _slice_heads(p, kind, whole, 2, 4)]
+    # forward: the output; gradient: d sum(output * w) / d (weights, input)
+    read = (lambda f: jax.grad(lambda hs, x: (f(hs, x) * w).sum(), (0, 1))
+            ) if grad else (lambda f: f)
+    want = jax.jit(read(lambda p, x: jax.vmap(
+        lambda xs, ss: R.MIXERS[kind](p, xs, ss, m, None))(x, segment)))(p, x)
+    cut = lambda p: [_slice_heads(p, kind, whole, 0, 2),
+                     _slice_heads(p, kind, whole, 2, 4)]
+    halves = cut(p)
     if kind == "linear_attention":
-        got = jax.jit(lambda hs: sum(
-            M.gated_delta_mixer(h, x, segment, share) for h in hs))(halves)
+        got = jax.jit(read(lambda hs, x: sum(
+            M.gated_delta_mixer(h, x, segment, share) for h in hs)))(halves, x)
     else:
         mesh = Mesh(np.array(jax.devices()[:2]), ("model",))
         stacked = jax.tree.map(lambda a, b: jnp.stack([a, b]), *halves)
@@ -191,13 +201,31 @@ def test_two_head_shares_add_up_to_the_uncut_layer(kind):
             out = M.full_attention_mixer(h, x, segment, share, axis_name="model")
             return jax.lax.psum(out, "model")
 
-        got = jax.shard_map(on_chip, mesh=mesh, in_specs=(P("model"), P(), P()),
-                            out_specs=P())(stacked, x, jnp.asarray(segment))
+        mapped = jax.shard_map(on_chip, mesh=mesh, in_specs=(P("model"), P(), P()),
+                               out_specs=P())
+        got = jax.jit(read(lambda hs, x: mapped(hs, x, jnp.asarray(segment))))(
+            stacked, x)
+        if grad:
+            got = ([jax.tree.map(lambda a: a[i], got[0]) for i in (0, 1)], got[1])
         # one chip alone norms over its own columns: a different layer
-        alone = jax.jit(lambda hs: sum(
-            M.full_attention_mixer(h, x, segment, share) for h in hs))(halves)
-        assert float(jnp.abs(alone - want).max()) > 1e-3
-    np.testing.assert_allclose(got, want, atol=2e-5)
+        alone = jax.jit(read(lambda hs, x: sum(
+            M.full_attention_mixer(h, x, segment, share) for h in hs)))(halves, x)
+        far = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()),
+                           alone, (cut(want[0]), want[1]) if grad else want)
+        assert max(jax.tree.leaves(far)) > 1e-3
+    if not grad:
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        return
+    g_halves, g_x = got
+    np.testing.assert_allclose(g_x, want[1], atol=1e-4)
+    shared = ("o_norm",)    # one leaf both shares read: their gradients add
+    for name in halves[0]:
+        if name in shared:
+            np.testing.assert_allclose(g_halves[0][name] + g_halves[1][name],
+                                       want[0][name], atol=1e-4, err_msg=name)
+            continue
+        for a, b in zip(g_halves, cut(want[0])):
+            np.testing.assert_allclose(a[name], b[name], atol=1e-4, err_msg=name)
 
 
 def trainer_config(data_dir, **over):
@@ -294,25 +322,45 @@ def test_packed_documents_fill_the_sequence():
         assert (sizes[:-1] >= 16).all() and sizes.max() <= 256
 
 
-# ---- the weight gradients are held apart from the optimizer's sweep (PR 33)
+# ---- every large product reads operands that were made once (PR 35; PR 33
+# ---- held the weight gradients apart from the optimizer's sweep)
 
 
 def plain_products(monkeypatch):
-    """The formulation before PR 33, kept here: ``x @ w`` with the weight
-    cast where it is used and nothing between its gradient and the
-    optimizer."""
-    monkeypatch.setattr(M, "_proj", lambda x, w: jnp.dot(x, w.astype(x.dtype)))
-    monkeypatch.setattr(M, "_grad_apart", lambda w: w)
+    """The formulation before PRs 33 and 35, kept here: ``x @ w`` with the
+    weight cast where it is used, nothing between an expression and the
+    product that reads it, nor between a gradient and the optimizer."""
+    monkeypatch.setattr(M, "_cotangent_once", lambda y: y)
+    monkeypatch.setattr(M, "_made_once", lambda x: x)
 
 
-def product_leaves(params) -> list[tuple]:
-    """Shapes of the leaves whose products go through ``_proj`` or the
-    head's ``jnp.dot``: every matrix but the delta-rule layers' tiny ``wa``
-    / ``wb`` and the convolutions."""
-    return sorted(
-        a.shape for path, a in jax.tree.leaves_with_path(params)
-        if a.ndim == 2 and path[-1].key not in ("wa", "wb")
-        and not path[-1].key.startswith("conv_"))
+def cotangents_once(cfg: M.OlmoHybridConfig, b: int, t: int) -> list[tuple]:
+    """Shapes of the product outputs whose cotangent is made once, one a
+    site: the head's logits; in each layer ``gate``, ``up`` and ``down``; in
+    a delta-rule layer ``wv`` and ``wg``.  The other products (``wq`` /
+    ``wk`` / ``wo`` there, the full-attention layer's four, the tiny ``wa``
+    / ``wb``) measured no faster for it in the step and have none."""
+    d, f = cfg.hidden_size, cfg.intermediate_size
+    vw = cfg.linear_heads * cfg.linear_value_head_dim
+    out = [(b, t, cfg.vocab_size)]
+    for kind in cfg.layer_types:
+        out += [(b, t, f), (b, t, f), (b, t, d)]
+        if kind == "linear_attention":
+            out += [(b, t, vw), (b, t, vw)]
+    return sorted(out)
+
+
+def operands_once(cfg: M.OlmoHybridConfig, b: int, t: int) -> list[tuple]:
+    """Shapes of the left operands that are expressions and are made once,
+    one a site: in each layer ``h`` (into ``gate`` and ``up``); in a
+    delta-rule layer the gated and normed output (into ``wo``)."""
+    vw = cfg.linear_heads * cfg.linear_value_head_dim
+    out = []
+    for kind in cfg.layer_types:
+        out += [(b, t, cfg.hidden_size)]
+        if kind == "linear_attention":
+            out += [(b, t, vw)]
+    return sorted(out)
 
 
 def barriers(jaxpr) -> list[list[tuple]]:
@@ -328,6 +376,25 @@ def barriers(jaxpr) -> list[list[tuple]]:
                 if hasattr(sub, "eqns"):
                     found += barriers(sub)
     return found
+
+
+@pytest.mark.parametrize("name,side", [("_cotangent_once", "backward"),
+                                       ("_made_once", "forward")])
+def test_an_identity_holds_its_barrier_on_one_side(name, side):
+    """``_cotangent_once`` is the mirror of ``_made_once``: the one bars the
+    cotangent and leaves the value alone, the other bars the value and
+    leaves the cotangent alone; both are identities with the identity's
+    gradient and keep nothing for the backward pass."""
+    fn = getattr(M, name)
+    x = jnp.arange(6.0).reshape(2, 3)
+    value, back = jax.vjp(fn, x)
+    np.testing.assert_array_equal(value, x)
+    np.testing.assert_array_equal(back(2 * x)[0], 2 * x)
+    forward = barriers(jax.make_jaxpr(fn)(x).jaxpr)
+    backward = barriers(jax.make_jaxpr(back)(x).jaxpr)
+    assert forward == ([[(2, 3)]] if side == "forward" else [])
+    assert backward == ([[(2, 3)]] if side == "backward" else [])
+    assert not jax.tree.leaves(back)      # nothing saved for the backward
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -377,11 +444,17 @@ def test_three_trainer_steps_are_those_of_the_plain_products(
         np.testing.assert_array_equal(a, b, err_msg=str(path))
 
 
-def test_the_step_holds_every_product_leafs_gradient_apart(tmp_path):
-    """One barrier a ``_proj`` leaf (and the head), each over that leaf's
-    gradient alone and none over the whole tree: a later edit cannot put
-    the product + AdamW fusion back in silence, nor hold every gradient
-    alive at once."""
+def test_the_step_makes_every_large_products_operands_once(tmp_path):
+    """The step's barriers, each over ONE array and none over a tree: one a
+    site over the cotangent of a product's output (the ``dy`` that ``dx``
+    and ``dw`` both read: ``cotangents_once``), and one a site over a left
+    operand that is an expression, there twice (the forward pass and the
+    rematerialised one, whose array the weight-gradient product reads:
+    ``operands_once``).  A later edit cannot put an expression back inside a
+    product in silence, nor hold every cotangent alive at once.  PR 33's
+    barrier over each leaf's weight gradient is gone: with the operands
+    made once the step measured faster with the product and AdamW's sweep
+    as one fusion (``models/olmo_hybrid._cotangent_once``)."""
     from tdfo_tpu.train.trainer import Trainer
 
     trainer = Trainer(trainer_config(tmp_path, lm=LM),
@@ -392,9 +465,13 @@ def test_the_step_holds_every_product_leafs_gradient_apart(tmp_path):
     trainer.logger.close()
     held = barriers(jaxpr.jaxpr)
     assert all(len(shapes) == 1 for shapes in held), held
-    want = product_leaves(trainer.state.dense_params)
-    assert len(want) == 15 + 4 + 12 + 1
-    assert sorted(shapes[0] for shapes in held) == want
+    cfg = trainer.model_cfg
+    cotangents, operands = cotangents_once(cfg, 2, 48), operands_once(cfg, 2, 48)
+    assert len(cotangents) == 1 + 12 + 6 and len(operands) == 4 + 3
+    assert sorted(shapes[0] for shapes in held) == sorted(cotangents + 2 * operands)
+    # no barrier has a weight's shape: no leaf's gradient is held apart
+    leaves = {a.shape for a in jax.tree.leaves(trainer.state.dense_params)}
+    assert not leaves & {shapes[0] for shapes in held}
 
 
 @pytest.mark.parametrize("kind", M.LAYER_KINDS)
