@@ -1,0 +1,9 @@
+"""Device milliseconds a step under the program's ``ssd_scan`` scope (the
+chunked Mamba-2 scan of every ``M`` layer: forward, the layer's
+rematerialised forward and backward), by exclusive time of the operations
+whose ``tf_op`` names the scope (``lib/scopes.py``).  Layer: kernels.
+Nothing where the trace or the program has no such scope."""
+
+
+def read(ctx):
+    return (ctx.get("scope_ms") or {}).get("ssd_scan")

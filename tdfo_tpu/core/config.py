@@ -372,29 +372,74 @@ class TrainSpec:
 
 @dataclass(frozen=True)
 class LmSpec:
-    """``[lm]`` config table: the ``olmo_hybrid`` decoder (``model =
-    "olmo_hybrid"``, ``tdfo_tpu/models/olmo_hybrid.py``).  Key names are the
-    published ``config.json``'s (huggingface ``model_type: olmo_hybrid``)
-    where it has one; a head count there is the PUBLISHED count (it sets the
-    head size), and the ``*_held`` keys say how many of them this process
-    group's layers hold (the chip's share of a deployment that divides each
-    layer by heads; 0 = all of them)."""
+    """``[lm]`` config table: the architecture and the share of a causal
+    decoder (``model`` names the family: ``"olmo_hybrid"``,
+    ``tdfo_tpu/models/olmo_hybrid.py``; ``"nemotron_h"``,
+    ``tdfo_tpu/models/nemotron_h.py``).  Key names are the published
+    ``config.json``'s (huggingface ``model_type`` of the same name) where it
+    has one; a head, group or expert count there is the PUBLISHED count (it
+    sets the head and group sizes and the router's width), and the
+    ``*_held`` keys say how many of them this process group's layers hold
+    (the chip's share of a deployment that divides each layer by heads and
+    experts; 0 = all of them).  A family reads the keys its module's
+    ``LmConfig`` has as fields; a key of another family must stay at its
+    default (``Trainer`` refuses it at build)."""
 
     vocab_size: int = 0            # rows of the token table and of the head
     hidden_size: int = 0
+    # published query heads of the attention layers (olmo_hybrid: of both
+    # kinds of layer, and head size = hidden / this)
+    num_attention_heads: int = 0
+    rms_norm_eps: float = 1e-6     # nemotron_h: the config's layer_norm_epsilon
+    # ---- olmo_hybrid
     intermediate_size: int = 0     # SwiGLU width
     # one entry a layer: "linear_attention" (Gated DeltaNet) | "full_attention"
     layer_types: tuple[str, ...] = ()
-    # published, for both kinds of layer; head size = hidden / this
-    num_attention_heads: int = 0
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
     # beta = 2 * sigmoid(.) (the state's eigenvalues may go negative)
     linear_allow_neg_eigval: bool = True
-    rms_norm_eps: float = 1e-6
     full_heads_held: int = 0
     linear_heads_held: int = 0
+    # ---- nemotron_h
+    # one letter a layer: "M" Mamba-2 | "*" attention | "E" experts
+    hybrid_override_pattern: str = ""
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    n_groups: int = 0              # groups of Mamba heads sharing B and C
+    conv_kernel: int = 4
+    num_key_value_heads: int = 0
+    head_dim: int = 0
+    n_routed_experts: int = 0      # the router's width
+    num_experts_per_tok: int = 0
+    moe_latent_size: int = 0
+    moe_intermediate_size: int = 0
+    moe_shared_expert_intermediate_size: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    mamba_heads_held: int = 0      # whole groups of them
+    attention_heads_held: int = 0  # with the key/value heads they read
+    experts_held: int = 0
+    first_expert_held: int = 0     # index of the first held expert
+
+
+# the causal decoder families (``tdfo_tpu/models/<name>.py``) and the [lm]
+# keys each needs > 0.  That is all this module, which does not import jax,
+# says of a family: which keys it reads and what they must satisfy together
+# is its module's ``LmConfig``, which ``Trainer`` constructs at build
+LM_FAMILIES: dict[str, tuple[str, ...]] = {
+    "olmo_hybrid": (
+        "vocab_size", "hidden_size", "num_attention_heads",
+        "intermediate_size", "linear_key_head_dim", "linear_value_head_dim"),
+    "nemotron_h": (
+        "vocab_size", "hidden_size", "num_attention_heads", "mamba_num_heads",
+        "mamba_head_dim", "ssm_state_size", "n_groups", "num_key_value_heads",
+        "head_dim", "n_routed_experts", "num_experts_per_tok",
+        "moe_latent_size", "moe_intermediate_size",
+        "moe_shared_expert_intermediate_size"),
+}
 
 
 @dataclass(frozen=True)
@@ -584,7 +629,9 @@ class Config:
     seed: int = 42
 
     # --- model (L2) ---
-    model: str = "twotower"  # "twotower" | "bert4rec" | "dlrm" | "olmo_hybrid"
+    # "twotower" | "bert4rec" | "dlrm" | a causal decoder: "olmo_hybrid" |
+    # "nemotron_h"
+    model: str = "twotower"
     embed_dim: int = 16
     # custom CTR feature schema (dlrm only): categorical column names (one
     # embedding table each, vocab sizes from size_map) and continuous column
@@ -676,7 +723,7 @@ class Config:
     embeddings: EmbeddingsSpec = field(default_factory=EmbeddingsSpec)
     # [train] table: train-loop pipelining knobs
     train: TrainSpec = field(default_factory=TrainSpec)
-    # [lm] table: the olmo_hybrid decoder's architecture and share
+    # [lm] table: a causal decoder's architecture and share
     lm: LmSpec = field(default_factory=LmSpec)
     # [serving] table: online-inference knobs (launch serve / tdfo_tpu.serve)
     serving: ServingSpec = field(default_factory=ServingSpec)
@@ -752,39 +799,30 @@ class Config:
     # --- preprocessing handshake ---
     size_map: Mapping[str, int] = field(default_factory=dict)
 
+    @property
+    def is_causal_lm(self) -> bool:
+        """``model`` names a causal decoder family: the ``[lm]`` table, packed
+        token sequences, a donated state, next-token loss."""
+        return self.model in LM_FAMILIES
+
     def _check_lm(self) -> None:
-        lm = self.lm
-        for key in ("vocab_size", "hidden_size", "intermediate_size",
-                    "num_attention_heads", "linear_key_head_dim",
-                    "linear_value_head_dim"):
-            if getattr(lm, key) <= 0:
-                raise ValueError(f"model = \"olmo_hybrid\" needs [lm] {key} > 0")
-        if not lm.layer_types or set(lm.layer_types) - {
-                "linear_attention", "full_attention"}:
-            raise ValueError(
-                "[lm] layer_types: one of \"linear_attention\" | "
-                f"\"full_attention\" a layer, got {lm.layer_types!r}")
-        if lm.hidden_size % lm.num_attention_heads:
-            raise ValueError("[lm] hidden_size must divide by num_attention_heads")
-        if not 0 <= lm.full_heads_held <= lm.num_attention_heads:
-            raise ValueError("[lm] full_heads_held must be in [0, num_attention_heads]")
-        if not 0 <= lm.linear_heads_held <= lm.num_attention_heads:
-            raise ValueError("[lm] linear_heads_held must be in [0, num_attention_heads]")
-        if lm.linear_conv_kernel_dim < 1:
-            raise ValueError("[lm] linear_conv_kernel_dim must be >= 1")
+        model = self.model
+        for key in LM_FAMILIES[model]:
+            if getattr(self.lm, key) <= 0:
+                raise ValueError(f"model = \"{model}\" needs [lm] {key} > 0")
         if self.nonfinite_tolerance != 0:
             # the step donates its state (a dense state of many GiB cannot
             # live twice), so there is nothing for the guard to roll back to
             raise ValueError(
-                "model = \"olmo_hybrid\" donates its train state: set "
+                f"model = \"{model}\" donates its train state: set "
                 "nonfinite_tolerance = 0 (the non-finite guard keeps a second "
                 "copy of the state, which this model's state has no room for)")
         if self.steps_per_execution != 1 or self.train.pipeline_overlap:
-            raise ValueError("model = \"olmo_hybrid\" runs single-step "
+            raise ValueError(f"model = \"{model}\" runs single-step "
                              "dispatches: steps_per_execution = 1, no "
                              "train.pipeline_overlap")
         if self.write_format != "parquet":
-            raise ValueError("model = \"olmo_hybrid\" reads parquet only "
+            raise ValueError(f"model = \"{model}\" reads parquet only "
                              "(sequence columns are list-valued)")
 
     def __post_init__(self) -> None:
@@ -794,13 +832,13 @@ class Config:
             )
         if self.write_format not in ("parquet", "tfrecord"):
             raise ValueError(f"unsupported write_format: {self.write_format!r}")
-        if self.model not in ("twotower", "dlrm", "bert4rec", "olmo_hybrid"):
+        if self.model not in ("twotower", "dlrm", "bert4rec", *LM_FAMILIES):
             raise ValueError(f"unknown model: {self.model!r}")
-        if self.model == "olmo_hybrid":
+        if self.is_causal_lm:
             self._check_lm()
         elif self.lm != LmSpec():
-            raise ValueError("the [lm] table configures model = "
-                             "\"olmo_hybrid\" only")
+            raise ValueError("the [lm] table configures a causal decoder "
+                             f"(model = one of {sorted(LM_FAMILIES)}) only")
         if ((self.categorical_features or self.continuous_features)
                 and self.model != "dlrm"):
             raise ValueError(

@@ -3,7 +3,7 @@ Gated DeltaNet (``linear_attention``) or causal full attention
 (``full_attention``), each followed by a SwiGLU MLP.
 
 Source: https://huggingface.co/allenai/Olmo-Hybrid-7B/blob/main/config.json
-(``model_type: olmo_hybrid``, whose key names :class:`OlmoHybridConfig`
+(``model_type: olmo_hybrid``, whose key names :class:`LmConfig`
 keeps); the linear-attention layer follows flash-linear-attention's
 ``GatedDeltaNet`` (Yang, Kautz, Hatamizadeh, arXiv:2412.06464).  The
 equations are written out in ``benchmarks/reference/olmo_hybrid.py``, the
@@ -38,9 +38,12 @@ import jax.numpy as jnp
 
 from tdfo_tpu.ops.gated_delta import chunk_gated_delta_rule
 
-__all__ = ["OlmoHybridConfig", "init_olmo_hybrid", "forward_loss", "backbone",
-           "next_token_loss", "full_attention_mixer", "gated_delta_mixer",
-           "causal_document_attention", "rms_norm", "LAYER_KINDS"]
+__all__ = ["LmConfig", "init_params", "forward_loss", "backbone",
+           "STEP_COUNTERS", "BUFFERS", "next_token_loss",
+           "full_attention_mixer", "gated_delta_mixer", "LAYER_KINDS",
+           # pieces another decoder family takes as they are
+           "causal_document_attention", "rms_norm", "causal_conv", "proj",
+           "made_once", "cotangent_once", "init_tree"]
 
 LAYER_KINDS = ("linear_attention", "full_attention")
 # attention: queries (and keys) a block; the tests pass smaller ones
@@ -48,7 +51,7 @@ QUERY_BLOCK = 1024
 
 
 @dataclass(frozen=True)
-class OlmoHybridConfig:
+class LmConfig:
     vocab_size: int
     hidden_size: int
     intermediate_size: int
@@ -70,6 +73,11 @@ class OlmoHybridConfig:
                              f"{sorted(bad) or 'none'}")
         if self.hidden_size % self.num_attention_heads:
             raise ValueError("hidden_size must divide by num_attention_heads")
+        for key in ("full_heads_held", "linear_heads_held"):
+            if not 0 <= getattr(self, key) <= self.num_attention_heads:
+                raise ValueError(f"{key} must be in [0, num_attention_heads]")
+        if self.linear_conv_kernel_dim < 1:
+            raise ValueError("linear_conv_kernel_dim must be >= 1")
 
     @property
     def head_dim(self) -> int:
@@ -84,10 +92,18 @@ class OlmoHybridConfig:
         return self.linear_heads_held or self.num_attention_heads
 
 
+# what ``Trainer``'s decoder builder reads of a family's module beside
+# ``LmConfig`` (built from the ``[lm]`` table's keys of its fields' names),
+# ``init_params`` and ``forward_loss``: the counters its step returns beside
+# the loss, and the leaves that are buffers (no decay)
+STEP_COUNTERS: tuple[str, ...] = ()
+BUFFERS: tuple[str, ...] = ()
+
+
 # ------------------------------------------------------------- parameters
 
 
-def _mixer_shapes(cfg: OlmoHybridConfig, kind: str) -> dict[str, tuple]:
+def _mixer_shapes(cfg: LmConfig, kind: str) -> dict[str, tuple]:
     d = cfg.hidden_size
     if kind == "full_attention":
         w = cfg.full_heads * cfg.head_dim
@@ -102,7 +118,7 @@ def _mixer_shapes(cfg: OlmoHybridConfig, kind: str) -> dict[str, tuple]:
             "o_norm": (cfg.linear_value_head_dim,)}
 
 
-def param_shapes(cfg: OlmoHybridConfig) -> dict:
+def param_shapes(cfg: LmConfig) -> dict:
     """The dense parameter tree's shapes (nested like the tree)."""
     d, f = cfg.hidden_size, cfg.intermediate_size
     tree: dict = {}
@@ -136,14 +152,19 @@ def init_leaf(rng: jax.Array, name: str, shape: tuple) -> jax.Array:
     return 0.02 * jax.random.normal(rng, shape, jnp.float32)
 
 
-def init_olmo_hybrid(rng: jax.Array, cfg: OlmoHybridConfig) -> dict:
-    shapes = param_shapes(cfg)
+def init_tree(rng: jax.Array, shapes: dict, leaf=init_leaf) -> dict:
+    """A tree of ``shapes`` (nested dicts of tuples) initialised leaf by
+    leaf, ``leaf(key, name, shape)`` by the leaf's own name."""
     leaves, treedef = jax.tree.flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
     keys = jax.random.split(rng, len(leaves))
     return jax.tree.unflatten(treedef, [
-        init_leaf(k, path[-1].key, shape)
+        leaf(k, path[-1].key, shape)
         for k, (path, shape) in zip(keys, leaves)])
+
+
+def init_params(rng: jax.Array, cfg: LmConfig) -> dict:
+    return init_tree(rng, param_shapes(cfg))
 
 
 # ------------------------------------------------------------------ pieces
@@ -162,7 +183,7 @@ def rms_norm(x, w, eps: float, *, axis_name: str | None = None):
 
 
 @jax.custom_vjp
-def _cotangent_once(y):
+def cotangent_once(y):
     """Identity on a product's OUTPUT whose cotangent ``dy`` passes an
     ``optimization_barrier`` of its own, so ``dy`` is ONE array, in the
     dtype the products read it in (bfloat16 in the cell, float32 for the
@@ -174,7 +195,7 @@ def _cotangent_once(y):
     peak, the head's ``dx`` 9.6 for 4.0 (v5e; PERF.md, PR 35).  One barrier
     a site, on the sites where the step measured faster for it: the head,
     ``gate`` / ``up`` / ``down``, the delta-rule layers' ``wv`` / ``wg``.
-    With ``dy`` and the left operands arrays (:func:`_made_once`) the
+    With ``dy`` and the left operands arrays (:func:`made_once`) the
     weight-gradient product runs FUSED with AdamW's sweep of the leaf and
     its moments, 3.95-6.2 ms a [3840, 11008] leaf; PR 33's barrier between
     the two (``_grad_apart``: product 3.9 + sweep 1.65) measured 8 ms a
@@ -183,12 +204,12 @@ def _cotangent_once(y):
     return y
 
 
-_cotangent_once.defvjp(lambda y: (y, None),
+cotangent_once.defvjp(lambda y: (y, None),
                        lambda _, g: (jax.lax.optimization_barrier(g),))
 
 
 @jax.custom_vjp
-def _made_once(x):
+def made_once(x):
     """A product's left operand that is an expression (``x + rms_norm(mixed)``
     into ``gate`` and ``up``; the normed and gated delta-rule output into
     ``wo``), made as ONE array on the forward side, so the weight-gradient
@@ -196,26 +217,27 @@ def _made_once(x):
     expression again a tile: 19 and 2 ms of the step (PERF.md, PR 35;
     ``silu(gate) * up`` into ``down`` measured 6 ms SLOWER made once, and
     the final norm 1: they are left expressions).  The mirror of
-    :func:`_cotangent_once`: the barrier is on the value, the cotangent
+    :func:`cotangent_once`: the barrier is on the value, the cotangent
     passes as it is (a sum of products' outputs: barred, its bfloat16
     rounding differs on the CPU and the step measured slower).  Inside a
     rematerialised layer the array lives in the backward pass only."""
     return jax.lax.optimization_barrier(x)
 
 
-_made_once.defvjp(lambda x: (jax.lax.optimization_barrier(x), None),
+made_once.defvjp(lambda x: (jax.lax.optimization_barrier(x), None),
                   lambda _, g: (g,))
 
 
-def _proj(x, w):
+def proj(x, w):
     return jnp.dot(x, w.astype(x.dtype))
 
 
 def causal_document_attention(q, k, v, segment, *,
                               query_block: int = QUERY_BLOCK):
     """``softmax(q k^T / sqrt(dh))`` over the keys of the same document at
-    positions ``<= t``.  ``q``, ``k``, ``v`` [B, T, H, dh]; ``segment``
-    [B, T].  Blockwise with an online softmax (the ``ring_block_k``
+    positions ``<= t``.  ``q`` [B, T, H, dh]; ``k``, ``v`` [B, T, H_kv, dh]
+    with ``H_kv`` dividing ``H`` (query head ``i`` reads key/value head
+    ``i // (H / H_kv)``); ``segment`` [B, T].  Blockwise with an online softmax (the ``ring_block_k``
     formulation of ``parallel/ring_attention.py``): a block of
     ``query_block`` queries at a time against the keys up to the block's end
     (the blocks above the diagonal are never formed), those keys a block at
@@ -225,6 +247,9 @@ def causal_document_attention(q, k, v, segment, *,
     row maximum into a lane-reduce over [H, 1024, 8192] float32 that runs
     far under the memory bandwidth (PERF.md, PR 31)."""
     b, t, h, dh = q.shape
+    if k.shape[2] != h:
+        # grouped queries: ``h / h_kv`` query heads read one key/value head
+        k, v = (jnp.repeat(a, h // k.shape[2], axis=2) for a in (k, v))
     scale = 1.0 / math.sqrt(dh)
     block = min(query_block, t)
     pos = jnp.arange(t)
@@ -274,21 +299,21 @@ def causal_document_attention(q, k, v, segment, *,
     return jnp.concatenate(out, axis=1) if len(out) > 1 else out[0]
 
 
-def full_attention_mixer(p, x, segment, cfg: OlmoHybridConfig, *,
+def full_attention_mixer(p, x, segment, cfg: LmConfig, *,
                          axis_name: str | None = None):
     """``x`` [B, T, d] -> ``W_o``'s output over the heads held here."""
     b, t, _ = x.shape
     with jax.named_scope("full_attn"):
         eps = cfg.rms_norm_eps
-        q = rms_norm(_proj(x, p["wq"]), p["q_norm"], eps, axis_name=axis_name)
-        k = rms_norm(_proj(x, p["wk"]), p["k_norm"], eps, axis_name=axis_name)
-        v = _proj(x, p["wv"])
+        q = rms_norm(proj(x, p["wq"]), p["q_norm"], eps, axis_name=axis_name)
+        k = rms_norm(proj(x, p["wk"]), p["k_norm"], eps, axis_name=axis_name)
+        v = proj(x, p["wv"])
         heads = lambda a: a.reshape(b, t, -1, cfg.head_dim)
         o = causal_document_attention(heads(q), heads(k), heads(v), segment)
-        return _proj(o.reshape(b, t, -1), p["wo"])
+        return proj(o.reshape(b, t, -1), p["wo"])
 
 
-def _causal_conv(x, w, segment):
+def causal_conv(x, w, segment):
     """Depthwise causal convolution over time; taps before the token's
     document start are zero.  ``x`` [B, T, C], ``w`` [K, C]."""
     t, kw = x.shape[1], w.shape[0]
@@ -301,21 +326,21 @@ def _causal_conv(x, w, segment):
     return y
 
 
-def gated_delta_mixer(p, x, segment, cfg: OlmoHybridConfig):
+def gated_delta_mixer(p, x, segment, cfg: LmConfig):
     """``x`` [B, T, d] -> ``W_o``'s output over the heads held here."""
     b, t, _ = x.shape
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
     f32 = jnp.float32
     with jax.named_scope("deltanet_proj"):
-        q, k = _proj(x, p["wq"]), _proj(x, p["wk"])
-        v = _cotangent_once(_proj(x, p["wv"]))
-        gate = _cotangent_once(_proj(x, p["wg"]))
+        q, k = proj(x, p["wq"]), proj(x, p["wk"])
+        v = cotangent_once(proj(x, p["wv"]))
+        gate = cotangent_once(proj(x, p["wg"]))
         a = jnp.dot(x, p["wa"].astype(x.dtype), preferred_element_type=f32)
         bb = jnp.dot(x, p["wb"].astype(x.dtype), preferred_element_type=f32)
         beta = jax.nn.sigmoid(bb) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
         g = -jnp.exp(p["A_log"]) * jax.nn.softplus(a + p["dt_bias"])
     with jax.named_scope("deltanet_conv"):
-        conv = lambda a, w: jax.nn.silu(_causal_conv(a, w, segment))
+        conv = lambda a, w: jax.nn.silu(causal_conv(a, w, segment))
         q = conv(q, p["conv_q"]).reshape(b, t, -1, dk).astype(f32)
         k = conv(k, p["conv_k"]).reshape(b, t, -1, dk).astype(f32)
         v = conv(v, p["conv_v"]).reshape(b, t, -1, dv)
@@ -329,26 +354,26 @@ def gated_delta_mixer(p, x, segment, cfg: OlmoHybridConfig):
     with jax.named_scope("deltanet_proj"):
         y = rms_norm(o, p["o_norm"], cfg.rms_norm_eps)
         y = y * jax.nn.silu(gate.reshape(b, t, -1, dv))
-        return _proj(_made_once(y.reshape(b, t, -1)), p["wo"])
+        return proj(made_once(y.reshape(b, t, -1)), p["wo"])
 
 
-def _block(p, x, segment, kind: str, cfg: OlmoHybridConfig, axis_name):
+def _block(p, x, segment, kind: str, cfg: LmConfig, axis_name):
     eps = cfg.rms_norm_eps
     if kind == "full_attention":
         mixed = full_attention_mixer(p["mixer"], x, segment, cfg,
                                      axis_name=axis_name)
     else:
         mixed = gated_delta_mixer(p["mixer"], x, segment, cfg)
-    h = _made_once(x + rms_norm(mixed, p["mixer_norm"], eps))
+    h = made_once(x + rms_norm(mixed, p["mixer_norm"], eps))
     with jax.named_scope("mlp"):
         m = p["mlp"]
-        gate = _cotangent_once(_proj(h, m["gate"]))
-        up = _cotangent_once(_proj(h, m["up"]))
-        y = _cotangent_once(_proj(jax.nn.silu(gate) * up, m["down"]))
+        gate = cotangent_once(proj(h, m["gate"]))
+        up = cotangent_once(proj(h, m["up"]))
+        y = cotangent_once(proj(jax.nn.silu(gate) * up, m["down"]))
         return h + rms_norm(y, p["mlp_norm"], eps)
 
 
-def backbone(params, x, segment, cfg: OlmoHybridConfig, *,
+def backbone(params, x, segment, cfg: LmConfig, *,
              axis_name: str | None = None):
     """``x`` [B, T, d] through every layer, each rematerialised in the
     backward pass.  A layer keeps its input and the outputs of its
@@ -365,7 +390,7 @@ def backbone(params, x, segment, cfg: OlmoHybridConfig, *,
     return x
 
 
-def next_token_loss(params, x, token, segment, cfg: OlmoHybridConfig):
+def next_token_loss(params, x, token, segment, cfg: LmConfig):
     """Mean cross-entropy of token ``t + 1`` at position ``t`` where both
     lie in one document; ``(loss, labelled positions)``.  The [T, V] logits
     are float32 and rematerialised in the backward pass."""
@@ -373,7 +398,7 @@ def next_token_loss(params, x, token, segment, cfg: OlmoHybridConfig):
     @jax.checkpoint
     def loss(final_norm, head, x):
         h = rms_norm(x, final_norm, cfg.rms_norm_eps)
-        logits = _cotangent_once(jnp.dot(h, head.astype(h.dtype),
+        logits = cotangent_once(jnp.dot(h, head.astype(h.dtype),
                                          preferred_element_type=jnp.float32))
         logp = jax.nn.log_softmax(logits, axis=-1)
         nxt = jnp.concatenate([token[:, 1:], token[:, :1]], axis=1)
@@ -388,7 +413,7 @@ def next_token_loss(params, x, token, segment, cfg: OlmoHybridConfig):
         return loss(params["final_norm"], params["head"], x)
 
 
-def forward_loss(params, embedded, token, segment, cfg: OlmoHybridConfig, *,
+def forward_loss(params, embedded, token, segment, cfg: LmConfig, *,
                  dtype=jnp.float32, axis_name: str | None = None):
     """The training forward: gathered vectors ``embedded`` [B, T, d] ->
     scalar next-token loss."""

@@ -423,7 +423,7 @@ def _count_lm_batches(stream, seq_len: int):
         segment = np.ascontiguousarray(b["segment"], np.int32)
         if token.shape[1:] != (seq_len,) or segment.shape != token.shape:
             raise ValueError(
-                f"olmo_hybrid: the data holds sequences of {token.shape[1:]} "
+                f"causal decoder: the data holds sequences of {token.shape[1:]} "
                 f"tokens (segment {segment.shape}), max_len says {seq_len}")
         same = segment[:, 1:] == segment[:, :-1]
         obs_trace.tally("lm_tokens", token.size)
@@ -471,6 +471,8 @@ class Trainer:
         self._logged_steps = 0  # run-global data-step counter (batches consumed)
         self._a2a_overflow = None  # alltoall dropped-id diagnostic (jitted)
         self._pipelined = False  # train.pipeline_overlap (prime/step/flush)
+        # names of the counters a causal decoder's step returns beside the loss
+        self._step_counters: tuple[str, ...] = ()
         self._cache_flush = None  # update-cache write-back program (jitted)
         self._flush_every = 0  # cache write-back cadence in train steps
         self._map_streams: dict = {}  # streaming=false table cache
@@ -526,8 +528,8 @@ class Trainer:
             self._build_ctr()
         elif cfg.model == "bert4rec":
             self._build_bert4rec()
-        elif cfg.model == "olmo_hybrid":
-            self._build_olmo_hybrid()
+        elif cfg.is_causal_lm:
+            self._build_causal_lm()
         else:
             raise ValueError(f"unknown model {cfg.model!r}")
         # model.tabulate-equivalent observability (jax-flax/models.py:154-155)
@@ -1058,33 +1060,48 @@ class Trainer:
 
         self.eval_accum = eval_accum
 
-    def _build_olmo_hybrid(self) -> None:
-        """The ``olmo_hybrid`` decoder in the DMP regime, wired as
+    def _build_causal_lm(self) -> None:
+        """A causal decoder (``Config.is_causal_lm``: ``models/olmo_hybrid``,
+        ``models/nemotron_h``) in the DMP regime, wired as
         ``_build_bert4rec`` wires its model-parallel one: the vocabulary
         (slice) as one table of the collection (under the fused threshold it
         is a plain table: gather lookup, row-sparse Adam on touched rows),
         backbone and head as dense leaves under AdamW, one
         ``make_sparse_train_step`` whose forward is the backbone plus
-        next-token cross-entropy.  The dense state is many GiB, so unlike
-        the other builders this one DONATES the step's state (config
-        requires ``nonfinite_tolerance = 0``: the guard's second copy has no
-        room), rematerialises by layer (``models/olmo_hybrid.backbone``)
-        and computes in bfloat16 on TPU under ``mixed_precision`` with
-        float32 parameters, optimizer state and softmax."""
+        next-token cross-entropy.  The family's module supplies the
+        configuration (``LmConfig``, from the ``[lm]`` keys of its fields'
+        names), the initialiser (``init_params``), ``forward_loss``, the
+        counters its step returns beside the loss (``STEP_COUNTERS``:
+        tallied into the epoch record where the loss is fetched) and the
+        leaves that are buffers (``BUFFERS``: no decay).  The dense state is
+        many GiB, so unlike the other builders this one DONATES the step's
+        state (config requires ``nonfinite_tolerance = 0``: the guard's
+        second copy has no room), rematerialises by layer (the family's
+        ``backbone``) and computes in bfloat16 on TPU under
+        ``mixed_precision`` with float32 parameters, optimizer state and
+        softmax."""
+        import importlib
+
         from tdfo_tpu.core.precision import compute_dtype
-        from tdfo_tpu.models.olmo_hybrid import (
-            OlmoHybridConfig, forward_loss, init_olmo_hybrid)
         from tdfo_tpu.ops.sparse import sparse_optimizer
         from tdfo_tpu.parallel.embedding import (
             EmbeddingSpec, ShardedEmbeddingCollection)
         from tdfo_tpu.train.sparse_step import (
             SparseTrainState, make_sparse_train_step)
 
+        family = importlib.import_module(
+            f"tdfo_tpu.models.{self.config.model}")
         cfg, lm = self.config, self.config.lm
         # the [lm] table's keys are the model configuration's own (its chunk
         # and block sizes are the model's constants, measured on the v5e)
-        self.model_cfg = OlmoHybridConfig(**{
-            f.name: getattr(lm, f.name) for f in dataclasses.fields(lm)})
+        reads = {f.name for f in dataclasses.fields(family.LmConfig)}
+        other = sorted(f.name for f in dataclasses.fields(lm)
+                       if f.name not in reads
+                       and getattr(lm, f.name) != f.default)
+        if other:
+            raise ValueError(
+                f"model = \"{cfg.model}\" does not read [lm] {other}")
+        self.model_cfg = family.LmConfig(**{k: getattr(lm, k) for k in reads})
         sharding = cfg.embedding_sharding if cfg.model_parallel else "replicated"
         fused_at = cfg.effective_fused_threshold
         self.coll = ShardedEmbeddingCollection(
@@ -1099,12 +1116,18 @@ class Trainer:
         k_table, k_dense = jax.random.split(jax.random.key(cfg.seed))
         tables = self.coll.init(k_table)
         # initialised under jit so that every leaf is made where it lives
-        dense = jax.jit(lambda k: init_olmo_hybrid(k, self.model_cfg),
+        dense = jax.jit(lambda k: family.init_params(k, self.model_cfg),
                         out_shardings=NamedSharding(self.mesh, P()))(k_dense)
+        # a buffer (a router's selection bias) gets no gradient and no decay:
+        # under AdamW it stands still.  A family without one keeps the
+        # optimizer (and so its state's tree) as it was
+        decayed = jax.tree_util.tree_map_with_path(
+            lambda path, _: path[-1].key not in family.BUFFERS,
+            dense) if family.BUFFERS else None
         self.state = _commit_replicated(SparseTrainState.create(
             dense_params=dense,
             tx=optax.adamw(cfg.learning_rate, b2=_LM_ADAM_B2,
-                           weight_decay=cfg.weight_decay),
+                           weight_decay=cfg.weight_decay, mask=decayed),
             tables=tables,
             # the table takes Adam without decay (no decay on embeddings)
             sparse_opt=sparse_optimizer(
@@ -1115,12 +1138,17 @@ class Trainer:
         model_cfg = self.model_cfg
 
         def forward(dense_params, embs, batch):
-            return forward_loss(dense_params, embs["token"], batch["token"],
-                                batch["segment"], model_cfg, dtype=dtype)
+            return family.forward_loss(
+                dense_params, embs["token"], batch["token"], batch["segment"],
+                model_cfg, dtype=dtype)
 
+        # with counters the forward returns (loss, counters) and the step
+        # (state, (loss, counters)): _train_epoch takes them apart
+        self._step_counters = tuple(family.STEP_COUNTERS)
         step = make_sparse_train_step(
             self.coll, forward, mode=cfg.lookup_mode,
-            jit=not self._counters_on, dedup_lookup=cfg.dedup_lookup)
+            jit=not self._counters_on, dedup_lookup=cfg.dedup_lookup,
+            with_aux=bool(self._step_counters))
         self.train_step = (_wrap_counters_step(step, donate_state=True)
                            if self._counters_on else step)
         self._train_auc_enabled = False
@@ -1133,7 +1161,8 @@ class Trainer:
         @jax.jit
         def eval_loss(state, batch):
             embs = coll.lookup(state.tables, {"token": batch["token"]}, mode=mode)
-            return forward(state.dense_params, embs, batch)
+            out = forward(state.dense_params, embs, batch)
+            return out[0] if self._step_counters else out
 
         self._eval_loss = eval_loss
 
@@ -1213,7 +1242,7 @@ class Trainer:
             renamed = (
                 {"item": b["train_interactions"], "label": b["labels"]} for b in stream
             )
-        elif cfg.model == "olmo_hybrid":
+        elif cfg.is_causal_lm:
             renamed = _count_lm_batches(stream, cfg.max_len)
         else:
             renamed = iter(stream)
@@ -1315,6 +1344,7 @@ class Trainer:
         flush_n = self._flush_every if self._cache_flush is not None else 0
         next_flush = (n_steps // flush_n + 1) * flush_n if flush_n else None
         pending_over: list[dict] = []
+        pending_counts: list[dict] = []  # a decoder step's device counters
         next_log = start_step + cfg.log_every_n_steps
         profiled = cfg.profile and epoch == 0 and jax.process_index() == 0
         train_auc = None
@@ -1347,6 +1377,10 @@ class Trainer:
             for over in pending_over:
                 _check_cache_overflow(over)
             pending_over.clear()
+            for counted in jax.device_get(pending_counts):
+                for name, value in counted.items():
+                    obs_trace.tally(name, int(value))
+            pending_counts.clear()
             rolled = False
             for loss_dev, k, gstep in pending:
                 v = float(loss_dev)
@@ -1430,10 +1464,13 @@ class Trainer:
                             out = self.train_step(
                                 self.state, batch, carry, train_auc)
                             self.state, loss, carry, train_auc = out[:4]
-                    elif cfg.model in ("bert4rec", "olmo_hybrid"):
+                    elif cfg.model == "bert4rec" or cfg.is_causal_lm:
                         out = self.train_step(
                             self.state, batch, self._dropout_rng)
                         self.state, loss = out[:2]
+                        if self._step_counters:
+                            loss, counted = loss
+                            pending_counts.append(counted)
                     else:
                         out = self.train_step(self.state, batch, train_auc)
                         self.state, loss, train_auc = out[:3]
@@ -1621,7 +1658,7 @@ class Trainer:
         with self._jit_ctx():
             if self.config.model == "bert4rec":
                 return self._evaluate_bert4rec(epoch)
-            if self.config.model == "olmo_hybrid":
+            if self.config.is_causal_lm:
                 return self._evaluate_lm(epoch)
             return self._evaluate_twotower(epoch)
 

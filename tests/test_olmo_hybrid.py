@@ -29,11 +29,11 @@ LM = dict(vocab_size=50, hidden_size=32, intermediate_size=48,
           linear_key_head_dim=6, linear_value_head_dim=12)
 
 
-def model_cfg(**over) -> M.OlmoHybridConfig:
-    return M.OlmoHybridConfig(**{**LM, "layer_types": LAYERS, **over})
+def model_cfg(**over) -> M.LmConfig:
+    return M.LmConfig(**{**LM, "layer_types": LAYERS, **over})
 
 
-def reference_model(cfg: M.OlmoHybridConfig, **over) -> dict:
+def reference_model(cfg: M.LmConfig, **over) -> dict:
     return dict(layer_types=list(cfg.layer_types), head_dim=cfg.head_dim,
                 full_heads=cfg.full_heads, linear_heads=cfg.linear_heads,
                 linear_key_head_dim=cfg.linear_key_head_dim,
@@ -122,7 +122,7 @@ def test_causal_document_attention_is_a_masked_softmax(t, block):
 
 def test_model_matches_the_reference_loss_and_gradients():
     cfg = model_cfg(layer_types=("linear_attention", "full_attention"))
-    params = M.init_olmo_hybrid(jax.random.key(0), cfg)
+    params = M.init_params(jax.random.key(0), cfg)
     rng = np.random.default_rng(0)
     table = 0.1 * rng.normal(size=(50, 32)).astype(np.float32)
     token, segment = packed(rng, 2, 70)
@@ -175,7 +175,7 @@ def test_two_head_shares_add_up_to_the_uncut_layer(kind, grad):
     whole = model_cfg(layer_types=(kind,))
     share = model_cfg(layer_types=(kind,), full_heads_held=2,
                       linear_heads_held=2)
-    p = M.init_olmo_hybrid(jax.random.key(1), whole)["layer_0"]["mixer"]
+    p = M.init_params(jax.random.key(1), whole)["layer_0"]["mixer"]
     rng = np.random.default_rng(1)
     x = rng.normal(size=(2, 60, 32)).astype(np.float32)
     _, segment = packed(rng, 2, 60, p_start=0.08)
@@ -301,8 +301,14 @@ def test_config_holds_the_model_to_its_path(tmp_path):
         trainer_config(tmp_path, nonfinite_tolerance=3)
     with pytest.raises(ValueError, match="unknown lm config keys"):
         trainer_config(tmp_path, lm=dict(LM, chunk=64))
+    # what the keys must satisfy together is the family's ``LmConfig``, which
+    # ``Trainer`` constructs at build (``core/config`` does not import jax)
+    from tdfo_tpu.train.trainer import Trainer
     with pytest.raises(ValueError, match="layer_types"):
-        trainer_config(tmp_path, lm=dict(LM, layer_types=["windowed"]))
+        Trainer(trainer_config(tmp_path, lm=dict(LM, layer_types=["windowed"])),
+                devices=jax.devices()[:1])
+    with pytest.raises(ValueError, match="full_heads_held"):
+        model_cfg(full_heads_held=5)
     with pytest.raises(ValueError, match="olmo_hybrid"):
         read_configs(None, model="twotower", lm=dict(LM))
     with pytest.raises(ValueError, match="steps_per_execution"):
@@ -330,11 +336,11 @@ def plain_products(monkeypatch):
     """The formulation before PRs 33 and 35, kept here: ``x @ w`` with the
     weight cast where it is used, nothing between an expression and the
     product that reads it, nor between a gradient and the optimizer."""
-    monkeypatch.setattr(M, "_cotangent_once", lambda y: y)
-    monkeypatch.setattr(M, "_made_once", lambda x: x)
+    monkeypatch.setattr(M, "cotangent_once", lambda y: y)
+    monkeypatch.setattr(M, "made_once", lambda x: x)
 
 
-def cotangents_once(cfg: M.OlmoHybridConfig, b: int, t: int) -> list[tuple]:
+def cotangents_once(cfg: M.LmConfig, b: int, t: int) -> list[tuple]:
     """Shapes of the product outputs whose cotangent is made once, one a
     site: the head's logits; in each layer ``gate``, ``up`` and ``down``; in
     a delta-rule layer ``wv`` and ``wg``.  The other products (``wq`` /
@@ -350,7 +356,7 @@ def cotangents_once(cfg: M.OlmoHybridConfig, b: int, t: int) -> list[tuple]:
     return sorted(out)
 
 
-def operands_once(cfg: M.OlmoHybridConfig, b: int, t: int) -> list[tuple]:
+def operands_once(cfg: M.LmConfig, b: int, t: int) -> list[tuple]:
     """Shapes of the left operands that are expressions and are made once,
     one a site: in each layer ``h`` (into ``gate`` and ``up``); in a
     delta-rule layer the gated and normed output (into ``wo``)."""
@@ -378,10 +384,10 @@ def barriers(jaxpr) -> list[list[tuple]]:
     return found
 
 
-@pytest.mark.parametrize("name,side", [("_cotangent_once", "backward"),
-                                       ("_made_once", "forward")])
+@pytest.mark.parametrize("name,side", [("cotangent_once", "backward"),
+                                       ("made_once", "forward")])
 def test_an_identity_holds_its_barrier_on_one_side(name, side):
-    """``_cotangent_once`` is the mirror of ``_made_once``: the one bars the
+    """``cotangent_once`` is the mirror of ``made_once``: the one bars the
     cotangent and leaves the value alone, the other bars the value and
     leaves the cotangent alone; both are identities with the identity's
     gradient and keep nothing for the backward pass."""
@@ -400,7 +406,7 @@ def test_an_identity_holds_its_barrier_on_one_side(name, side):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gradients_are_those_of_the_plain_products(dtype, monkeypatch):
     cfg = model_cfg(layer_types=("linear_attention", "full_attention"))
-    params = M.init_olmo_hybrid(jax.random.key(2), cfg)
+    params = M.init_params(jax.random.key(2), cfg)
     rng = np.random.default_rng(2)
     token, segment = packed(rng, 2, 40)
     emb = 0.1 * rng.normal(size=(2, 40, 32)).astype(np.float32)
@@ -454,7 +460,7 @@ def test_the_step_makes_every_large_products_operands_once(tmp_path):
     product in silence, nor hold every cotangent alive at once.  PR 33's
     barrier over each leaf's weight gradient is gone: with the operands
     made once the step measured faster with the product and AdamW's sweep
-    as one fusion (``models/olmo_hybrid._cotangent_once``)."""
+    as one fusion (``models/olmo_hybrid.cotangent_once``)."""
     from tdfo_tpu.train.trainer import Trainer
 
     trainer = Trainer(trainer_config(tmp_path, lm=LM),
@@ -480,7 +486,7 @@ def test_a_rematerialised_layer_saves_what_the_plain_products_save(
     from jax.ad_checkpoint import print_saved_residuals
 
     cfg = model_cfg(layer_types=(kind,))
-    params = M.init_olmo_hybrid(jax.random.key(3), cfg)
+    params = M.init_params(jax.random.key(3), cfg)
     rng = np.random.default_rng(3)
     x = rng.normal(size=(2, 40, 32)).astype(np.float32)
     _, segment = packed(rng, 2, 40)
