@@ -186,8 +186,10 @@ def rms_norm(x, w, eps: float, *, axis_name: str | None = None):
 def cotangent_once(y):
     """Identity on a product's OUTPUT whose cotangent ``dy`` passes an
     ``optimization_barrier`` of its own, so ``dy`` is ONE array, in the
-    dtype the products read it in (bfloat16 in the cell, float32 for the
-    head's logits), before the two products that need it run: ``dx = dy
+    dtype the products read it in (bfloat16 in the cell; the head's logits
+    take a float32 ``dy``, which XLA on the v5e writes as bfloat16 where it
+    is made, the one pass its products read at the default precision:
+    PERF.md, PR 37), before the two products that need it run: ``dx = dy
     w^T`` and ``dw = x^T dy``.  Without it XLA fuses the expression that
     makes ``dy`` (SwiGLU's backward from three [8192, 11008] arrays, a
     norm's backward, the softmax's) INTO both and evaluates it again for
@@ -393,24 +395,32 @@ def backbone(params, x, segment, cfg: LmConfig, *,
 def next_token_loss(params, x, token, segment, cfg: LmConfig):
     """Mean cross-entropy of token ``t + 1`` at position ``t`` where both
     lie in one document; ``(loss, labelled positions)``.  The [T, V] logits
-    are float32 and rematerialised in the backward pass."""
+    are float32 and KEPT for the backward pass under the backbone's policy,
+    which runs the norm and the log-sum-exp again from them and no product
+    (rematerialised whole, the logits' product ran twice: the head's loss
+    read 26.7 against 20.2 ms a step on the v5e, at the same peak memory;
+    PERF.md, PR 37).  The label's logit is picked by a compare against the
+    vocabulary's iota, so its transpose is a select inside the fusion that
+    makes the logits' cotangent, not a scatter into a zeroed [T, V] array."""
 
-    @jax.checkpoint
     def loss(final_norm, head, x):
         h = rms_norm(x, final_norm, cfg.rms_norm_eps)
         logits = cotangent_once(jnp.dot(h, head.astype(h.dtype),
                                          preferred_element_type=jnp.float32))
-        logp = jax.nn.log_softmax(logits, axis=-1)
         nxt = jnp.concatenate([token[:, 1:], token[:, :1]], axis=1)
         labelled = jnp.concatenate(
             [segment[:, 1:] == segment[:, :-1],
              jnp.zeros((token.shape[0], 1), bool)], axis=1)
-        picked = jnp.take_along_axis(logp, nxt[..., None], axis=-1)[..., 0]
+        vocab = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+        picked = jnp.where(vocab == nxt[..., None], logits, 0.0).sum(-1)
+        nll = jax.nn.logsumexp(logits, axis=-1) - picked
         n = jnp.maximum(labelled.sum(), 1)
-        return -jnp.where(labelled, picked, 0.0).sum() / n, n
+        return jnp.where(labelled, nll, 0.0).sum() / n, n
 
+    keep = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
     with jax.named_scope("lm_head_loss"):
-        return loss(params["final_norm"], params["head"], x)
+        return jax.checkpoint(loss, policy=keep)(
+            params["final_norm"], params["head"], x)
 
 
 def forward_loss(params, embedded, token, segment, cfg: LmConfig, *,

@@ -369,19 +369,22 @@ def operands_once(cfg: M.LmConfig, b: int, t: int) -> list[tuple]:
     return sorted(out)
 
 
-def barriers(jaxpr) -> list[list[tuple]]:
-    """The operand shapes of every ``optimization_barrier`` in a jaxpr and
-    in the jaxprs nested in it."""
-    found = []
+def equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "optimization_barrier":
-            found.append([v.aval.shape for v in eqn.invars])
+        yield eqn
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else (value,):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    found += barriers(sub)
-    return found
+                    yield from equations(sub)
+
+
+def barriers(jaxpr) -> list[list[tuple]]:
+    """The operand shapes of every ``optimization_barrier`` in a jaxpr and
+    in the jaxprs nested in it."""
+    return [[v.aval.shape for v in eqn.invars] for eqn in equations(jaxpr)
+            if eqn.primitive.name == "optimization_barrier"]
 
 
 @pytest.mark.parametrize("name,side", [("cotangent_once", "backward"),
@@ -507,3 +510,107 @@ def test_a_rematerialised_layer_saves_what_the_plain_products_save(
     # that did not before
     kept = [line for line in got if "reduce_precision" in line]
     assert len(kept) == (6 if kind == "full_attention" else 9), got
+
+
+# ---- the head's loss keeps its logits and picks the label without a
+# ---- scatter (PR 37)
+
+
+def parent_head_loss(params, x, token, segment, cfg: M.LmConfig):
+    """The formulation before PR 37, kept as the oracle: the whole loss
+    rematerialised, ``log_softmax``, the label's log-probability picked with
+    ``take_along_axis`` (whose transpose is a ``scatter-add``)."""
+
+    @jax.checkpoint
+    def loss(final_norm, head, x):
+        h = M.rms_norm(x, final_norm, cfg.rms_norm_eps)
+        logits = jnp.dot(h, head.astype(h.dtype),
+                         preferred_element_type=jnp.float32)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nxt = jnp.concatenate([token[:, 1:], token[:, :1]], axis=1)
+        labelled = jnp.concatenate(
+            [segment[:, 1:] == segment[:, :-1],
+             jnp.zeros((token.shape[0], 1), bool)], axis=1)
+        picked = jnp.take_along_axis(logp, nxt[..., None], axis=-1)[..., 0]
+        n = jnp.maximum(labelled.sum(), 1)
+        return -jnp.where(labelled, picked, 0.0).sum() / n, n
+
+    return loss(params["final_norm"], params["head"], x)
+
+
+@pytest.mark.parametrize("rows", ["packed", "unlabelled"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_head_loss_is_the_parent_formulation(dtype, rows):
+    """Value, count and gradients for ``x``, ``final_norm`` and ``head``.
+    ``packed``: three rows, two of several documents and one of one-token
+    documents (no labelled position); ``unlabelled``: no row has one, so
+    the count clamps to 1 and the loss and every gradient are zero."""
+    cfg = model_cfg(vocab_size=50)
+    params = M.init_params(jax.random.key(4), cfg)
+    rng = np.random.default_rng(4)
+    params = {"final_norm": params["final_norm"] + 0.3 * rng.normal(
+                  size=params["final_norm"].shape).astype(np.float32),
+              "head": 20 * params["head"]}          # logits that spread
+    b, t = 3, 40
+    token, segment = packed(rng, b, t, p_start=0.15)
+    segment[2] = np.arange(t)
+    if rows == "unlabelled":
+        segment[:] = np.arange(t)
+    x = jnp.asarray(rng.normal(size=(b, t, cfg.hidden_size)), dtype)
+
+    def value_and_grads(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda p, x: fn(p, x, token, segment, cfg), (0, 1),
+            has_aux=True))(params, x)
+
+    (loss, n), grads = value_and_grads(M.next_token_loss)
+    (want_loss, want_n), want = value_and_grads(parent_head_loss)
+    assert int(n) == int(want_n) == max(int((segment[:, 1:] == segment[:, :-1]).sum()), 1)
+    tight = dtype == jnp.float32
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6 if tight else 1e-5)
+    for (path, a), w in zip(jax.tree.leaves_with_path(grads),
+                            jax.tree.leaves(want)):
+        assert a.dtype == w.dtype
+        a, w = np.asarray(a, np.float32), np.asarray(w, np.float32)
+        scale = float(np.abs(w).max()) + 1e-30
+        np.testing.assert_allclose(a, w, atol=(1e-6 if tight else 1e-2) * scale,
+                                   err_msg=str(path))
+    if rows == "unlabelled":
+        assert float(loss) == 0.0
+        assert not any(np.asarray(g).any() for g in jax.tree.leaves(grads))
+
+
+@pytest.mark.parametrize("family", ["olmo_hybrid", "nemotron_h"])
+def test_the_head_runs_three_products_and_no_scatter(family):
+    """In the value and gradient of the whole forward, the [B, T, V] logits
+    are made by ONE product and the backward pass reads them as they were
+    kept: three products touch a [B, T, V] array (the logits, ``dx`` and
+    ``dw``), not four, and nothing is scattered into one (the label is
+    picked by a compare against the vocabulary's iota)."""
+    import importlib
+
+    from tests.test_nemotron_h import LM as NEMOTRON_LM
+
+    module = importlib.import_module(f"tdfo_tpu.models.{family}")
+    cfg = (model_cfg() if family == "olmo_hybrid"
+           else module.LmConfig(**NEMOTRON_LM))
+    params = module.init_params(jax.random.key(5), cfg)
+    rng = np.random.default_rng(5)
+    b, t = 2, 40
+    token, segment = packed(rng, b, t)
+    emb = jnp.asarray(0.1 * rng.normal(size=(b, t, cfg.hidden_size)),
+                      jnp.bfloat16)
+
+    def loss(p, e):
+        out = module.forward_loss(p, e, token, segment, cfg, dtype=jnp.bfloat16)
+        return out[0] if isinstance(out, tuple) else out
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(params, emb)
+    logits = (b, t, cfg.vocab_size)
+    touch = lambda eqn: any(getattr(v.aval, "shape", None) == logits
+                            for v in (*eqn.invars, *eqn.outvars))
+    found = [eqn.primitive.name for eqn in equations(jaxpr.jaxpr) if touch(eqn)]
+    assert found.count("dot_general") == 3, found
+    scattered = [eqn.outvars[0].aval.shape for eqn in equations(jaxpr.jaxpr)
+                 if "scatter" in eqn.primitive.name]
+    assert not [s for s in scattered if s[-1:] == (cfg.vocab_size,)], scattered
