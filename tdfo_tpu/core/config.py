@@ -372,10 +372,11 @@ class TrainSpec:
 
 @dataclass(frozen=True)
 class LmSpec:
-    """``[lm]`` config table: the architecture and the share of a causal
-    decoder (``model`` names the family: ``"olmo_hybrid"``,
+    """``[lm]`` config table: the architecture and the share of a decoder
+    (``model`` names the family: ``"olmo_hybrid"``,
     ``tdfo_tpu/models/olmo_hybrid.py``; ``"nemotron_h"``,
-    ``tdfo_tpu/models/nemotron_h.py``).  Key names are the published
+    ``tdfo_tpu/models/nemotron_h.py``; ``"sdar_moe"``,
+    ``tdfo_tpu/models/sdar_moe.py``).  Key names are the published
     ``config.json``'s (huggingface ``model_type`` of the same name) where it
     has one; a head, group or expert count there is the PUBLISHED count (it
     sets the head and group sizes and the router's width), and the
@@ -423,10 +424,20 @@ class LmSpec:
     attention_heads_held: int = 0  # with the key/value heads they read
     experts_held: int = 0
     first_expert_held: int = 0     # index of the first held expert
+    # ---- sdar_moe (and the nemotron_h keys of the same meaning: heads,
+    # head_dim, num_experts_per_tok, moe_intermediate_size, norm_topk_prob,
+    # experts_held, first_expert_held)
+    num_hidden_layers: int = 0
+    num_experts: int = 0           # the router's width (Qwen3-MoE's name)
+    rope_theta: float = 0.0        # rotary base, over the whole head
+    # block diffusion: tokens a block and the least noise level (t ~
+    # U[noise_eps, 1] a block); the mask token is the table's last row
+    block_length: int = 0
+    noise_eps: float = 1e-3
 
 
-# the causal decoder families (``tdfo_tpu/models/<name>.py``) and the [lm]
-# keys each needs > 0.  That is all this module, which does not import jax,
+# the decoder families (``tdfo_tpu/models/<name>.py``) and the [lm] keys
+# each needs > 0.  That is all this module, which does not import jax,
 # says of a family: which keys it reads and what they must satisfy together
 # is its module's ``LmConfig``, which ``Trainer`` constructs at build
 LM_FAMILIES: dict[str, tuple[str, ...]] = {
@@ -439,6 +450,11 @@ LM_FAMILIES: dict[str, tuple[str, ...]] = {
         "head_dim", "n_routed_experts", "num_experts_per_tok",
         "moe_latent_size", "moe_intermediate_size",
         "moe_shared_expert_intermediate_size"),
+    "sdar_moe": (
+        "vocab_size", "hidden_size", "num_hidden_layers",
+        "num_attention_heads", "num_key_value_heads", "head_dim",
+        "num_experts", "num_experts_per_tok", "moe_intermediate_size",
+        "rope_theta", "block_length"),
 }
 
 
@@ -801,8 +817,10 @@ class Config:
 
     @property
     def is_causal_lm(self) -> bool:
-        """``model`` names a causal decoder family: the ``[lm]`` table, packed
-        token sequences, a donated state, next-token loss."""
+        """``model`` names a decoder family (``LM_FAMILIES``): the ``[lm]``
+        table, packed token sequences, a donated state; the family's
+        ``forward_loss`` owns its loss (next-token for the causal ones, the
+        masked-position loss of block diffusion for ``sdar_moe``)."""
         return self.model in LM_FAMILIES
 
     def _check_lm(self) -> None:
@@ -837,7 +855,7 @@ class Config:
         if self.is_causal_lm:
             self._check_lm()
         elif self.lm != LmSpec():
-            raise ValueError("the [lm] table configures a causal decoder "
+            raise ValueError("the [lm] table configures a decoder "
                              f"(model = one of {sorted(LM_FAMILIES)}) only")
         if ((self.categorical_features or self.continuous_features)
                 and self.model != "dlrm"):

@@ -48,6 +48,9 @@ __all__ = ["LmConfig", "init_params", "forward_loss", "backbone",
 LAYER_KINDS = ("linear_attention", "full_attention")
 # attention: queries (and keys) a block; the tests pass smaller ones
 QUERY_BLOCK = 1024
+# the online softmax's masked logit: finite, so a row whose keys so far are
+# all masked stays exact
+_LOW = -1e30
 
 
 @dataclass(frozen=True)
@@ -234,59 +237,104 @@ def proj(x, w):
     return jnp.dot(x, w.astype(x.dtype))
 
 
-def causal_document_attention(q, k, v, segment, *,
+def _online_read(carry, q_b, k_i, v_i, visible, scale):
+    """One read of the online softmax: the queries ``q_b`` [B, Q, H, dh]
+    against the keys ``k_i`` / values ``v_i`` [B, K, H, dh] where
+    ``visible()`` ([B, 1, Q, K] bool) lets them, folded into the carry
+    ``(o, m, l)``: the unnormalised output [B, H, Q, dh], the row maximum
+    and the row sum [B, H, Q], all float32."""
+    o, m, l = carry
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q_b, k_i,
+                        preferred_element_type=jnp.float32) * scale
+    ok = visible()
+    logits = jnp.where(ok, logits, _LOW)
+    m_new = jnp.maximum(m, logits.max(axis=-1))
+    p = jnp.where(ok, jnp.exp(logits - m_new[..., None]), 0.0)
+    shrink = jnp.exp(m - m_new)
+    l = l * shrink + p.sum(axis=-1)
+    o = o * shrink[..., None] + jnp.einsum(
+        "bhqk,bkhd->bhqd", p.astype(v_i.dtype), v_i,
+        preferred_element_type=jnp.float32)
+    return o, m_new, l
+
+
+def _fresh(q_b):
+    """The online softmax's empty carry, its zeros made FROM the queries:
+    under a shard_map they vary over the mesh axis as the reads do."""
+    o0 = jnp.swapaxes(q_b, 1, 2).astype(jnp.float32) * 0.0
+    return o0, o0[..., 0] + _LOW, o0[..., 0]
+
+
+def _finish(carry, dtype):
+    o, _, l = carry
+    return jnp.swapaxes(o / l[..., None], 1, 2).astype(dtype)
+
+
+def causal_document_attention(q, k, v, segment, *, block_of=None,
+                              block_length: int = 1,
                               query_block: int = QUERY_BLOCK):
-    """``softmax(q k^T / sqrt(dh))`` over the keys of the same document at
-    positions ``<= t``.  ``q`` [B, T, H, dh]; ``k``, ``v`` [B, T, H_kv, dh]
-    with ``H_kv`` dividing ``H`` (query head ``i`` reads key/value head
-    ``i // (H / H_kv)``); ``segment`` [B, T].  Blockwise with an online softmax (the ``ring_block_k``
-    formulation of ``parallel/ring_attention.py``): a block of
-    ``query_block`` queries at a time against the keys up to the block's end
-    (the blocks above the diagonal are never formed), those keys a block at
-    a time in a ``lax.scan`` whose body is rematerialised, so nothing
-    [T, T] is ever alive; statistics in float32.  One masked softmax over
-    all keys of a query block measured 7x slower on the v5e: XLA fuses the
-    row maximum into a lane-reduce over [H, 1024, 8192] float32 that runs
-    far under the memory bandwidth (PERF.md, PR 31)."""
+    """``softmax(q k^T / sqrt(dh) + M) v`` where the mask ``M`` lets query
+    ``i`` see key ``j`` only in its own document and as a predicate over
+    blocks says:
+
+    causal            (``block_of`` None) ``q``, ``k``, ``v`` of the ``T``
+                      positions of ``segment`` [B, T]; ``j <= i``: the
+                      decoders' mask.
+    block diffusion   ``q``, ``k``, ``v`` of the stream ``[x_t | x_0]``, the
+                      noisy copy of the ``L`` positions of ``segment`` [B,
+                      L] beside the clean one (``T = 2 L``); ``block_of``
+                      [B, L] a position's block of ``block_length`` tokens
+                      within its document.  A noisy ``i`` sees the noisy
+                      ``j`` of its own block and the clean ``j`` of earlier
+                      blocks; a clean ``i`` the clean ``j`` of its block and
+                      of earlier ones, never a noisy ``j``.  Every position
+                      clean, with blocks of one token, is the causal mask.
+
+    ``k``, ``v`` [B, T, H_kv, dh] with ``H_kv`` dividing ``H`` (query head
+    ``i`` reads key/value head ``i // (H / H_kv)``).  Blockwise with an
+    online softmax (the ``ring_block_k`` formulation of
+    ``parallel/ring_attention.py``), statistics in float32, so nothing [T,
+    T] is ever alive: a tile of ``query_block`` queries at a time reads the
+    keys it may see a tile at a time in a ``lax.scan`` whose body is
+    rematerialised.  Causal: a Python loop over the query tiles, each
+    against the keys up to its end (the tiles above the diagonal are never
+    formed).  Block diffusion: ONE ``lax.scan`` over the query tiles, every
+    tile the same program: its own window of ``query_block`` keys and, where
+    its blocks straddle the tile's ends, ``block_length - 1`` more each way
+    (rounded to half a lane row, a ``dynamic_slice`` of fixed width), then
+    a scan over the clean key tiles, a tile the mask leaves wholly dead
+    skipped by ``lax.cond``; the mask does the rest.  One masked softmax
+    over all keys of a query block measured 7x slower on the v5e: XLA fuses
+    the row maximum into a lane-reduce over [H, 1024, 8192] float32 that
+    runs far under the memory bandwidth (PERF.md, PR 31)."""
     b, t, h, dh = q.shape
     if k.shape[2] != h:
         # grouped queries: ``h / h_kv`` query heads read one key/value head
         k, v = (jnp.repeat(a, h // k.shape[2], axis=2) for a in (k, v))
     scale = 1.0 / math.sqrt(dh)
+    if block_of is not None:
+        return _block_diffusion(q, k, v, segment, block_of, block_length,
+                                query_block, scale)
     block = min(query_block, t)
     pos = jnp.arange(t)
-    low = -1e30   # finite: a row whose keys so far are all masked stays exact
 
     def rows(q_b, k_b, v_b, seg_q, seg_k, pos_q, pos_k):
         n = k_b.shape[1] // block
 
         def keys(carry, xs):
-            o, m, l = carry
             k_i, v_i, seg_i, pos_i = xs
-            logits = jnp.einsum("bqhd,bkhd->bhqk", q_b, k_i,
-                                preferred_element_type=jnp.float32) * scale
-            ok = ((pos_i[None, :] <= pos_q[:, None])[None]
-                  & (seg_i[:, None, :] == seg_q[:, :, None]))[:, None]
-            logits = jnp.where(ok, logits, low)
-            m_new = jnp.maximum(m, logits.max(axis=-1))
-            p = jnp.where(ok, jnp.exp(logits - m_new[..., None]), 0.0)
-            shrink = jnp.exp(m - m_new)
-            l = l * shrink + p.sum(axis=-1)
-            o = o * shrink[..., None] + jnp.einsum(
-                "bhqk,bkhd->bhqd", p.astype(v_i.dtype), v_i,
-                preferred_element_type=jnp.float32)
-            return (o, m_new, l), None
+            return _online_read(
+                carry, q_b, k_i, v_i,
+                lambda: ((pos_i[None, :] <= pos_q[:, None])[None]
+                         & (seg_i[:, None, :] == seg_q[:, :, None]))[:, None],
+                scale), None
 
         split = lambda a: jnp.moveaxis(
             a.reshape(a.shape[0], n, block, *a.shape[2:]), 1, 0)
-        # zeros made FROM the queries: under a shard_map they vary over the
-        # mesh axis as the body's outputs do
-        o0 = jnp.swapaxes(q_b, 1, 2).astype(jnp.float32) * 0.0
-        init = (o0, o0[..., 0] + low, o0[..., 0])
-        (o, _, l), _ = jax.lax.scan(
-            jax.checkpoint(keys), init,
+        carry, _ = jax.lax.scan(
+            jax.checkpoint(keys), _fresh(q_b),
             (split(k_b), split(v_b), split(seg_k), pos_k.reshape(n, block)))
-        return jnp.swapaxes(o / l[..., None], 1, 2).astype(v_b.dtype)
+        return _finish(carry, v_b.dtype)
 
     out = []
     for a in range(0, t, block):
@@ -299,6 +347,77 @@ def causal_document_attention(q, k, v, segment, *,
                         widen(segment, -1), pos[a:e],
                         jnp.pad(pos[:e], (0, pad), constant_values=t)))
     return jnp.concatenate(out, axis=1) if len(out) > 1 else out[0]
+
+
+def _block_diffusion(q, k, v, segment, block_of, block_length, query_block,
+                     scale):
+    """:func:`causal_document_attention` over the stream ``[x_t | x_0]``."""
+    b, t, h, dh = q.shape
+    length = t // 2
+    if segment.shape[1] != length or block_of.shape != segment.shape:
+        raise ValueError("block diffusion: q, k, v of 2 L positions, segment "
+                         "and block_of of L")
+    block = min(query_block, length)
+    if length % block:
+        raise ValueError(f"block diffusion: query_block {block} must divide "
+                         f"the sequence's {length} positions")
+    tiles = length // block
+    # a block may straddle a tile's end by block_length - 1 keys each way
+    reach = -(-(block_length - 1) // 64) * 64
+    width = min(block + 2 * reach, length)
+    tile = lambda a: jnp.moveaxis(
+        a.reshape(a.shape[0], -1, block, *a.shape[2:]), 1, 0)
+    twice = lambda a: jnp.concatenate([a, a], axis=1)
+
+    def same_doc(seg_q, seg_k):
+        return seg_k[:, None, :] == seg_q[:, :, None]
+
+    def earlier(q_t, seg_q, blk_q, noisy, i):
+        """A scan step over the clean key tiles before the query tile's
+        own (a noisy tile's own clean tile too: its earlier blocks)."""
+        def read(carry, xs):
+            k_j, v_j, seg_j, blk_j, j = xs
+            bq, bk = blk_q[:, :, None], blk_j[:, None, :]
+            visible = lambda: (same_doc(seg_q, seg_j) & jnp.where(
+                noisy, bk < bq, bk <= bq))[:, None]
+            return jax.lax.cond(
+                jnp.where(noisy, j <= i, j < i),
+                lambda c: _online_read(c, q_t, k_j, v_j, visible, scale),
+                lambda c: c, carry), None
+        return read
+
+    def own(carry, q_t, seg_q, blk_q, noisy, i):
+        """The window of the query tile's own kind about it: noisy keys of
+        its blocks for a noisy tile, clean keys from its first on for a
+        clean one (those before are the scan's)."""
+        start = jnp.clip(i * block - reach, 0, length - width)
+        at = jnp.where(noisy, 0, length) + start
+        window = lambda a, s: jax.lax.dynamic_slice_in_dim(a, s, width, axis=1)
+        k_w, v_w = window(k, at), window(v, at)
+        seg_w, blk_w = window(segment, start), window(block_of, start)
+        first = jnp.where(noisy, 0, i * block)
+        bq, bk = blk_q[:, :, None], blk_w[:, None, :]
+
+        def visible():
+            ok = same_doc(seg_q, seg_w) & jnp.where(noisy, bk == bq, bk <= bq)
+            return (ok & (start + jnp.arange(width) >= first))[:, None]
+        return _online_read(carry, q_t, k_w, v_w, visible, scale)
+
+    clean = tuple(tile(a) for a in (k[:, length:], v[:, length:], segment,
+                                    block_of)) + (jnp.arange(tiles),)
+
+    def query_tile(_, xs):
+        q_t, seg_q, blk_q, n = xs
+        noisy, i = n < tiles, n % tiles
+        carry = jax.checkpoint(own)(_fresh(q_t), q_t, seg_q, blk_q, noisy, i)
+        carry, _ = jax.lax.scan(
+            jax.checkpoint(earlier(q_t, seg_q, blk_q, noisy, i)), carry, clean)
+        return None, _finish(carry, v.dtype)
+
+    _, out = jax.lax.scan(query_tile, None, (
+        tile(q), tile(twice(segment)), tile(twice(block_of)),
+        jnp.arange(2 * tiles)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, t, h, dh)
 
 
 def full_attention_mixer(p, x, segment, cfg: LmConfig, *,

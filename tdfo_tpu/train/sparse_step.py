@@ -133,6 +133,8 @@ def make_sparse_train_step(
     transform turns into one: the transform runs INSIDE the jitted step
     (e.g. ``jagged_to_dense`` materialising [B, T] ids from a
     (values, lengths) jagged batch, fbgemm ``jagged_2d_to_dense`` parity).
+    A transform that accepts an ``rng`` keyword gets the step's key, as a
+    ``dropout_rng`` forward does (block diffusion's noisy copy of the ids).
 
     ``with_aux=True``: ``forward`` must return ``(loss, aux)`` and the step
     returns ``(state, (loss, aux))`` — the hook for per-epoch TRAIN metrics
@@ -190,6 +192,8 @@ def make_sparse_train_step(
             "'gspmd' only")
     features = list(coll.features())
     takes_rng = "dropout_rng" in inspect.signature(forward).parameters
+    transform_rng = (batch_transform is not None and "rng" in
+                     inspect.signature(batch_transform).parameters)
     # hot/cold (frequency-partitioned) tables: per-feature id routing splits
     # lookups into hot-head positions (updated scatter-free via one-hot MXU
     # contractions, no dedupe) and residual cold ids (riding the unchanged
@@ -253,7 +257,12 @@ def make_sparse_train_step(
         return jnp.concatenate(id_list), sizes, bound
 
     def step(state: SparseTrainState, batch, rng=None) -> tuple[SparseTrainState, jax.Array]:
-        if batch_transform is not None:
+        step_rng = None
+        if (takes_rng or transform_rng) and rng is not None:
+            step_rng = jax.random.fold_in(rng, state.step)
+        if transform_rng:
+            batch = batch_transform(batch, rng=step_rng)
+        elif batch_transform is not None:
             batch = batch_transform(batch)
         ids = {f: batch[f] for f in features}
         # update-cache coverage, static under jit: the presence of the
@@ -262,9 +271,6 @@ def make_sparse_train_step(
         # graph even on a cache_rows > 0 collection
         cached = {k[len(CACHE_PREFIX):] for k in state.slots
                   if k.startswith(CACHE_PREFIX)}
-        step_rng = None
-        if takes_rng and rng is not None:
-            step_rng = jax.random.fold_in(rng, state.step)
 
         # hot/cold routing: one remap per hot feature, shared by the
         # forward gather and both update halves.  cold_ids carries -1 at

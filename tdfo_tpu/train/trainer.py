@@ -1061,23 +1061,28 @@ class Trainer:
         self.eval_accum = eval_accum
 
     def _build_causal_lm(self) -> None:
-        """A causal decoder (``Config.is_causal_lm``: ``models/olmo_hybrid``,
-        ``models/nemotron_h``) in the DMP regime, wired as
-        ``_build_bert4rec`` wires its model-parallel one: the vocabulary
+        """A decoder (``Config.is_causal_lm``: ``models/olmo_hybrid``,
+        ``models/nemotron_h``, ``models/sdar_moe``) in the DMP regime, wired
+        as ``_build_bert4rec`` wires its model-parallel one: the vocabulary
         (slice) as one table of the collection (under the fused threshold it
         is a plain table: gather lookup, row-sparse Adam on touched rows),
         backbone and head as dense leaves under AdamW, one
-        ``make_sparse_train_step`` whose forward is the backbone plus
-        next-token cross-entropy.  The family's module supplies the
-        configuration (``LmConfig``, from the ``[lm]`` keys of its fields'
-        names), the initialiser (``init_params``), ``forward_loss``, the
-        counters its step returns beside the loss (``STEP_COUNTERS``:
-        tallied into the epoch record where the loss is fetched) and the
-        leaves that are buffers (``BUFFERS``: no decay).  The dense state is
-        many GiB, so unlike the other builders this one DONATES the step's
-        state (config requires ``nonfinite_tolerance = 0``: the guard's
-        second copy has no room), rematerialises by layer (the family's
-        ``backbone``) and computes in bfloat16 on TPU under
+        ``make_sparse_train_step`` whose forward is the family's
+        ``forward_loss``, which owns its loss (next-token cross-entropy, or
+        block diffusion's over masked positions).  The family's module
+        supplies the configuration (``LmConfig``, from the ``[lm]`` keys of
+        its fields' names), the initialiser (``init_params``),
+        ``forward_loss``, the counters its step returns beside the loss
+        (``STEP_COUNTERS``: tallied into the epoch record where the loss is
+        fetched), the leaves that are buffers (``BUFFERS``: no decay) and,
+        where its loss needs more than the packed ids, ``prepare_batch``:
+        run inside the step on the step's key before the lookup, it may
+        replace the ids (block diffusion's noisy copy beside the clean one)
+        and add arrays, which reach ``forward_loss`` by name.  The dense
+        state is many GiB, so unlike the other builders this one DONATES
+        the step's state (config requires ``nonfinite_tolerance = 0``: the
+        guard's second copy has no room), rematerialises by layer (the
+        family's ``backbone``) and computes in bfloat16 on TPU under
         ``mixed_precision`` with float32 parameters, optimizer state and
         softmax."""
         import importlib
@@ -1136,11 +1141,16 @@ class Trainer:
         ), self.mesh)
         dtype = compute_dtype(cfg.mixed_precision, mesh_platform(self.mesh))
         model_cfg = self.model_cfg
+        prepare = getattr(family, "prepare_batch", None)
+        transform = None if prepare is None else (
+            lambda batch, rng: prepare(batch, rng, model_cfg))
 
         def forward(dense_params, embs, batch):
+            added = {k: v for k, v in batch.items()
+                     if k not in ("token", "segment")}
             return family.forward_loss(
                 dense_params, embs["token"], batch["token"], batch["segment"],
-                model_cfg, dtype=dtype)
+                model_cfg, dtype=dtype, **added)
 
         # with counters the forward returns (loss, counters) and the step
         # (state, (loss, counters)): _train_epoch takes them apart
@@ -1148,7 +1158,7 @@ class Trainer:
         step = make_sparse_train_step(
             self.coll, forward, mode=cfg.lookup_mode,
             jit=not self._counters_on, dedup_lookup=cfg.dedup_lookup,
-            with_aux=bool(self._step_counters))
+            with_aux=bool(self._step_counters), batch_transform=transform)
         self.train_step = (_wrap_counters_step(step, donate_state=True)
                            if self._counters_on else step)
         self._train_auc_enabled = False
@@ -1156,10 +1166,12 @@ class Trainer:
         self._stream_cls = ParquetStream
         self._train_pattern = str(Path("parquet_lm") / cfg.train_data)
         self._eval_pattern = str(Path("parquet_lm") / cfg.eval_data)
-        coll, mode = self.coll, cfg.lookup_mode
+        coll, mode, rng = self.coll, cfg.lookup_mode, self._dropout_rng
 
         @jax.jit
         def eval_loss(state, batch):
+            if transform is not None:   # the noise the next step would draw
+                batch = transform(batch, jax.random.fold_in(rng, state.step))
             embs = coll.lookup(state.tables, {"token": batch["token"]}, mode=mode)
             out = forward(state.dense_params, embs, batch)
             return out[0] if self._step_counters else out
@@ -1748,8 +1760,9 @@ class Trainer:
         return metrics
 
     def _evaluate_lm(self, epoch: int) -> dict[str, float]:
-        """Mean next-token loss over the eval shards' whole batches (a batch
-        weighs the same whatever its labelled positions)."""
+        """Mean loss (the family's: next-token, or block diffusion's) over
+        the eval shards' whole batches (a batch weighs the same whatever its
+        labelled positions)."""
         stream = self._stream(self._eval_pattern, train=False)
         losses = []
         full = (b for b in _count_lm_batches(stream, self.config.max_len)

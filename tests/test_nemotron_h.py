@@ -6,6 +6,7 @@ program), the shares tied to the uncut layers, and the decoder builder's
 ``olmo_hybrid`` against the steps built by hand."""
 
 import dataclasses
+import importlib
 import json
 import shutil
 import sys
@@ -421,8 +422,8 @@ def test_config_holds_each_family_to_its_keys(tmp_path):
 
     table = {f.name for f in dataclasses.fields(LmSpec)}
     for name, positive in LM_FAMILIES.items():
-        reads = {f.name for f in dataclasses.fields(
-            {"nemotron_h": M, "olmo_hybrid": olmo_hybrid}[name].LmConfig)}
+        reads = {f.name for f in dataclasses.fields(importlib.import_module(
+            f"tdfo_tpu.models.{name}").LmConfig)}
         assert set(positive) <= reads <= table, name
     cfg = trainer_config(tmp_path)
     assert cfg.is_causal_lm and cfg.lm.hybrid_override_pattern == "ME*"
