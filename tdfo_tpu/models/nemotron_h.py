@@ -304,9 +304,10 @@ def backbone(params, x, segment, cfg: LmConfig):
 
 
 def forward_loss(params, embedded, token, segment, cfg: LmConfig, *,
-                 dtype=jnp.float32):
+                 dtype=jnp.float32, platform: str | None = None):
     """The training forward: gathered vectors ``embedded`` [B, T, d] ->
-    ``(next-token loss, {counter: int32 scalar})``."""
+    ``(next-token loss, {counter: int32 scalar})``.  ``platform`` (the
+    devices') picks no kernel in this family."""
     with jax.named_scope("lm_embed"):
         x = embedded.astype(dtype)
     x, counters = backbone(params, x, segment, cfg)

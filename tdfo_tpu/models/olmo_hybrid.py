@@ -36,7 +36,9 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from tdfo_tpu.core.mesh import mesh_platform, pallas_impl
 from tdfo_tpu.ops.gated_delta import chunk_gated_delta_rule
+from tdfo_tpu.ops.pallas_kernels import bd_attention
 
 __all__ = ["LmConfig", "init_params", "forward_loss", "backbone",
            "STEP_COUNTERS", "BUFFERS", "next_token_loss",
@@ -272,7 +274,8 @@ def _finish(carry, dtype):
 
 def causal_document_attention(q, k, v, segment, *, block_of=None,
                               block_length: int = 1,
-                              query_block: int = QUERY_BLOCK):
+                              query_block: int = QUERY_BLOCK,
+                              platform: str | None = None):
     """``softmax(q k^T / sqrt(dh) + M) v`` where the mask ``M`` lets query
     ``i`` see key ``j`` only in its own document and as a predicate over
     blocks says:
@@ -306,8 +309,20 @@ def causal_document_attention(q, k, v, segment, *, block_of=None,
     skipped by ``lax.cond``; the mask does the rest.  One masked softmax
     over all keys of a query block measured 7x slower on the v5e: XLA fuses
     the row maximum into a lane-reduce over [H, 1024, 8192] float32 that
-    runs far under the memory bandwidth (PERF.md, PR 31)."""
+    runs far under the memory bandwidth (PERF.md, section 6).
+
+    On TPU devices (``platform``, the devices' platform; the default
+    device's if None) block diffusion is ONE Pallas kernel instead
+    (``ops/pallas_kernels.bd_attention``): the key/value heads read once for
+    their query heads, only the key tiles a query tile's documents reach,
+    the same products and float32 statistics.  The scan below is the path
+    off the chip."""
     b, t, h, dh = q.shape
+    if block_of is not None and pallas_impl(
+            "bd_attention", platform or mesh_platform(),
+            off_chip="xla") == "kernel":
+        return bd_attention(q, k, v, segment, block_of,
+                            block_length=block_length)
     if k.shape[2] != h:
         # grouped queries: ``h / h_kv`` query heads read one key/value head
         k, v = (jnp.repeat(a, h // k.shape[2], axis=2) for a in (k, v))
@@ -543,9 +558,11 @@ def next_token_loss(params, x, token, segment, cfg: LmConfig):
 
 
 def forward_loss(params, embedded, token, segment, cfg: LmConfig, *,
-                 dtype=jnp.float32, axis_name: str | None = None):
+                 dtype=jnp.float32, axis_name: str | None = None,
+                 platform: str | None = None):
     """The training forward: gathered vectors ``embedded`` [B, T, d] ->
-    scalar next-token loss."""
+    scalar next-token loss.  ``platform`` (the devices') picks no kernel in
+    this family."""
     with jax.named_scope("lm_embed"):
         x = embedded.astype(dtype)
     x = backbone(params, x, segment, cfg, axis_name=axis_name)

@@ -56,6 +56,7 @@ from tdfo_tpu.models.olmo_hybrid import (
     causal_document_attention, cotangent_once, init_tree, made_once, proj,
     rms_norm)
 from tdfo_tpu.ops import moe
+from tdfo_tpu.ops.pallas_kernels import bd_tile_count
 
 __all__ = ["LmConfig", "init_params", "param_shapes", "prepare_batch",
            "forward_loss", "backbone", "attention_mixer", "expert_layer",
@@ -63,10 +64,14 @@ __all__ = ["LmConfig", "init_params", "param_shapes", "prepare_batch",
            "STEP_COUNTERS", "BUFFERS"]
 
 # what the step returns beside the loss: the noisy positions that were
-# masked, and ``nemotron_h``'s expert counters (summed over the layers but
-# ``moe_load_max``, the largest held expert's pairs in one)
-STEP_COUNTERS = ("bd_masked_tokens", "moe_pairs", "moe_pairs_computed",
-                 "moe_load_max", "moe_layers_dense")
+# masked, the attention kernel's (query tile, key tile) pairs summed over the
+# layers (``ops/pallas_kernels.bd_tile_count``: what the kernel multiplies on
+# the chip, counted alike off it), and ``nemotron_h``'s expert counters
+# (summed over the layers but ``moe_load_max``, the largest held expert's
+# pairs in one)
+_EXPERT_COUNTERS = ("moe_pairs", "moe_pairs_computed", "moe_load_max",
+                   "moe_layers_dense")
+STEP_COUNTERS = ("bd_masked_tokens", "bd_attn_tiles") + _EXPERT_COUNTERS
 BUFFERS: tuple[str, ...] = ()
 
 
@@ -208,8 +213,10 @@ def rope(x, pos, theta: float):
     return (x32 * jnp.cos(ang) + half * jnp.sin(ang)).astype(x.dtype)
 
 
-def attention_mixer(p, u, segment, block_of, cfg: LmConfig):
-    """``u`` [B, 2T, d] (normed ``[x_t | x_0]``) -> ``W_o``'s output."""
+def attention_mixer(p, u, segment, block_of, cfg: LmConfig, *,
+                    platform: str | None = None):
+    """``u`` [B, 2T, d] (normed ``[x_t | x_0]``) -> ``W_o``'s output;
+    ``platform`` (the devices') picks the attention's kernel."""
     b, n, _ = u.shape
     length, dh = n // 2, cfg.head_dim
     with jax.named_scope("attn_proj"):
@@ -221,7 +228,8 @@ def attention_mixer(p, u, segment, block_of, cfg: LmConfig):
         q, k = rope(q, pos, cfg.rope_theta), rope(k, pos, cfg.rope_theta)
     with jax.named_scope("bd_attn"):
         o = causal_document_attention(q, k, v, segment, block_of=block_of,
-                                      block_length=cfg.block_length)
+                                      block_length=cfg.block_length,
+                                      platform=platform)
     with jax.named_scope("attn_proj"):
         return proj(o.reshape(b, n, -1), p["wo"])
 
@@ -247,24 +255,27 @@ def expert_layer(p, u, cfg: LmConfig):
     return out.reshape(b, n, d), counters
 
 
-def _block(p, x, segment, block_of, cfg: LmConfig):
+def _block(p, x, segment, block_of, cfg: LmConfig, platform=None):
     eps = cfg.rms_norm_eps
     u = made_once(rms_norm(x, p["attn_norm"], eps))
-    h = x + attention_mixer(p["attn"], u, segment, block_of, cfg)
+    h = x + attention_mixer(p["attn"], u, segment, block_of, cfg,
+                            platform=platform)
     out, counters = expert_layer(
         p["moe"], made_once(rms_norm(h, p["moe_norm"], eps)), cfg)
     return h + out, counters
 
 
-def backbone(params, x, segment, block_of, cfg: LmConfig):
+def backbone(params, x, segment, block_of, cfg: LmConfig, *,
+             platform: str | None = None):
     """``x`` [B, 2T, d] through every layer, each rematerialised in the
     backward pass (it keeps its input and its projections' outputs, as
     ``models/olmo_hybrid.backbone``); ``(x, counters)``."""
     keep = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    total = dict.fromkeys(STEP_COUNTERS[1:], jnp.zeros((), jnp.int32))
+    total = dict.fromkeys(_EXPERT_COUNTERS, jnp.zeros((), jnp.int32))
     for i in range(cfg.num_hidden_layers):
         layer = jax.checkpoint(
-            lambda p, x: _block(p, x, segment, block_of, cfg), policy=keep)
+            lambda p, x: _block(p, x, segment, block_of, cfg, platform),
+            policy=keep)
         x, c = layer(params[f"layer_{i}"], x)
         total = {k: (jnp.maximum(total[k], c[k]) if k == "moe_load_max"
                      else total[k] + c[k]) for k in total}
@@ -294,15 +305,18 @@ def diffusion_loss(params, x, label, weight, cfg: LmConfig):
 
 
 def forward_loss(params, embedded, token, segment, cfg: LmConfig, *,
-                 weight, dtype=jnp.float32):
+                 weight, dtype=jnp.float32, platform: str | None = None):
     """The training forward over :func:`prepare_batch`'s arrays: gathered
     vectors ``embedded`` [B, 2T, d] of ``token`` = ``[x_t | x_0]``,
-    ``segment`` and ``weight`` [B, T] -> ``(loss, {counter: int32})``."""
+    ``segment`` and ``weight`` [B, T] -> ``(loss, {counter: int32})``;
+    ``platform`` is the devices' (the attention is a kernel on TPU)."""
     length = segment.shape[1]
     with jax.named_scope("lm_embed"):
         x = embedded.astype(dtype)
     _, block_of = document_blocks(segment, cfg.block_length)
-    x, counters = backbone(params, x, segment, block_of, cfg)
+    x, counters = backbone(params, x, segment, block_of, cfg,
+                           platform=platform)
     loss = diffusion_loss(params, x[:, :length], token[:, length:], weight, cfg)
+    tiles = bd_tile_count(segment, block_of, cfg.block_length)
     return loss, {"bd_masked_tokens": (weight > 0).sum(dtype=jnp.int32),
-                  **counters}
+                  "bd_attn_tiles": cfg.num_hidden_layers * tiles, **counters}

@@ -52,6 +52,8 @@ from tdfo_tpu.ops.quant import (
 
 __all__ = [
     "flash_attention",
+    "bd_attention",
+    "bd_tile_count",
     "LineLayout",
     "line_layout",
     "fat_line_update",
@@ -408,6 +410,384 @@ flash_attention.defvjp(
         block_q, block_k, interpret, res, g
     ),
 )
+
+
+# --------------------------------------------------------------------------
+# block-diffusion attention
+# --------------------------------------------------------------------------
+#
+# Block diffusion's masked attention over the stream ``[x_t | x_0]`` (the
+# noisy copy of L positions beside the clean one; the predicate is
+# ``models/olmo_hybrid.causal_document_attention``'s).  A query sees the keys
+# of two intervals of the stream, one in each copy (:func:`bd_query_spans`).
+# A grid step is one key/value head and one (query tile, key tile) PAIR of a
+# schedule that lists only the pairs some query of the tile reaches
+# (:func:`bd_tile_pairs`, read by the index maps from scalar memory): a dead
+# tile is neither fetched nor multiplied, and the head's G query heads read
+# its key and value tiles once.  The schedule is ordered by query tile (the
+# forward and dQ: accumulators in VMEM while the tile stays) or, for dK/dV,
+# by key tile; the steps past its count repeat its last pair, fetch nothing
+# and compute nothing.
+
+# tiles of the stream's positions, measured in the sdar cell's step on the
+# v5e (PERF.md, section 6): 256 x 512 read 150.0 ms of attention a step, 512 x 512
+# 159.6, 512 x 256 160.9, 128 x 512 165.2, 256 x 256 167.1
+BD_BLOCK_Q = 256
+BD_BLOCK_K = 512
+# the masked logit: finite, as ``models/olmo_hybrid._LOW``
+_BD_LOW = -1e30
+
+
+def bd_query_spans(segment, block_of, block_length: int) -> jax.Array:
+    """``[B, 2 L, 4]`` int32: the keys each query of the stream may see, as
+    stream positions ``[lo, hi)`` in the noisy copy (lanes 0, 1) and in the
+    clean copy (lanes 2, 3).  ``segment`` and ``block_of`` [B, L] as
+    ``causal_document_attention`` takes them (a document is one run of its
+    id).  A noisy query sees the noisy keys of its own block and the clean
+    keys of its document before its block; a clean query the clean keys of
+    its document up to its block's end, and no noisy key."""
+    b, length = segment.shape
+    pos = jnp.arange(length, dtype=jnp.int32)
+    edge = segment[:, 1:] != segment[:, :-1]
+    one = jnp.ones((b, 1), bool)
+    start = jax.lax.cummax(
+        jnp.where(jnp.concatenate([one, edge], 1), pos, 0), axis=1)
+    end = jax.lax.cummin(jnp.where(jnp.concatenate([edge, one], 1), pos + 1,
+                                   length), axis=1, reverse=True)
+    blk = start + block_of.astype(jnp.int32) * block_length
+    blk_end = jnp.minimum(blk + block_length, end)
+    none = jnp.zeros_like(blk)
+    noisy = jnp.stack([blk, blk_end, length + start, length + blk], -1)
+    clean = jnp.stack([none, none, length + start, length + blk_end], -1)
+    return jnp.concatenate([noisy, clean], axis=1)
+
+
+def _bd_blocks(length: int, block_q, block_k) -> tuple[int, int]:
+    blocks = (min(block_q or BD_BLOCK_Q, length),
+              min(block_k or BD_BLOCK_K, length))
+    if length % blocks[0] or length % blocks[1]:
+        raise ValueError(f"block diffusion: tiles {blocks} must divide the "
+                         f"sequence's {length} positions")
+    return blocks
+
+
+def _bd_max_pairs(length: int, block_q: int, block_k: int,
+                  block_length: int) -> int:
+    """The most pairs :func:`bd_tile_pairs` can list, whatever the
+    documents: the schedule's static length.  Noisy query tile ``[a, e)``:
+    clean keys below ``e``, noisy keys within ``block_length - 1`` of it;
+    clean query tile: clean keys below ``e + block_length - 1``."""
+    reach, total = block_length - 1, 0
+    for a in range(0, length, block_q):
+        e = a + block_q
+        near = -(-min(length, e + reach) // block_k)
+        total += -(-e // block_k) + near - max(0, a - reach) // block_k + near
+    return total
+
+
+def bd_tile_pairs(spans, block_q: int, block_k: int, block_length: int):
+    """The (query tile, key tile) pairs of the stream some query of the
+    query tile reaches, from :func:`bd_query_spans`: a tile's keys in each
+    copy are one interval, from the least ``lo`` to the greatest ``hi`` of
+    its rows that see a key there.  ``(qt, kt, n)``: [B, P] int32 ordered by
+    query tile, then key tile, and the count [B]; the slots past the count
+    repeat the last pair."""
+    b, t, _ = spans.shape
+    width = _bd_max_pairs(t // 2, block_q, block_k, block_length)
+    lo, hi = (spans[..., c::2].reshape(b, t // block_q, block_q, 2)
+              for c in (0, 1))
+    live = hi > lo
+    first = jnp.where(live, lo, t).min(axis=2) // block_k     # [B, tiles, 2]
+    count = jnp.maximum(
+        -(-jnp.where(live, hi, 0).max(axis=2) // block_k) - first, 0)
+    per_tile = count.sum(-1)
+    ends = jnp.cumsum(per_tile, axis=1)
+    total = ends[:, -1]
+    slot = jnp.minimum(jnp.arange(width), total[:, None] - 1)
+    qt = (ends[:, None, :] <= slot[..., None]).sum(-1, dtype=jnp.int32)
+    at = lambda a: jnp.take_along_axis(a, qt, axis=1)
+    r = slot - at(ends - per_tile)
+    noisy = at(count[..., 0])
+    kt = jnp.where(r < noisy, at(first[..., 0]) + r,
+                   at(first[..., 1]) + r - noisy)
+    return qt, kt.astype(jnp.int32), total
+
+
+def _bd_by_key_tile(qt, kt, total):
+    """:func:`bd_tile_pairs`'s pairs ordered by key tile: ``(kt, qt, n)``."""
+    slot = jnp.arange(qt.shape[1])
+    order = jnp.argsort(jnp.where(slot < total[:, None],
+                                  kt * qt.shape[1] + qt,
+                                  jnp.iinfo(jnp.int32).max), axis=1)
+    order = jnp.take_along_axis(
+        order, jnp.minimum(slot, total[:, None] - 1), axis=1)
+    pick = lambda a: jnp.take_along_axis(a, order, axis=1)
+    return pick(kt), pick(qt), total
+
+
+def bd_tile_count(segment, block_of, block_length: int, block_q=None,
+                  block_k=None) -> jax.Array:
+    """int32: the (query tile, key tile) pairs :func:`bd_attention`
+    multiplies a call over these packed sequences (all batch rows)."""
+    bq, bk = _bd_blocks(segment.shape[1], block_q, block_k)
+    spans = bd_query_spans(segment, block_of, block_length)
+    return bd_tile_pairs(spans, bq, bk, block_length)[2].sum(dtype=jnp.int32)
+
+
+def _bd_dot(a, b, contract):
+    # bf16 operands on the MXU, float32 accumulation, as the XLA path's
+    # einsums (DEFAULT stated for the quality gate)
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=jax.lax.Precision.DEFAULT)
+
+
+def _bd_step(lead_ref, n_ref):
+    """``(at, live, first, last)``: this grid step's slot in the flat
+    schedule, whether it holds a pair, and whether that pair is the first /
+    last of its run of equal ``lead`` (the tile whose outputs the run
+    accumulates)."""
+    b, p = pl.program_id(0), pl.program_id(2)
+    at = b * pl.num_programs(2) + p
+    n, lead = n_ref[b], lead_ref[at]
+    first = (p == 0) | (lead_ref[jnp.maximum(at - 1, 0)] != lead)
+    last = (p == n - 1) | (
+        lead_ref[jnp.minimum(at + 1, lead_ref.shape[0] - 1)] != lead)
+    return at, p < n, first, last
+
+
+def _bd_visible(spans_ref, kt, block_k: int, length: int):
+    """[bq, bk] bool: which keys of key tile ``kt`` (stream positions) each
+    query row of the tile sees; one mask for the tile's G query heads."""
+    noisy = kt * block_k < length
+    lo = jnp.where(noisy, spans_ref[:, 0:1], spans_ref[:, 2:3])
+    hi = jnp.where(noisy, spans_ref[:, 1:2], spans_ref[:, 3:4])
+    key = kt * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    return (key >= lo) & (key < hi)
+
+
+def _bd_probs(q, k, ok, lse, scale):
+    """The softmax's probabilities of a tile, rebuilt from the row's
+    log-sum-exp: 0 where masked."""
+    s = _bd_dot(q, k, ((1,), (1,))) * scale
+    return jnp.exp(jnp.where(ok, s, _BD_LOW) - lse)
+
+
+def _bd_fwd_kernel(qt_ref, kt_ref, n_ref, spans_ref, q_ref, k_ref, v_ref,
+                   o_ref, lse_ref, m_sc, l_sc, acc_sc, *, length, scale):
+    """Online softmax of the G query heads' rows of one query tile over the
+    schedule's key tiles, ``_online_read``'s arithmetic.  A masked logit
+    adds ``exp(LOW - m)``: 0 once the row has seen a key, and before that
+    the row's sums are scaled by ``exp(LOW - m) = 0`` when it first does
+    (every query sees itself)."""
+    at, live, first, last = _bd_step(qt_ref, n_ref)
+
+    @pl.when(live & first)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, _BD_LOW, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    @pl.when(live)
+    def _():
+        ok = _bd_visible(spans_ref, kt_ref[at], k_ref.shape[0], length)
+        k, v = k_ref[...], v_ref[...]
+        for g in range(q_ref.shape[0]):
+            s = jnp.where(ok, _bd_dot(q_ref[g], k, ((1,), (1,))) * scale,
+                          _BD_LOW)
+            m = m_sc[g]
+            m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            shrink = jnp.exp(m - m_new)
+            l_sc[g] = l_sc[g] * shrink + p.sum(axis=1, keepdims=True)
+            acc_sc[g] = acc_sc[g] * shrink + _bd_dot(
+                p.astype(v.dtype), v, ((1,), (0,)))
+            m_sc[g] = m_new
+
+    @pl.when(live & last)
+    def _():
+        for g in range(q_ref.shape[0]):
+            l = l_sc[g]
+            o_ref[g] = (acc_sc[g] / l).astype(o_ref.dtype)
+            lse = m_sc[g] + jnp.log(l)
+            # 8-sublane broadcast layout, as flash_attention's
+            lse_ref[g] = jnp.broadcast_to(lse[:, 0][None, :],
+                                          lse_ref.shape[1:])
+
+
+def _bd_dq_kernel(qt_ref, kt_ref, n_ref, spans_ref, q_ref, k_ref, v_ref,
+                  do_ref, lse_ref, delta_ref, dq_ref, acc_sc, *, length,
+                  scale):
+    """dQ of one query tile over its key tiles (FlashAttention-2)."""
+    at, live, first, last = _bd_step(qt_ref, n_ref)
+
+    @pl.when(live & first)
+    def _():
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    @pl.when(live)
+    def _():
+        ok = _bd_visible(spans_ref, kt_ref[at], k_ref.shape[0], length)
+        k, v = k_ref[...], v_ref[...]
+        for g in range(q_ref.shape[0]):
+            p = _bd_probs(q_ref[g], k, ok, lse_ref[g, 0][:, None], scale)
+            dp = _bd_dot(do_ref[g], v, ((1,), (1,)))
+            ds = (p * (dp - delta_ref[g, 0][:, None])).astype(k.dtype)
+            acc_sc[g] = acc_sc[g] + _bd_dot(ds, k, ((1,), (0,)))
+
+    @pl.when(live & last)
+    def _():
+        dq_ref[...] = (acc_sc[...] * scale).astype(dq_ref.dtype)
+
+
+def _bd_dkv_kernel(kt_ref, qt_ref, n_ref, spans_ref, q_ref, k_ref, v_ref,
+                   do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_sc, dv_sc,
+                   *, length, scale):
+    """dK / dV of one key tile over the query tiles that reach it, summed
+    over the G query heads that read it."""
+    at, live, first, last = _bd_step(kt_ref, n_ref)
+
+    @pl.when(live & first)
+    def _():
+        dk_sc[...] = jnp.zeros(dk_sc.shape, jnp.float32)
+        dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
+
+    @pl.when(live)
+    def _():
+        ok = _bd_visible(spans_ref, kt_ref[at], k_ref.shape[0], length)
+        k, v = k_ref[...], v_ref[...]
+        dk, dv = dk_sc[...], dv_sc[...]
+        for g in range(q_ref.shape[0]):
+            q, do = q_ref[g], do_ref[g]
+            p = _bd_probs(q, k, ok, lse_ref[g, 0][:, None], scale)
+            dv = dv + _bd_dot(p.astype(do.dtype), do, ((0,), (0,)))
+            dp = _bd_dot(do, v, ((1,), (1,)))
+            ds = (p * (dp - delta_ref[g, 0][:, None])).astype(q.dtype)
+            dk = dk + _bd_dot(ds, q, ((0,), (0,)))
+        dk_sc[...] = dk
+        dv_sc[...] = dv
+
+    @pl.when(live & last)
+    def _():
+        dk_ref[...] = (dk_sc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
+
+
+def _bd_call(kernel, name, pairs, by_key, shape, in_specs_of, operands,
+             out_specs_of, out_shape, scratch, block_q, block_k, interpret):
+    """One kernel over the schedule ``pairs`` (``(lead, other, n)``; the
+    lead is the key tile where ``by_key``) for queries of ``shape`` [B,
+    H_kv, G, 2 L, dh]: the operands' BlockSpecs read their tiles off it in
+    scalar memory."""
+    b, hkv, groups, t, dh = shape
+    width = pairs[0].shape[1]
+    qi, ki = (1, 0) if by_key else (0, 1)
+
+    def block(block_shape, index):
+        return pl.BlockSpec(block_shape, lambda bi, hi, p, *refs: index(
+            bi, hi, refs[qi][bi * width + p], refs[ki][bi * width + p]))
+
+    specs = {
+        "spans": block((None, block_q, 4), lambda bi, hi, q, k: (bi, q, 0)),
+        "rows": block((None, None, groups, block_q, dh),
+                      lambda bi, hi, q, k: (bi, hi, 0, q, 0)),
+        "stats": block((None, None, groups, 8, block_q),
+                       lambda bi, hi, q, k: (bi, hi, 0, 0, q)),
+        "keys": block((None, None, block_k, dh),
+                      lambda bi, hi, q, k: (bi, hi, k, 0)),
+    }
+    return pl.pallas_call(
+        functools.partial(kernel, length=t // 2, scale=1.0 / dh ** 0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b, hkv, width),
+            in_specs=[specs[s] for s in in_specs_of],
+            out_specs=[specs[s] for s in out_specs_of],
+            scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(pairs[0].reshape(-1), pairs[1].reshape(-1), pairs[2], *operands)
+
+
+def _bd_forward(q, k, v, spans, pairs, block_q, block_k, interpret):
+    b, hkv, groups, t, dh = q.shape
+    f32 = jnp.float32
+    return _bd_call(
+        _bd_fwd_kernel, "bd_attn_fwd", pairs, False, q.shape,
+        ("spans", "rows", "keys", "keys"), (spans, q, k, v),
+        ("rows", "stats"),
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct((b, hkv, groups, 8, t), f32)],
+        [pltpu.VMEM((groups, block_q, 1), f32),
+         pltpu.VMEM((groups, block_q, 1), f32),
+         pltpu.VMEM((groups, block_q, dh), f32)],
+        block_q, block_k, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _bd_core(q, k, v, spans, pairs, key_pairs, block_q, block_k, interpret):
+    return _bd_forward(q, k, v, spans, pairs, block_q, block_k, interpret)[0]
+
+
+def _bd_core_fwd(q, k, v, spans, pairs, key_pairs, block_q, block_k,
+                 interpret):
+    o, lse = _bd_forward(q, k, v, spans, pairs, block_q, block_k, interpret)
+    return o, (q, k, v, spans, pairs, key_pairs, o, lse)
+
+
+def _bd_core_bwd(block_q, block_k, interpret, res, g):
+    """FlashAttention-2: the residuals are the output and the row's
+    log-sum-exp; dQ walks the schedule by query tile, dK / dV by key tile."""
+    q, k, v, spans, pairs, key_pairs, o, lse = res
+    groups, dh = q.shape[2], q.shape[4]
+    delta = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+    delta = jnp.broadcast_to(delta[..., None, :], lse.shape)
+    operands = (spans, q, k, v, g, lse, delta)
+    ins = ("spans", "rows", "keys", "keys", "rows", "stats", "stats")
+    dq = _bd_call(
+        _bd_dq_kernel, "bd_attn_bwd_dq", pairs, False, q.shape, ins, operands,
+        ("rows",), [jax.ShapeDtypeStruct(q.shape, q.dtype)],
+        [pltpu.VMEM((groups, block_q, dh), jnp.float32)],
+        block_q, block_k, interpret)[0]
+    dk, dv = _bd_call(
+        _bd_dkv_kernel, "bd_attn_bwd_dkv", key_pairs, True, q.shape, ins,
+        operands, ("keys", "keys"),
+        [jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        [pltpu.VMEM((block_k, dh), jnp.float32),
+         pltpu.VMEM((block_k, dh), jnp.float32)],
+        block_q, block_k, interpret)
+    return dq, dk, dv, None, None, None
+
+
+_bd_core.defvjp(_bd_core_fwd, _bd_core_bwd)
+
+
+def bd_attention(q, k, v, segment, block_of, *, block_length: int,
+                 block_q: int | None = None, block_k: int | None = None,
+                 interpret: bool = False) -> jax.Array:
+    """Block diffusion's attention as ``causal_document_attention`` computes
+    it (``q`` [B, 2 L, H, dh] of the stream, ``k``, ``v`` [B, 2 L, H_kv,
+    dh], query head ``i`` on key/value head ``i // (H / H_kv)``; ``segment``
+    and ``block_of`` [B, L]) as one Pallas flash-attention kernel with a
+    FlashAttention-2 backward.  Operands stay in their dtype on the MXU
+    (bfloat16 at ``Precision.DEFAULT``), logits, maximum, sum and
+    accumulators are float32 and ``p`` is cast to the values' dtype before
+    ``p v``, as the XLA path does; every pair the mask admits is computed.
+    Tiles of ``block_q`` queries and ``block_k`` keys (default
+    ``BD_BLOCK_Q`` / ``BD_BLOCK_K``, clipped to ``L``) must divide ``L``."""
+    b, t, h, dh = q.shape
+    hkv = k.shape[2]
+    block_q, block_k = _bd_blocks(t // 2, block_q, block_k)
+    spans = bd_query_spans(segment, block_of, block_length)
+    pairs = bd_tile_pairs(spans, block_q, block_k, block_length)
+    heads = lambda a: jnp.swapaxes(a, 1, 2)
+    o = _bd_core(heads(q).reshape(b, hkv, h // hkv, t, dh), heads(k),
+                 heads(v), spans, pairs, _bd_by_key_tile(*pairs), block_q,
+                 block_k, interpret)
+    return heads(o.reshape(b, h, t, dh))
 
 
 # --------------------------------------------------------------------------

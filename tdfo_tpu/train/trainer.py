@@ -1072,13 +1072,15 @@ class Trainer:
         block diffusion's over masked positions).  The family's module
         supplies the configuration (``LmConfig``, from the ``[lm]`` keys of
         its fields' names), the initialiser (``init_params``),
-        ``forward_loss``, the counters its step returns beside the loss
-        (``STEP_COUNTERS``: tallied into the epoch record where the loss is
-        fetched), the leaves that are buffers (``BUFFERS``: no decay) and,
-        where its loss needs more than the packed ids, ``prepare_batch``:
-        run inside the step on the step's key before the lookup, it may
-        replace the ids (block diffusion's noisy copy beside the clean one)
-        and add arrays, which reach ``forward_loss`` by name.  The dense
+        ``forward_loss`` (given the devices' ``platform``, by which a layer
+        may pick a kernel: ``core/mesh.pallas_impl``), the counters its
+        step returns beside the loss (``STEP_COUNTERS``: tallied into the
+        epoch record where the loss is fetched), the leaves that are
+        buffers (``BUFFERS``: no decay) and, where its loss needs more than
+        the packed ids, ``prepare_batch``: run inside the step on the
+        step's key before the lookup, it may replace the ids (block
+        diffusion's noisy copy beside the clean one) and add arrays, which
+        reach ``forward_loss`` by name.  The dense
         state is many GiB, so unlike the other builders this one DONATES
         the step's state (config requires ``nonfinite_tolerance = 0``: the
         guard's second copy has no room), rematerialises by layer (the
@@ -1139,7 +1141,8 @@ class Trainer:
                 cfg.sparse_optimizer, lr=cfg.learning_rate, weight_decay=0.0,
                 b2=_LM_ADAM_B2),
         ), self.mesh)
-        dtype = compute_dtype(cfg.mixed_precision, mesh_platform(self.mesh))
+        platform = mesh_platform(self.mesh)
+        dtype = compute_dtype(cfg.mixed_precision, platform)
         model_cfg = self.model_cfg
         prepare = getattr(family, "prepare_batch", None)
         transform = None if prepare is None else (
@@ -1150,7 +1153,7 @@ class Trainer:
                      if k not in ("token", "segment")}
             return family.forward_loss(
                 dense_params, embs["token"], batch["token"], batch["segment"],
-                model_cfg, dtype=dtype, **added)
+                model_cfg, dtype=dtype, platform=platform, **added)
 
         # with counters the forward returns (loss, counters) and the step
         # (state, (loss, counters)): _train_epoch takes them apart

@@ -31,6 +31,7 @@ from jax.sharding import SingleDeviceSharding
 from tdfo_tpu.core.mesh import PALLAS_CHOICES
 from tdfo_tpu.ops import sparse
 from tdfo_tpu.ops.pallas_kernels import (
+    bd_attention,
     fat_gather_rows,
     flash_attention,
     line_layout,
@@ -163,5 +164,26 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, shape, dtype):
     assert text.count("tpu_custom_call") >= 3  # fwd, dq, dk/dv
     for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         # op_name="jit(loss)/transpose(jvp(flash_bwd_dq))/pallas_call"
+        assert re.search(rf'op_name="[^"]*\b{kernel}\b[^"]*/pallas_call"',
+                         text), kernel
+
+
+def test_bd_attention_fwd_bwd_compiles(one_chip):
+    """Block diffusion's kernel at the sdar cell's shapes: one sequence of
+    8,192 tokens as a 16,384-position stream, 32 query heads on 4
+    key/value heads of 128, blocks of 4, bfloat16; the forward and the two
+    backward kernels, their tiles read off a schedule in scalar memory."""
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    q = s((1, 16384, 32, 128), jnp.bfloat16)
+    kv = s((1, 16384, 4, 128), jnp.bfloat16)
+    seg = s((1, 8192), jnp.int32)
+
+    def loss(q, k, v, segment, block_of):
+        return bd_attention(q, k, v, segment, block_of, block_length=4
+                            ).astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, seg, seg)
+    assert text.count("tpu_custom_call") >= 3
+    for kernel in ("bd_attn_fwd", "bd_attn_bwd_dq", "bd_attn_bwd_dkv"):
         assert re.search(rf'op_name="[^"]*\b{kernel}\b[^"]*/pallas_call"',
                          text), kernel

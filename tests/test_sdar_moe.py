@@ -24,6 +24,7 @@ from tdfo_tpu.core.config import read_configs  # noqa: E402
 from tdfo_tpu.models import olmo_hybrid  # noqa: E402
 from tdfo_tpu.models import sdar_moe as M  # noqa: E402
 from tdfo_tpu.ops import moe  # noqa: E402
+from tdfo_tpu.ops import pallas_kernels as PK  # noqa: E402
 
 LM = dict(vocab_size=50, hidden_size=64, num_hidden_layers=2,
           num_attention_heads=4, num_key_value_heads=2, head_dim=16,
@@ -96,22 +97,29 @@ def dense_attention(q, k, v, visible):
 @pytest.mark.parametrize("kind,block,length,block_length", [
     ("diffusion", 32, 64, 4), ("diffusion", 1024, 64, 4),
     ("diffusion", 16, 256, 4), ("diffusion", 32, 512, 67),
-    ("diffusion", 16, 64, 1), ("causal", 16, 64, 1)])
+    ("diffusion", 16, 64, 1), ("causal", 16, 64, 1),
+    ("kernel", (64, 64), 64, 4), ("kernel", (16, 32), 256, 4),
+    ("kernel", (32, 16), 512, 67), ("kernel", (16, 16), 64, 1)])
 def test_the_attention_is_a_dense_masked_softmax(kind, block, length,
                                                  block_length):
     """The blockwise attention, values and gradients, against one masked
     softmax whose mask is the rule written out (``reference.visible``):
     one query tile, two, tiles whose fixed key windows are clipped at
     either end of a half (256 positions in tiles of 16), blocks that
-    reach past a whole tile (67), blocks of one token."""
-    rng = np.random.default_rng(block + length)
+    reach past a whole tile (67), blocks of one token.  ``kernel``: the
+    chip's Pallas kernel (``ops/pallas_kernels.bd_attention``, forward and
+    both backward kernels) under the interpreter, tiles ``(block_q,
+    block_k)``: one query tile a copy, many, key tiles wider and narrower
+    than the query tiles, documents across tile ends and tiles whose first
+    document began tiles earlier (256 positions in three documents)."""
+    rng = np.random.default_rng(np.prod(block) + length)
     _, segment = packed(rng, t=length)
     b, h, hkv, dh = 2, 4, 2, 8
-    n = 2 * length if kind == "diffusion" else length
+    n = 2 * length if kind != "causal" else length
     f = lambda *s: rng.normal(size=s).astype(np.float32)
     q, k, v = f(b, n, h, dh), f(b, n, hkv, dh), f(b, n, hkv, dh)
     i, j = np.arange(n)[:, None], np.arange(n)[None, :]
-    if kind == "diffusion":
+    if kind != "causal":
         seg = np.concatenate([segment, segment], 1)
         blk = np.asarray(M.document_blocks(segment, block_length)[1])
         noisy = np.arange(n) < length
@@ -124,6 +132,11 @@ def test_the_attention_is_a_dense_masked_softmax(kind, block, length,
     mask = np.asarray(rule) & (seg[:, None, :] == seg[:, :, None])
     blockwise = lambda q, k, v: olmo_hybrid.causal_document_attention(
         q, k, v, jnp.asarray(segment), query_block=block, **kw)
+    if kind == "kernel":
+        blockwise = lambda q, k, v: PK.bd_attention(
+            q, k, v, jnp.asarray(segment), block_of=kw["block_of"],
+            block_length=block_length, block_q=block[0], block_k=block[1],
+            interpret=True)
     plain = lambda q, k, v: dense_attention(
         q, jnp.repeat(k, h // hkv, 2), jnp.repeat(v, h // hkv, 2), mask)
     w = f(b, n, h, dh)
@@ -133,6 +146,93 @@ def test_the_attention_is_a_dense_masked_softmax(kind, block, length,
     np.testing.assert_allclose(oa, ob, atol=2e-5)
     for x, y in zip(ga, gb):
         np.testing.assert_allclose(x, y, atol=2e-5)
+
+
+def host_tile_pairs(segment, block_length, block_q, block_k):
+    """The kernel's (query tile, key tile) pairs of one packed row, written
+    out on the host: per query its key intervals in each copy, per tile and
+    copy the hull of the rows that see a key there."""
+    length = len(segment)
+    start, end = np.zeros(length, int), np.zeros(length, int)
+    for s in range(length):
+        start[s] = s if s == 0 or segment[s] != segment[s - 1] else start[s - 1]
+    for s in reversed(range(length)):
+        end[s] = s + 1 if s == length - 1 or segment[s] != segment[s + 1] \
+            else end[s + 1]
+    blk = start + (np.arange(length) - start) // block_length * block_length
+    blk_end = np.minimum(blk + block_length, end)
+    pairs = set()
+    for qt in range(2 * length // block_q):
+        rows = np.arange(qt * block_q, (qt + 1) * block_q)
+        if rows[0] < length:     # noisy: own block noisy, earlier blocks clean
+            spans = [(blk[rows], blk_end[rows]),
+                     (length + start[rows], length + blk[rows])]
+        else:
+            rows = rows - length
+            spans = [(length + start[rows], length + blk_end[rows])]
+        for lo, hi in spans:
+            live = hi > lo
+            if live.any():
+                pairs |= {(qt, kt) for kt in range(
+                    lo[live].min() // block_k,
+                    -(-hi[live].max() // block_k))}
+    return pairs
+
+
+def test_the_tile_schedule_covers_every_admitted_pair(monkeypatch):
+    """Over random packings (documents of 1 token up, blocks of 1 to 67,
+    tiles wider and narrower than each other): every (query, key) pair the
+    rule admits lies in a listed tile pair, the query spans ARE the rule,
+    the pairs are the host's recount in both orders, and the step's
+    ``bd_attn_tiles`` is the layers times that recount."""
+    rng = np.random.default_rng(11)
+    schedule = jax.jit(lambda seg, blk, bl, bq, bk: (
+        PK.bd_query_spans(seg, blk, bl),
+        *PK.bd_tile_pairs(PK.bd_query_spans(seg, blk, bl), bq, bk, bl)),
+        static_argnums=(2, 3, 4))
+    for length, bl, bq, bk in [(64, 4, 8, 8), (64, 1, 16, 8), (128, 3, 8, 32),
+                               (128, 67, 32, 16), (64, 4, 64, 64)]:
+        cuts = np.sort(rng.choice(np.arange(1, length), rng.integers(0, 8),
+                                  replace=False))
+        segment = np.zeros((2, length), np.int32)
+        for c in cuts:
+            segment[0, c:] += 1
+        segment[1] = np.repeat(np.arange(length // 2), 2)   # 2-token documents
+        blk = np.asarray(M.document_blocks(segment, bl)[1])
+        spans, qt, kt, n = (np.asarray(a) for a in schedule(
+            jnp.asarray(segment), jnp.asarray(blk), bl, bq, bk))
+        key_order = [np.asarray(a) for a in PK._bd_by_key_tile(
+            jnp.asarray(qt), jnp.asarray(kt), jnp.asarray(n))]
+        n2 = 2 * length
+        i, j = np.arange(n2)[:, None], np.arange(n2)[None, :]
+        noisy = np.arange(n2) < length
+        for row in range(2):
+            seg, bb = np.tile(segment[row], 2), np.tile(blk[row], 2)
+            rule = np.asarray(R.visible(i, j, noisy[i], noisy[j], bb[i], bb[j],
+                                        None)) & (seg[i] == seg[j])
+            lo = np.where(noisy[j], spans[row, :, 0:1], spans[row, :, 2:3])
+            hi = np.where(noisy[j], spans[row, :, 1:2], spans[row, :, 3:4])
+            np.testing.assert_array_equal((j >= lo) & (j < hi), rule)
+            listed = set(zip(qt[row, :n[row]].tolist(), kt[row, :n[row]].tolist()))
+            assert len(listed) == n[row]
+            assert listed == host_tile_pairs(segment[row], bl, bq, bk)
+            qi, kj = np.nonzero(rule)
+            assert set(zip((qi // bq).tolist(), (kj // bk).tolist())) <= listed
+            # the steps past the count repeat the last pair
+            assert (qt[row, n[row]:] == qt[row, n[row] - 1]).all()
+            by_key = list(zip(key_order[0][row, :n[row]].tolist(),
+                              key_order[1][row, :n[row]].tolist()))
+            assert by_key == sorted((k, q) for q, k in listed)
+    # the step's counter, at tiles of 16 over 64 positions
+    monkeypatch.setattr(PK, "BD_BLOCK_Q", 16)
+    monkeypatch.setattr(PK, "BD_BLOCK_K", 16)
+    cfg = model_cfg()
+    token, segment = packed(np.random.default_rng(12), t=64)
+    _, counters = jax.jit(program_loss(cfg, token, segment, R.step_key(0, 1)))(
+        seeded(cfg), jnp.zeros((50, 64), jnp.float32))
+    want = sum(len(host_tile_pairs(row, cfg.block_length, 16, 16))
+               for row in segment)
+    assert int(counters["bd_attn_tiles"]) == cfg.num_hidden_layers * want
 
 
 def test_rope_sees_only_position_differences_within_a_document():
