@@ -56,12 +56,12 @@ from tdfo_tpu.models.olmo_hybrid import (
     causal_document_attention, cotangent_once, init_tree, made_once, proj,
     rms_norm)
 from tdfo_tpu.ops import moe
-from tdfo_tpu.ops.pallas_kernels import bd_tile_count
+from tdfo_tpu.ops.pallas_kernels import BD_RESIDUALS, bd_tile_count
 
 __all__ = ["LmConfig", "init_params", "param_shapes", "prepare_batch",
            "forward_loss", "backbone", "attention_mixer", "expert_layer",
            "block_noise", "document_blocks", "rope",
-           "STEP_COUNTERS", "BUFFERS"]
+           "STEP_COUNTERS", "BUFFERS", "LAYER_POLICY"]
 
 # what the step returns beside the loss: the noisy positions that were
 # masked, the attention kernel's (query tile, key tile) pairs summed over the
@@ -265,17 +265,25 @@ def _block(p, x, segment, block_of, cfg: LmConfig, platform=None):
     return h + out, counters
 
 
+# what a layer keeps for the backward pass: its input, its projections'
+# outputs (as ``models/olmo_hybrid.backbone``) and, on the chip, the
+# attention kernel's output and log-sum-exp, so that the forward kernel runs
+# once a layer and not again to remake them (off the chip nothing has those
+# names)
+LAYER_POLICY = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    jax.checkpoint_policies.save_only_these_names(*BD_RESIDUALS))
+
+
 def backbone(params, x, segment, block_of, cfg: LmConfig, *,
              platform: str | None = None):
     """``x`` [B, 2T, d] through every layer, each rematerialised in the
-    backward pass (it keeps its input and its projections' outputs, as
-    ``models/olmo_hybrid.backbone``); ``(x, counters)``."""
-    keep = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    backward pass under :data:`LAYER_POLICY`; ``(x, counters)``."""
     total = dict.fromkeys(_EXPERT_COUNTERS, jnp.zeros((), jnp.int32))
     for i in range(cfg.num_hidden_layers):
         layer = jax.checkpoint(
             lambda p, x: _block(p, x, segment, block_of, cfg, platform),
-            policy=keep)
+            policy=LAYER_POLICY)
         x, c = layer(params[f"layer_{i}"], x)
         total = {k: (jnp.maximum(total[k], c[k]) if k == "moe_load_max"
                      else total[k] + c[k]) for k in total}

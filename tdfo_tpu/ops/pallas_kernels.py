@@ -44,6 +44,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -54,6 +55,7 @@ __all__ = [
     "flash_attention",
     "bd_attention",
     "bd_tile_count",
+    "BD_RESIDUALS",
     "LineLayout",
     "line_layout",
     "fat_line_update",
@@ -436,6 +438,11 @@ BD_BLOCK_Q = 256
 BD_BLOCK_K = 512
 # the masked logit: finite, as ``models/olmo_hybrid._LOW``
 _BD_LOW = -1e30
+# the names of the forward's output and log-sum-exp among the backward
+# pass's residuals (``jax.ad_checkpoint.checkpoint_name``): a
+# ``jax.checkpoint`` policy that saves these names keeps them, and the
+# backward pass does not run the forward kernel again to remake them
+BD_RESIDUALS = ("bd_attn_out", "bd_attn_lse")
 
 
 def bd_query_spans(segment, block_of, block_length: int) -> jax.Array:
@@ -734,6 +741,8 @@ def _bd_core(q, k, v, spans, pairs, key_pairs, block_q, block_k, interpret):
 def _bd_core_fwd(q, k, v, spans, pairs, key_pairs, block_q, block_k,
                  interpret):
     o, lse = _bd_forward(q, k, v, spans, pairs, block_q, block_k, interpret)
+    o = checkpoint_name(o, BD_RESIDUALS[0])
+    lse = checkpoint_name(lse, BD_RESIDUALS[1])
     return o, (q, k, v, spans, pairs, key_pairs, o, lse)
 
 

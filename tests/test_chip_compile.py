@@ -29,6 +29,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from tdfo_tpu.core.mesh import PALLAS_CHOICES
+from tdfo_tpu.models.sdar_moe import LAYER_POLICY
 from tdfo_tpu.ops import sparse
 from tdfo_tpu.ops.pallas_kernels import (
     bd_attention,
@@ -168,11 +169,16 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, shape, dtype):
                          text), kernel
 
 
-def test_bd_attention_fwd_bwd_compiles(one_chip):
+@pytest.mark.parametrize("kept", [False, True])
+def test_bd_attention_fwd_bwd_compiles(one_chip, kept):
     """Block diffusion's kernel at the sdar cell's shapes: one sequence of
     8,192 tokens as a 16,384-position stream, 32 query heads on 4
     key/value heads of 128, blocks of 4, bfloat16; the forward and the two
-    backward kernels, their tiles read off a schedule in scalar memory."""
+    backward kernels, their tiles read off a schedule in scalar memory.
+    ``kept``: rematerialised under the decoder layers' policy
+    (``sdar_moe.LAYER_POLICY``), which keeps the forward's output and
+    log-sum-exp: the value and gradient hold ONE forward kernel, as without
+    the rematerialisation, not a second one in the backward pass."""
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     q = s((1, 16384, 32, 128), jnp.bfloat16)
     kv = s((1, 16384, 4, 128), jnp.bfloat16)
@@ -182,8 +188,12 @@ def test_bd_attention_fwd_bwd_compiles(one_chip):
         return bd_attention(q, k, v, segment, block_of, block_length=4
                             ).astype(jnp.float32).sum()
 
-    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, seg, seg)
-    assert text.count("tpu_custom_call") >= 3
+    if kept:
+        loss = jax.checkpoint(loss, policy=LAYER_POLICY)
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv,
+                    seg, seg)
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
     for kernel in ("bd_attn_fwd", "bd_attn_bwd_dq", "bd_attn_bwd_dkv"):
-        assert re.search(rf'op_name="[^"]*\b{kernel}\b[^"]*/pallas_call"',
-                         text), kernel
+        pattern = rf'op_name="[^"]*\b{kernel}\b[^"]*/pallas_call"'
+        assert len([c for c in calls if re.search(pattern, c)]) == 1, kernel

@@ -1,10 +1,12 @@
 """``model = "sdar_moe"``: block diffusion's attention against a dense
-masked softmax, the noise from (seed, step), rotary positions, the model and
+masked softmax, its kernel's forward once a layer in the chip's step, the
+noise from (seed, step), rotary positions, the model and
 ``Trainer``'s path against the plain reference
 (``benchmarks/reference/sdar_moe.py``), the expert shares tied to the uncut
 layer, and the shared pieces held to their forms before this family came:
 the causal attention and the sigmoid / relu-squared expert layer."""
 
+import collections
 import dataclasses
 import functools
 import json
@@ -25,6 +27,7 @@ from tdfo_tpu.models import olmo_hybrid  # noqa: E402
 from tdfo_tpu.models import sdar_moe as M  # noqa: E402
 from tdfo_tpu.ops import moe  # noqa: E402
 from tdfo_tpu.ops import pallas_kernels as PK  # noqa: E402
+from tests.test_olmo_hybrid import equations  # noqa: E402
 
 LM = dict(vocab_size=50, hidden_size=64, num_hidden_layers=2,
           num_attention_heads=4, num_key_value_heads=2, head_dim=16,
@@ -99,7 +102,8 @@ def dense_attention(q, k, v, visible):
     ("diffusion", 16, 256, 4), ("diffusion", 32, 512, 67),
     ("diffusion", 16, 64, 1), ("causal", 16, 64, 1),
     ("kernel", (64, 64), 64, 4), ("kernel", (16, 32), 256, 4),
-    ("kernel", (32, 16), 512, 67), ("kernel", (16, 16), 64, 1)])
+    ("kernel", (32, 16), 512, 67), ("kernel", (16, 16), 64, 1),
+    ("kernel_kept", (16, 32), 64, 4)])
 def test_the_attention_is_a_dense_masked_softmax(kind, block, length,
                                                  block_length):
     """The blockwise attention, values and gradients, against one masked
@@ -111,7 +115,11 @@ def test_the_attention_is_a_dense_masked_softmax(kind, block, length,
     both backward kernels) under the interpreter, tiles ``(block_q,
     block_k)``: one query tile a copy, many, key tiles wider and narrower
     than the query tiles, documents across tile ends and tiles whose first
-    document began tiles earlier (256 positions in three documents)."""
+    document began tiles earlier (256 positions in three documents).
+    ``kernel_kept``: the same kernel under the layers' checkpoint policy
+    (``sdar_moe.LAYER_POLICY``), which keeps its output and log-sum-exp
+    for the backward pass: values and gradients are the unwrapped
+    kernel's, bit for bit, and the dense softmax's as every case's."""
     rng = np.random.default_rng(np.prod(block) + length)
     _, segment = packed(rng, t=length)
     b, h, hkv, dh = 2, 4, 2, 8
@@ -132,7 +140,7 @@ def test_the_attention_is_a_dense_masked_softmax(kind, block, length,
     mask = np.asarray(rule) & (seg[:, None, :] == seg[:, :, None])
     blockwise = lambda q, k, v: olmo_hybrid.causal_document_attention(
         q, k, v, jnp.asarray(segment), query_block=block, **kw)
-    if kind == "kernel":
+    if kind.startswith("kernel"):
         blockwise = lambda q, k, v: PK.bd_attention(
             q, k, v, jnp.asarray(segment), block_of=kw["block_of"],
             block_length=block_length, block_q=block[0], block_k=block[1],
@@ -142,10 +150,51 @@ def test_the_attention_is_a_dense_masked_softmax(kind, block, length,
     w = f(b, n, h, dh)
     both = lambda fn: jax.jit(lambda *a: (fn(*a), jax.grad(
         lambda *x: (fn(*x) * w).sum(), (0, 1, 2))(*a)))(q, k, v)
+    if kind == "kernel_kept":
+        # the forward and the backward pass as programs of their own: in
+        # one program XLA may fuse the backward's row sum ``delta`` with
+        # the cotangent's producer and order it otherwise (1 ulp)
+        def apart(fn):
+            o, back = jax.jit(lambda *a: jax.vjp(fn, *a))(q, k, v)
+            return o, jax.jit(lambda back, w: back(w))(back, w)
+
+        kept = jax.checkpoint(blockwise, policy=M.LAYER_POLICY)
+        for x, y in zip(jax.tree.leaves(apart(kept)),
+                        jax.tree.leaves(apart(blockwise))):
+            np.testing.assert_array_equal(x, y)
+        blockwise = kept
     (oa, ga), (ob, gb) = both(blockwise), both(plain)
     np.testing.assert_allclose(oa, ob, atol=2e-5)
     for x, y in zip(ga, gb):
         np.testing.assert_allclose(x, y, atol=2e-5)
+
+
+def test_the_backward_pass_runs_the_forward_kernel_once_a_layer():
+    """On the chip each layer keeps the attention kernel's output and
+    log-sum-exp across its rematerialisation (``sdar_moe.LAYER_POLICY``):
+    the value and gradient of the backbone run ``bd_attn_fwd`` once a
+    layer, not a second time in the rematerialised forward, beside one
+    ``bd_attn_bwd_dq`` and one ``bd_attn_bwd_dkv``.  Traced with
+    ``platform="tpu"``; nothing is compiled or run."""
+    cfg = model_cfg()
+    rng = np.random.default_rng(4)
+    _, segment = packed(rng, b=1, t=64)
+    segment = jnp.asarray(segment)
+    _, block_of = M.document_blocks(segment, cfg.block_length)
+    x = jnp.asarray(rng.normal(size=(1, 128, cfg.hidden_size)), jnp.bfloat16)
+
+    def loss(p, x):
+        y, _ = M.backbone(p, x, segment, block_of, cfg, platform="tpu")
+        return y.astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(
+        M.init_params(jax.random.key(4), cfg), x)
+    kernels = collections.Counter(
+        eqn.params["name"] for eqn in equations(jaxpr.jaxpr)
+        if eqn.primitive.name == "pallas_call")
+    layers = cfg.num_hidden_layers
+    assert kernels == {"bd_attn_fwd": layers, "bd_attn_bwd_dq": layers,
+                       "bd_attn_bwd_dkv": layers}, kernels
 
 
 def host_tile_pairs(segment, block_length, block_q, block_k):
