@@ -376,7 +376,8 @@ class LmSpec:
     (``model`` names the family: ``"olmo_hybrid"``,
     ``tdfo_tpu/models/olmo_hybrid.py``; ``"nemotron_h"``,
     ``tdfo_tpu/models/nemotron_h.py``; ``"sdar_moe"``,
-    ``tdfo_tpu/models/sdar_moe.py``).  Key names are the published
+    ``tdfo_tpu/models/sdar_moe.py``; ``"deepseek_v3"``,
+    ``tdfo_tpu/models/deepseek_v3.py``).  Key names are the published
     ``config.json``'s (huggingface ``model_type`` of the same name) where it
     has one; a head, group or expert count there is the PUBLISHED count (it
     sets the head and group sizes and the router's width), and the
@@ -434,6 +435,19 @@ class LmSpec:
     # U[noise_eps, 1] a block); the mask token is the table's last row
     block_length: int = 0
     noise_eps: float = 1e-3
+    # ---- deepseek_v3 (and the keys above of the same meaning: heads,
+    # intermediate_size of the leading dense layers, n_routed_experts,
+    # num_experts_per_tok, moe_intermediate_size, routed_scaling_factor,
+    # norm_topk_prob, rope_theta, experts_held, first_expert_held)
+    kv_lora_rank: int = 0          # the key/value latent's width
+    # the query's low-rank path: null in the supported configurations, and
+    # refused otherwise (the family projects queries with one W_q)
+    q_lora_rank: int | None = None
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0      # the one rotary key all heads share
+    v_head_dim: int = 0
+    first_k_dense_replace: int = 0  # leading layers with a dense MLP
+    n_shared_experts: int = 0      # one SwiGLU of n x moe_intermediate_size
 
 
 # the decoder families (``tdfo_tpu/models/<name>.py``) and the [lm] keys
@@ -455,6 +469,12 @@ LM_FAMILIES: dict[str, tuple[str, ...]] = {
         "num_attention_heads", "num_key_value_heads", "head_dim",
         "num_experts", "num_experts_per_tok", "moe_intermediate_size",
         "rope_theta", "block_length"),
+    "deepseek_v3": (
+        "vocab_size", "hidden_size", "num_hidden_layers",
+        "num_attention_heads", "kv_lora_rank", "qk_nope_head_dim",
+        "qk_rope_head_dim", "v_head_dim", "intermediate_size",
+        "n_routed_experts", "num_experts_per_tok", "moe_intermediate_size",
+        "rope_theta"),
 }
 
 
@@ -645,8 +665,8 @@ class Config:
     seed: int = 42
 
     # --- model (L2) ---
-    # "twotower" | "bert4rec" | "dlrm" | a causal decoder: "olmo_hybrid" |
-    # "nemotron_h"
+    # "twotower" | "bert4rec" | "dlrm" | a decoder (LM_FAMILIES):
+    # "olmo_hybrid" | "nemotron_h" | "sdar_moe" | "deepseek_v3"
     model: str = "twotower"
     embed_dim: int = 16
     # custom CTR feature schema (dlrm only): categorical column names (one
@@ -828,6 +848,12 @@ class Config:
         for key in LM_FAMILIES[model]:
             if getattr(self.lm, key) <= 0:
                 raise ValueError(f"model = \"{model}\" needs [lm] {key} > 0")
+        if self.lm.q_lora_rank is not None:
+            raise ValueError(
+                f"[lm] q_lora_rank = {self.lm.q_lora_rank}: the query's "
+                "low-rank path is not implemented (deepseek_v3 projects "
+                "queries with one W_q, as q_lora_rank null configures); "
+                "leave q_lora_rank unset")
         if self.nonfinite_tolerance != 0:
             # the step donates its state (a dense state of many GiB cannot
             # live twice), so there is nothing for the guard to roll back to

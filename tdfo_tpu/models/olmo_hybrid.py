@@ -241,9 +241,9 @@ def proj(x, w):
 
 def _online_read(carry, q_b, k_i, v_i, visible, scale):
     """One read of the online softmax: the queries ``q_b`` [B, Q, H, dh]
-    against the keys ``k_i`` / values ``v_i`` [B, K, H, dh] where
-    ``visible()`` ([B, 1, Q, K] bool) lets them, folded into the carry
-    ``(o, m, l)``: the unnormalised output [B, H, Q, dh], the row maximum
+    against the keys ``k_i`` [B, K, H, dh] / values ``v_i`` [B, K, H, dv]
+    where ``visible()`` ([B, 1, Q, K] bool) lets them, folded into the carry
+    ``(o, m, l)``: the unnormalised output [B, H, Q, dv], the row maximum
     and the row sum [B, H, Q], all float32."""
     o, m, l = carry
     logits = jnp.einsum("bqhd,bkhd->bhqk", q_b, k_i,
@@ -260,11 +260,15 @@ def _online_read(carry, q_b, k_i, v_i, visible, scale):
     return o, m_new, l
 
 
-def _fresh(q_b):
-    """The online softmax's empty carry, its zeros made FROM the queries:
-    under a shard_map they vary over the mesh axis as the reads do."""
+def _fresh(q_b, dv: int):
+    """The online softmax's empty carry for values ``dv`` wide, its zeros
+    made FROM the queries: under a shard_map they vary over the mesh axis
+    as the reads do."""
     o0 = jnp.swapaxes(q_b, 1, 2).astype(jnp.float32) * 0.0
-    return o0, o0[..., 0] + _LOW, o0[..., 0]
+    m0, l0 = o0[..., 0] + _LOW, o0[..., 0]
+    if dv != o0.shape[-1]:
+        o0 = jnp.broadcast_to(l0[..., None], (*l0.shape, dv))
+    return o0, m0, l0
 
 
 def _finish(carry, dtype):
@@ -293,8 +297,12 @@ def causal_document_attention(q, k, v, segment, *, block_of=None,
                       of earlier ones, never a noisy ``j``.  Every position
                       clean, with blocks of one token, is the causal mask.
 
-    ``k``, ``v`` [B, T, H_kv, dh] with ``H_kv`` dividing ``H`` (query head
-    ``i`` reads key/value head ``i // (H / H_kv)``).  Blockwise with an
+    ``q`` [B, T, H, dh], ``k`` [B, T, H_kv, dh] and ``v`` [B, T, H_kv,
+    dv] with ``H_kv`` dividing ``H`` (query head ``i`` reads key/value head
+    ``i // (H / H_kv)``); the scale is ``1 / sqrt(dh)``, the output [B, T,
+    H, dv].  Under the causal mask the values may be narrower than queries
+    and keys (latent attention's are); block diffusion takes ``dv = dh``.
+    Blockwise with an
     online softmax (the ``ring_block_k`` formulation of
     ``parallel/ring_attention.py``), statistics in float32, so nothing [T,
     T] is ever alive: a tile of ``query_block`` queries at a time reads the
@@ -347,7 +355,7 @@ def causal_document_attention(q, k, v, segment, *, block_of=None,
         split = lambda a: jnp.moveaxis(
             a.reshape(a.shape[0], n, block, *a.shape[2:]), 1, 0)
         carry, _ = jax.lax.scan(
-            jax.checkpoint(keys), _fresh(q_b),
+            jax.checkpoint(keys), _fresh(q_b, v_b.shape[-1]),
             (split(k_b), split(v_b), split(seg_k), pos_k.reshape(n, block)))
         return _finish(carry, v_b.dtype)
 
@@ -424,7 +432,8 @@ def _block_diffusion(q, k, v, segment, block_of, block_length, query_block,
     def query_tile(_, xs):
         q_t, seg_q, blk_q, n = xs
         noisy, i = n < tiles, n % tiles
-        carry = jax.checkpoint(own)(_fresh(q_t), q_t, seg_q, blk_q, noisy, i)
+        carry = jax.checkpoint(own)(_fresh(q_t, v.shape[-1]), q_t, seg_q,
+                                    blk_q, noisy, i)
         carry, _ = jax.lax.scan(
             jax.checkpoint(earlier(q_t, seg_q, blk_q, noisy, i)), carry, clean)
         return None, _finish(carry, v.dtype)

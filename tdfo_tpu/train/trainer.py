@@ -1102,7 +1102,8 @@ class Trainer:
 
     def _build_causal_lm(self) -> None:
         """A decoder (``Config.is_causal_lm``: ``models/olmo_hybrid``,
-        ``models/nemotron_h``, ``models/sdar_moe``) in the DMP regime, wired
+        ``models/nemotron_h``, ``models/sdar_moe``, ``models/deepseek_v3``)
+        in the DMP regime, wired
         as ``_build_bert4rec`` wires its model-parallel one: the vocabulary
         (slice) as one table of the collection (under the fused threshold it
         is a plain table: gather lookup, row-sparse Adam on touched rows),
